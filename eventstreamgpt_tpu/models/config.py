@@ -460,7 +460,7 @@ class StructuredTransformerConfig(JSONableMixin):
         # so the backward never re-executes the flash/splash/band attention
         # custom-calls — the production-width policy candidate (the bench
         # width probe A/Bs it against dots_no_batch every run and reports
-        # both; docs/performance.md). Measured A/Bs: BASELINE.md
+        # both; docs/performance.md). Measured A/Bs: BASELINE.md (pre-PR-22 record, git history)
         # "Rematerialization" tables.
         if gradient_checkpointing not in (
             "none", "block", "dots", "dots_no_batch", "save_attention"

@@ -358,6 +358,37 @@ def make_causal_mask(
     return mask
 
 
+def flash_block_sizes(B: int, num_heads: int, S: int, head_dim: int):
+    """The block sizes the ``pallas_flash`` global layers hand the kernel.
+
+    The kernel's default 128-wide blocks leave the MXU badly
+    underfed at long sequence lengths; the sweet spot depends on
+    head_dim (scripts/probe_flash_blocks.py, fwd+bwd per global
+    layer at B=8/L=1024, quiet-window sustained protocol):
+    d=128 → 1.72 ms at 1024-wide vs 1.90 at 512 / 5.08 at 128 /
+    9.2 at defaults; d=64 → 4.0 ms at 512-wide vs 5.8 at 256 /
+    11.5 at defaults (and the splash causal kernel measures 9.5 —
+    flash+big-blocks wins). Pick the largest measured-good width
+    that divides the sequence length; otherwise keep the kernel's
+    defaults.
+    """
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+    # 128 closes the ladder in both branches so short sequences (S=128)
+    # still pin explicit blocks instead of silently falling to kernel
+    # defaults.
+    preferred = (1024, 512, 256, 128) if head_dim >= 128 else (512, 256, 128)
+    bn = next((b for b in preferred if b <= S and S % b == 0), None)
+    if bn is None:
+        return BlockSizes.get_default(B, num_heads, S, S, head_dim)
+    return BlockSizes(
+        block_q=bn, block_k_major=bn, block_k=bn, block_b=1,
+        block_q_major_dkv=bn, block_k_major_dkv=bn,
+        block_k_dkv=bn, block_q_dkv=bn,
+        block_k_major_dq=bn, block_k_dq=bn, block_q_dq=bn,
+    )
+
+
 class InnerSelfAttention(nn.Module):
     """Multi-head causal self-attention with optional local windowing.
 
@@ -723,6 +754,22 @@ class InnerSelfAttention(nn.Module):
             and S % 128 == 0
         )
         use_pallas = kernel_ok and self.attention_type == "global"
+        if (
+            cfg.attention_implementation == "pallas_flash"
+            and fused_ok
+            and not kernel_ok
+            and not use_dep_fused
+        ):
+            # Not silent: the config asked for the kernels and this trace
+            # cannot take them (chip_smoke.py asserts the opposite on the chip).
+            import warnings
+
+            warnings.warn(
+                "attention_implementation='pallas_flash' is taking the einsum/band "
+                f"path: backend={jax.default_backend()!r}, S={S} (the flash/splash "
+                "kernels need a TPU backend and S % 128 == 0)",
+                stacklevel=2,
+            )
         # Narrow-window local layers skip the kernels entirely: the chunked
         # band einsum (ops/band_attention.py) touches only a (C, 2C) logits
         # plane per window-sized chunk and measured ~35-45% faster fwd+bwd
@@ -825,50 +872,32 @@ class InnerSelfAttention(nn.Module):
             outputs = {"present_key_value": None, "_heads_first_out": True}
         elif use_pallas:
             from jax.experimental.pallas.ops.tpu.flash_attention import (
-                BlockSizes,
                 SegmentIds,
                 flash_attention,
             )
 
-            # The kernel's default 128-wide blocks leave the MXU badly
-            # underfed at long sequence lengths; the sweet spot depends on
-            # head_dim (scripts/probe_flash_blocks.py, fwd+bwd per global
-            # layer at B=8/L=1024, quiet-window sustained protocol):
-            # d=128 → 1.72 ms at 1024-wide vs 1.90 at 512 / 5.08 at 128 /
-            # 9.2 at defaults; d=64 → 4.0 ms at 512-wide vs 5.8 at 256 /
-            # 11.5 at defaults (and the splash causal kernel measures 9.5 —
-            # flash+big-blocks wins). Pick the largest measured-good width
-            # that divides the sequence length; otherwise keep the kernel's
-            # defaults.
-            head_dim = query.shape[-1]
-            # 128 closes the ladder in both branches so short sequences
-            # (S=128) still pin explicit blocks instead of silently falling
-            # to kernel defaults (ADVICE r04).
-            preferred = (1024, 512, 256, 128) if head_dim >= 128 else (512, 256, 128)
-            bn = next((b for b in preferred if b <= S and S % b == 0), None)
-            block_sizes = (
-                BlockSizes(
-                    block_q=bn, block_k_major=bn, block_k=bn, block_b=1,
-                    block_q_major_dkv=bn, block_k_major_dkv=bn,
-                    block_k_dkv=bn, block_q_dkv=bn,
-                    block_k_major_dq=bn, block_k_dq=bn, block_q_dq=bn,
-                )
-                if bn is not None
-                else BlockSizes.get_default(B, num_heads, S, S, head_dim)
-            )
+            block_sizes = flash_block_sizes(B, num_heads, S, query.shape[-1])
 
             # GPT-Neo lineage: logits are NOT scaled by 1/sqrt(head_dim).
             # bf16 q/k/v ride the MXU directly (the kernel accumulates its
             # softmax statistics in fp32); fp32 mode keeps fp32 inputs.
             kernel_dt = dt if dt == jnp.bfloat16 else jnp.float32
-            attn_output = flash_attention(
+            from ..parallel.context import per_batch_shard
+
+            attn_output = per_batch_shard(
+                lambda q, k, v, s: flash_attention(
+                    q,
+                    k,
+                    v,
+                    segment_ids=SegmentIds(q=s, kv=s),
+                    causal=True,
+                    sm_scale=1.0,
+                    block_sizes=block_sizes,
+                ),
                 query.astype(kernel_dt),
                 key.astype(kernel_dt),
                 value.astype(kernel_dt),
-                segment_ids=SegmentIds(q=seg, kv=seg),
-                causal=True,
-                sm_scale=1.0,
-                block_sizes=block_sizes,
+                seg,
             ).astype(value.dtype)
             outputs = {"present_key_value": None, "_heads_first_out": True}
         elif use_band:
@@ -876,7 +905,7 @@ class InnerSelfAttention(nn.Module):
 
             # chunk_size is left at its default C=window — the settled
             # production choice: fatter chunks win layer microbenches but
-            # lose the interleaved step-level A/B (BASELINE.md); the knob
+            # lose the interleaved step-level A/B (BASELINE.md (pre-PR-22 record, git history)); the knob
             # stays for per-deployment tuning via probes.
             attn_output = band_local_attention(query, key, value, seg, self.window_size)
             outputs = {"present_key_value": None, "_heads_first_out": True}
@@ -902,9 +931,14 @@ class InnerSelfAttention(nn.Module):
             # Splash applies no logit scaling — matching the unscaled GPT-Neo
             # lineage — and accumulates softmax statistics in fp32.
             kernel_dt = dt if dt == jnp.bfloat16 else jnp.float32
-            attn_output = jax.vmap(
-                lambda q, k, v, s: kernel(q, k, v, segment_ids=splash_kernel.SegmentIds(q=s, kv=s))
-            )(
+            from ..parallel.context import per_batch_shard
+
+            attn_output = per_batch_shard(
+                jax.vmap(
+                    lambda q, k, v, s: kernel(
+                        q, k, v, segment_ids=splash_kernel.SegmentIds(q=s, kv=s)
+                    )
+                ),
                 query.astype(kernel_dt),
                 key.astype(kernel_dt),
                 value.astype(kernel_dt),
@@ -1144,7 +1178,7 @@ def remat_block_cls(config: StructuredTransformerConfig, use_flag: bool = False)
 
     ``config.gradient_checkpointing`` selects the policy (VERDICT r05 #3;
     r06 MFU round): ``"none"`` (config default — at toy shapes every policy only
-    adds recompute, BASELINE.md "Rematerialization"), ``"block"``
+    adds recompute, BASELINE.md (pre-PR-22 record, git history) "Rematerialization"), ``"block"``
     (whole-block ``nn.remat``, minimum memory), ``"dots"`` /
     ``"dots_no_batch"`` (``jax.checkpoint`` selective policies saving matmul
     outputs — the memory/FLOPs middle ground for configs whose activations

@@ -197,17 +197,25 @@ def dep_graph_attention(
                 "dropout_mask, not a probs_transform closure"
             )
     if impl in ("pallas", "pallas_interpret"):
+        from ..parallel.context import per_batch_shard
         from .pallas_dep_graph import dep_graph_attention_pallas
 
-        return dep_graph_attention_pallas(
+        # Rows are batch-major flattened events, independent of each other.
+        return per_batch_shard(
+            lambda q, k, v, *m: dep_graph_attention_pallas(
+                q,
+                k,
+                v,
+                q_offset=q_offset,
+                window=window,
+                dropout_mask=m[0] if m else None,
+                dropout_rate=dropout_rate,
+                interpret=impl == "pallas_interpret",
+            ),
             query,
             key,
             value,
-            q_offset=q_offset,
-            window=window,
-            dropout_mask=dropout_mask,
-            dropout_rate=dropout_rate,
-            interpret=impl == "pallas_interpret",
+            *(() if dropout_mask is None else (dropout_mask,)),
         )
     return _dep_graph_attention_xla(
         query,

@@ -61,11 +61,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .impl_select import LANE, compiler_params_cls, resolve_impl
+from .impl_select import LANE, resolve_impl
 from .impl_select import round_up as _round_up
 
-_CompilerParams = compiler_params_cls()
 
 __all__ = ["fused_categorical", "topk_topp_mask"]
 
@@ -162,7 +162,7 @@ def _sample_2d(z, g, keep, interpret=False):
         ],
         out_specs=pl.BlockSpec((_ROW_TILE, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, 1), jnp.int32),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(z, g, keep_op)
     return out[:rows, 0]

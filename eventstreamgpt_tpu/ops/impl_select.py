@@ -32,17 +32,6 @@ IMPLS = ("pallas", "pallas_interpret", "xla")
 LANE = 128
 
 
-def compiler_params_cls():
-    """The Pallas TPU CompilerParams class under either jaxlib name.
-
-    jax renamed ``TPUCompilerParams`` → ``CompilerParams``; every kernel
-    module resolves the shim HERE so the next rename is a one-line fix.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def round_up(x: int, m: int) -> int:
     """The smallest multiple of ``m`` >= ``x`` (tile padding)."""
     return (x + m - 1) // m * m

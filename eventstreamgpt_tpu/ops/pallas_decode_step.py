@@ -54,18 +54,35 @@ default (fused XLA, bench.py ``decode_step_impl_winner``).
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..models.transformer import ACT2FN
-from .impl_select import compiler_params_cls, resolve_impl
+from .impl_select import ENV_VAR, resolve_impl
 from .kv_quant import dequantize_kv, quantize_kv
 
-_CompilerParams = compiler_params_cls()
 
-__all__ = ["decode_stack_step", "stack_layer_weights", "WEIGHT_NAMES"]
+__all__ = ["decode_stack_step", "stack_layer_weights", "WEIGHT_NAMES", "MOSAIC_REFUSAL"]
+
+# PR 22 asked the chip's compiler (AOT, described v5e device): this kernel
+# has never lowered. Its per-layer 2D operands use (1, E) blocks of (L, E)
+# arrays, which the TPU lowering refuses ("last two dimensions of your
+# block shape [must be] divisible by 8 and 128 ... or equal the array's"),
+# and behind that the body is XLA-shaped (4D reshape/swapaxes, two-batch-dim
+# einsums, whole-cache `where`s) with one layer's weights plus KV planes far
+# past VMEM at hidden 1024. Until it is rewritten for Mosaic, the compiled
+# impl raises -- it never interprets or gives way to XLA at run time -- and
+# `auto` is the XLA step in code. `pallas_interpret` keeps the parity tests.
+MOSAIC_REFUSAL = (
+    "decode_stack_step(impl='pallas') does not compile under Mosaic (block "
+    "shapes (1, E) of (L, E) operands are refused; the layer body needs a "
+    "Mosaic rewrite -- CHANGES.md PR 22). Use decode_step_impl=None/'xla' "
+    "on the chip; 'pallas_interpret' runs the same code for parity tests."
+)
 
 _F32_MIN = float(jnp.finfo(jnp.float32).min)
 
@@ -335,7 +352,11 @@ def decode_stack_step(
         ``mask'``/``length'`` are the layer-shared cache-tracking updates
         (``length' = start + 1``).
     """
+    if impl in (None, "auto") and not os.environ.get(ENV_VAR):
+        impl = "xla"  # the compiled kernel does not lower (below)
     impl = resolve_impl(impl, "decode_stack_step")
+    if impl == "pallas":
+        raise NotImplementedError(MOSAIC_REFUSAL)
     L, B = key_cache.shape[0], key_cache.shape[1]
     quantized = key_scale is not None
     if (value_scale is not None) != quantized:
@@ -421,7 +442,7 @@ def decode_stack_step(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=impl == "pallas_interpret",
     )(*per_step, *per_layer)
     if not quantized:

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the head stack's vocabulary-plane gathers.
 
-Device profiling of the production train step (BASELINE.md "remaining hot
+Device profiling of the production train step (BASELINE.md (pre-PR-22 record, git history) "remaining hot
 spots") attributed ~40% of the toy-shape head cost to XLA's lowering of
 the multivariate-regression head's last-axis gathers and their backward
 scatter on the ``(B, L, 2*vocab)`` projection plane
@@ -40,13 +40,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .impl_select import LANE, compiler_params_cls, resolve_impl
+from .impl_select import LANE, resolve_impl
 from .impl_select import round_up as _round_up
 
 # jaxlib-compat shim (TPUCompilerParams → CompilerParams) lives in
 # impl_select so all kernel modules track renames in one place.
-_CompilerParams = compiler_params_cls()
 
 __all__ = ["vocab_gather"]
 
@@ -125,7 +125,7 @@ def _gather_2d(z: jnp.ndarray, ci: jnp.ndarray, interpret: bool = False) -> jnp.
         ],
         out_specs=pl.BlockSpec((_ROW_TILE, mp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, mp), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(z, ci.astype(jnp.int32))
     return out[:n, :m]
@@ -149,7 +149,7 @@ def _scatter_2d(
         ],
         out_specs=pl.BlockSpec((_ROW_TILE, vp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, vp), dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(g.astype(jnp.float32), ci.astype(jnp.int32))
     return dz[:n, :v]
@@ -201,6 +201,9 @@ def vocab_gather(z: jnp.ndarray, ci: jnp.ndarray, impl: str | None = None) -> jn
     impl = resolve_impl(impl, "vocab_gather")
     if impl == "xla":
         return jnp.take_along_axis(z, ci, axis=-1).astype(jnp.float32)
-    return _vocab_gather_kernel(
-        z, ci, impl == "pallas_interpret", z.shape[-1], jnp.dtype(z.dtype)
+    from ..parallel.context import per_batch_shard
+
+    interpret, v, dtype = impl == "pallas_interpret", z.shape[-1], jnp.dtype(z.dtype)
+    return per_batch_shard(
+        lambda z_, ci_: _vocab_gather_kernel(z_, ci_, interpret, v, dtype), z, ci
     )

@@ -15,7 +15,7 @@ from .collectives_audit import (
     compare_inventory,
     resolve_folded_reduce_scatters,
 )
-from .context import current_ring_context, ring_context
+from .context import current_kernel_mesh, current_ring_context, kernel_mesh, per_batch_shard, ring_context
 from .ring_attention import ring_attention, ring_attention_shard
 
 __all__ = [
@@ -23,7 +23,10 @@ __all__ = [
     "collective_inventory",
     "compare_inventory",
     "resolve_folded_reduce_scatters",
+    "current_kernel_mesh",
     "current_ring_context",
+    "kernel_mesh",
+    "per_batch_shard",
     "ring_attention",
     "ring_attention_shard",
     "ring_context",

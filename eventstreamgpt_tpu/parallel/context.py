@@ -1,4 +1,5 @@
-"""Active ring-attention context for model integration.
+"""Active mesh contexts for model integration: ring attention and the
+per-batch-shard wrap of the Pallas kernels.
 
 Flax modules don't carry device meshes; the training driver activates a
 `ring_context` around its jitted step, and `InnerSelfAttention` (with
@@ -49,3 +50,67 @@ def ring_context(
         yield
     finally:
         _STATE.ctx = prev
+
+
+# ------------------------------------------------------------- kernel mesh
+# Mosaic kernels cannot be partitioned by GSPMD ("Mosaic kernels cannot be
+# automatically partitioned. Please wrap the call in a shard_map" -- what
+# the chip's compiler answers for any multi-device jit that contains one;
+# PR 22). The training drivers activate `kernel_mesh` around their jitted
+# steps, and every Pallas call site whose rows are independent per batch
+# element routes through `per_batch_shard`: with a multi-device batch
+# layout active the call runs once per batch shard under `jax.shard_map`
+# (no collective, no gather of the operand planes); with no context, or a
+# single batch shard, it is a plain call.
+_BATCH_AXES = ("data", "fsdp")
+
+
+def current_kernel_mesh() -> Mesh | None:
+    return getattr(_STATE, "kernel_mesh", None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh: Mesh | None):
+    """Activates per-batch-shard Pallas calls over ``mesh`` for enclosed traces."""
+    prev = current_kernel_mesh()
+    _STATE.kernel_mesh = mesh
+    try:
+        yield
+    finally:
+        _STATE.kernel_mesh = prev
+
+
+def per_batch_shard(fn, *args):
+    """``fn(*args)``, once per batch shard of the active `kernel_mesh`.
+
+    Every array in ``args`` and in the result must carry the batch (or the
+    batch-major flattened row) dimension first; ``fn`` must be independent
+    across it. Every mesh axis is manual inside the call (Mosaic refuses a
+    partially-manual context), so operands are replicated over any axis
+    other than ``data``/``fsdp``.
+    """
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    mesh = current_kernel_mesh()
+    axes = tuple(a for a in _BATCH_AXES if mesh is not None and mesh.shape.get(a, 1) > 1)
+    if not axes:
+        return fn(*args)
+    n_shards = 1
+    for a in axes:
+        n_shards *= mesh.shape[a]
+    bad = [x.shape for x in jax.tree_util.tree_leaves(args) if x.shape[0] % n_shards]
+    if bad:
+        raise ValueError(
+            f"a Pallas kernel's leading (batch) dimension must divide by the "
+            f"{n_shards} batch shards of mesh axes {axes}; got shapes {bad}"
+        )
+    spec = lambda x: P(axes, *([None] * (x.ndim - 1)))  # noqa: E731
+    out_shape = jax.eval_shape(fn, *args)
+    return jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=jax.tree_util.tree_map(spec, args),
+        out_specs=jax.tree_util.tree_map(spec, out_shape),
+        check_vma=False,
+    )(*args)

@@ -28,13 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# ``shard_map`` graduated from jax.experimental to the jax namespace; support
-# both so the ring runs on every jaxlib the fleet carries.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on older jaxlib only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 MASK_VALUE = -1e30
 
 
@@ -171,7 +164,7 @@ def ring_attention(
     qkv_spec = P(b_spec, h_spec, axis_name, None)
     seg_spec = P(b_spec, axis_name)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(ring_attention_shard, axis_name=axis_name, window_size=window_size),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),

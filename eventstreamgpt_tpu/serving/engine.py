@@ -743,7 +743,11 @@ class GenerationEngine:
         self.decode_step_impl = decode_step_impl
         if decode_step_impl in (None, "auto"):
             self._decode_step_resolved = "xla"
-        elif decode_step_impl in ("pallas", "pallas_interpret", "xla"):
+        elif decode_step_impl == "pallas":
+            from ..ops.pallas_decode_step import MOSAIC_REFUSAL
+
+            raise NotImplementedError(MOSAIC_REFUSAL)
+        elif decode_step_impl in ("pallas_interpret", "xla"):
             self._decode_step_resolved = decode_step_impl
         else:
             raise ValueError(
@@ -1165,15 +1169,13 @@ class GenerationEngine:
         out_shape = jax.eval_shape(fn, *args)
         if not all(_rowwise(x) for x in jax.tree_util.tree_leaves(out_shape)):
             return fn(*args)
-        from jax.experimental.shard_map import shard_map
-
         row_spec = lambda x: P("data", *([None] * (x.ndim - 1)))  # noqa: E731
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=jax.tree_util.tree_map(row_spec, args),
             out_specs=jax.tree_util.tree_map(row_spec, out_shape),
-            check_rep=False,
+            check_vma=False,
         )
         return wrapped(*args)
 

@@ -388,7 +388,15 @@ def train(cfg: FinetuneConfig) -> tuple[float | None, dict | None, dict | None]:
         )
     _device_eval_cache: dict[int, "DeviceDataset | None"] = {}
 
+    # Pallas kernels run once per batch shard on a multi-device mesh
+    # (parallel/context.py); tracing-time state, as in pretraining.
+    from ..parallel.context import kernel_mesh
+
     def evaluate(params, dataset, split) -> dict[str, float]:
+        with kernel_mesh(mesh):
+            return _evaluate(params, dataset, split)
+
+    def _evaluate(params, dataset, split) -> dict[str, float]:
         metrics = StreamClassificationMetrics(config, split)
         # seed=0 pins random subsequence crops: eval passes must be comparable.
         if id(dataset) not in _device_eval_cache:
@@ -463,7 +471,7 @@ def train(cfg: FinetuneConfig) -> tuple[float | None, dict | None, dict | None]:
     shutdown = GracefulShutdown()
     resume_epoch, resume_skip = start_epoch, skip_batches
     epoch = start_epoch
-    with shutdown:
+    with kernel_mesh(mesh), shutdown:
         while epoch < oc.max_epochs:
             epoch_t0 = time.perf_counter()
             window_losses = []

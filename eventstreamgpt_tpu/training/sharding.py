@@ -71,6 +71,11 @@ def make_mesh(n_data: int, n_model: int = 1, n_fsdp: int = 1, devices=None) -> M
         raise ValueError(
             f"Need {n} devices for a {n_data}x{n_fsdp}x{n_model} mesh; have {len(devices)}"
         )
+    if n < len(devices):
+        print(
+            f"make_mesh: a {n_data}x{n_fsdp}x{n_model} mesh uses the first {n} of "
+            f"{len(devices)} devices."
+        )
     if n_fsdp == 1:
         return Mesh(np.asarray(devices[:n]).reshape(n_data, n_model), ("data", "model"))
     return Mesh(

@@ -225,6 +225,29 @@ def deep_merge(dst: dict, src: dict) -> dict:
     return dst
 
 
+def configure_compile_cache() -> str:
+    """Points JAX's persistent compilation cache at ONE fixed place.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set in code. Otherwise the cache lives at
+    ``<checkout>/.jax_cache`` (derived from this package's location,
+    git-ignored): the path is part of the cache key, so it is never a
+    tempfile, pid, or timestamp. Called by the device-running entry points
+    (``chip_smoke.py``, ``bench.py``, ``scripts/*.py`` ``__main__``), not
+    at import. Returns the directory in effect.
+    """
+    import os
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    cache_dir = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
 def split_config_arg(argv: list[str]) -> tuple[str | None, list[str]]:
     """Extracts a ``--config <yaml>`` pair from CLI args; returns (path, rest)."""
     argv = list(argv)

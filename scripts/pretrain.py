@@ -44,6 +44,9 @@ def main(argv: list[str] | None = None):
 
 
 if __name__ == "__main__":
+    from eventstreamgpt_tpu.utils.config_tool import configure_compile_cache
+
+    configure_compile_cache()
     from eventstreamgpt_tpu.reliability import EXIT_PREEMPTED, Preempted
 
     try:

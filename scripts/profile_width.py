@@ -2,8 +2,8 @@
 
 Traces a few pipelined steps of the packed bf16+Pallas train step through
 ``jax.profiler`` and prints the top HLO ops by device self-time (parsed from
-the xplane with ``xprof``). This is the tool that produced the "remaining
-hot spots" table in BASELINE.md.
+the xplane with ``xprof``). It runs only on a TPU (raises at start
+otherwise, and prints the device it found) — through the chip tool.
 
     PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python \
         python scripts/profile_width.py \
@@ -12,7 +12,7 @@ hot spots" table in BASELINE.md.
 ``--policy`` selects the rematerialization policy the step compiles under
 (default: ``save_attention``, the r06 production-width candidate) — the
 backward's recompute mix is policy-dependent, so attributions must name
-the policy they were taken under (VERDICT r05 weak #6).
+the policy they were taken under.
 
 (The pure-python protobuf flag is needed because the installed
 tensorflow/xprof protobuf generations disagree; parsing is slow but the
@@ -126,7 +126,7 @@ def top_ops_from_trace(trace_dir: str, top_n: int = 30):
 def summarize_categories(rows, top=25):
     """hlo_stats table ({cols, rows} gviz-style) -> [(category, self_us)].
 
-    The per-category rollup that produced BASELINE.md's head-stack tables
+    The per-category rollup that produced BASELINE.md (pre-PR-22 record, git history)'s head-stack tables
     (dense matmuls vs attention custom-calls vs scatter/gather vs loop
     fusions); re-run this under each remat policy (``--policy``) to see what
     the backward actually recomputes.
@@ -159,8 +159,11 @@ def main(argv=None):
 
     import jax
 
-    from eventstreamgpt_tpu.utils.benchmarking import drain, wait_for_quiet
+    from eventstreamgpt_tpu.utils.benchmarking import drain, require_tpu, wait_for_quiet
+    from eventstreamgpt_tpu.utils.config_tool import configure_compile_cache
 
+    require_tpu()  # raises unless a TPU with a published peak
+    configure_compile_cache()
     step, state, resident = build_step(args.hidden, args.layers, args.head_dim, args.policy)
     rng = jax.random.PRNGKey(0)
     state, loss = step(state, resident, rng)  # compile

@@ -2,7 +2,7 @@
 
 The end-to-end test runs the full pipeline (schema ingestion → range/event
 splitting → 1h datapoint-anchored aggregation → split → preprocess →
-save/load → DL cache) on ``/root/reference/sample_data/raw`` with the
+save/load → DL cache) on ``sample_data/raw`` with the
 reference's own ``dataset.yaml`` knobs, and checks fitted vocabularies
 against the reference's shipped processed artifacts where the input data
 overlap makes them comparable (eye_color, department). Unit tests pin the
@@ -35,7 +35,7 @@ from eventstreamgpt_tpu.data.types import (
     TemporalityType,
 )
 
-RAW = Path("/root/reference/sample_data/raw")
+from tests import SAMPLE_RAW_DIR as RAW  # noqa: E402  (the committed raw CSVs)
 
 
 def build_sample_dataset(save_dir: Path) -> Dataset:
@@ -116,25 +116,30 @@ def built_dataset(tmp_path_factory):
 class TestEndToEnd:
     def test_construction(self, built_dataset):
         ESD = built_dataset
-        assert len(ESD.subjects_df) == 100
-        assert len(ESD.events_df) > 10_000
+        assert len(ESD.subjects_df) == 120  # the committed raw CSVs
+        assert len(ESD.events_df) > 5_000
         # Aggregated event types are sorted unique unions joined with '&'.
         assert "ADMISSION&VITALS" in ESD.event_types
         assert set(ESD.split_subjects) == {"train", "tuning", "held_out"}
         sizes = {k: len(v) for k, v in ESD.split_subjects.items()}
-        assert sizes == {"train": 80, "tuning": 10, "held_out": 10}
+        assert sizes == {"train": 96, "tuning": 12, "held_out": 12}
 
     def test_fit_vocabularies_match_reference_artifacts(self, built_dataset):
-        """eye_color/department derive from the same raw inputs the reference's
-        shipped processed artifacts were built from — vocab must match."""
+        """eye_color/department derive from the same raw inputs the committed
+        processed artifact (sample_data/processed/sample) was built from — the
+        fitted vocabularies must hold the same elements, UNK first (their
+        frequency order depends on the train split drawn)."""
+        import json
+
+        from tests import SAMPLE_DIR
+
+        shipped = json.loads((SAMPLE_DIR / "inferred_measurement_configs.json").read_text())
         cfgs = built_dataset.measurement_configs
-        assert cfgs["eye_color"].vocabulary.vocabulary == ["UNK", "BROWN", "BLUE", "HAZEL", "GREEN"]
-        assert cfgs["department"].vocabulary.vocabulary == [
-            "UNK",
-            "CARDIAC",
-            "PULMONARY",
-            "ORTHOPEDIC",
-        ]
+        for name in ("eye_color", "department"):
+            vocab = cfgs[name].vocabulary.vocabulary
+            assert vocab[0] == "UNK"
+            assert set(vocab) == set(shipped[name]["vocabulary"]["vocabulary"])
+        assert len(cfgs["eye_color"].vocabulary.vocabulary) == 5
 
     def test_numeric_fit(self, built_dataset):
         md = built_dataset.measurement_configs["age"].measurement_metadata
@@ -177,7 +182,7 @@ class TestEndToEnd:
         ds = JaxDataset(
             PytorchDatasetConfig(save_dir=save_dir, max_seq_len=32, min_seq_len=2), "train"
         )
-        assert len(ds) == 80
+        assert len(ds) == 96
         b = next(ds.batches(4, shuffle=True, seed=0))
         assert np.asarray(b.event_mask).shape == (4, 32)
         assert np.asarray(b.event_mask).sum() > 0

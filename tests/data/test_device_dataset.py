@@ -18,7 +18,7 @@ import pytest
 from eventstreamgpt_tpu.data import DeviceDataset, JaxDataset, PytorchDatasetConfig
 from eventstreamgpt_tpu.data.config import SeqPaddingSide, SubsequenceSamplingStrategy
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 
 @pytest.fixture(scope="module")

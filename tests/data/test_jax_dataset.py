@@ -1,7 +1,7 @@
 """Tests for `JaxDataset` against the reference's own prebuilt sample cache.
 
 Uses the read-only artifacts at
-``/root/reference/sample_data/processed/sample/`` (DL_reps parquet +
+``sample_data/processed/sample/`` (DL_reps parquet +
 vocabulary/measurement configs produced by the reference implementation) as
 the interop fixture — parsing them correctly IS the data contract. Mirrors
 ``tests/data/test_pytorch_dataset.py`` coverage: getitem dicts, collated
@@ -19,7 +19,7 @@ import pytest
 from eventstreamgpt_tpu.data import JaxDataset, PytorchDatasetConfig
 from eventstreamgpt_tpu.data.config import SeqPaddingSide, SubsequenceSamplingStrategy
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +43,7 @@ class TestLoading:
     def test_loads_reference_artifacts(self, sample_dir):
         ds = JaxDataset(make_config(sample_dir), "tuning")
         assert len(ds) > 0
-        assert ds.vocabulary_config.total_vocab_size == 45
+        assert ds.vocabulary_config.total_vocab_size == 27  # the committed artifact's
         assert ds.do_produce_static_data
         assert ds.mean_log_inter_event_time_min != 0.0
         assert ds.std_log_inter_event_time_min > 0.0

@@ -172,7 +172,7 @@ class TestDevicePrefetcher:
 
         from eventstreamgpt_tpu.data import JaxDataset, PytorchDatasetConfig
 
-        ref = Path("/root/reference/sample_data/processed/sample")
+        from tests import SAMPLE_DIR as ref
         for name in ("vocabulary_config.json", "inferred_measurement_configs.json"):
             shutil.copy(ref / name, tmp_path / name)
         shutil.copytree(ref / "DL_reps", tmp_path / "DL_reps")

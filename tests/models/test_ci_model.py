@@ -18,7 +18,7 @@ from eventstreamgpt_tpu.data import JaxDataset, PytorchDatasetConfig
 from eventstreamgpt_tpu.models.ci_model import CIPPTForGenerativeSequenceModeling
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 
 @pytest.fixture(scope="module")
@@ -52,7 +52,7 @@ def model_and_params(dataset):
 class TestEndToEnd:
     def test_set_to_dataset(self, dataset, model_and_params):
         config, _, _ = model_and_params
-        assert config.vocab_size == 45
+        assert config.vocab_size == 27  # the committed artifact's
         assert config.max_seq_len == 24
         assert config.mean_log_inter_event_time_min == dataset.mean_log_inter_event_time_min
         assert set(config.measurements_idxmap) == set(dataset.vocabulary_config.measurements_idxmap)

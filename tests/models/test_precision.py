@@ -21,7 +21,7 @@ from eventstreamgpt_tpu.models.ci_model import CIPPTForGenerativeSequenceModelin
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 from eventstreamgpt_tpu.models.na_model import NAPPTForGenerativeSequenceModeling
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 # bf16 has ~3 decimal digits; after fp32 softmax/loss math the end-to-end
 # loss disagreement stays comfortably within a relative 2%.

@@ -1,0 +1,174 @@
+"""Compiles the main path's Pallas kernels for a described TPU v5e device.
+
+No chip is attached: the TPU compiler installed in this image compiles for
+a device that `jax.experimental.topologies` describes (the
+``on-chip-measurement`` guide, section 2, third rehearsal). Interpret-mode
+parity tests cannot see what Mosaic refuses -- an unsupported shape cast, a
+VMEM overflow -- and both had shipped in ``ops/pallas_dep_graph.py`` before
+this file existed. Every kernel `ops.impl_select.resolve_impl` hands to the
+chip by default is compiled here at the shapes ``chip_smoke.py`` runs,
+forward and gradient, and must contain ``tpu_custom_call``.
+
+The topology is described inside a module-scoped fixture (never at import,
+in a ``skipif`` or a ``parametrize``): only the xdist worker that is given
+this file loads libtpu. Keep every such test in THIS file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+pytestmark = pytest.mark.pallas
+
+# chip_smoke.py's shapes: the NA tutorial shape (B*L = 32*256 rows of a
+# three-level dependency graph, 4 heads of 64) and the width-1024 head
+# geometry (8 heads of 128); the synthetic cohort's unified vocabulary.
+DEP_GRAPH_SHAPES = {"tutorial_4x64": (8192, 3, 4, 4, 64), "wide_8x128": (8192, 3, 4, 8, 128)}
+VOCAB = 4057
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _chip_compile_config():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep these compiles out of it.
+    And compile what the chip compiles: tests/conftest.py forces
+    ``jax_default_matmul_precision=highest`` for CPU numerics, which the
+    upstream flash kernel's bf16 matmuls inherit and Mosaic then refuses."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("shape_name", list(DEP_GRAPH_SHAPES))
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_dep_graph_attention(one_chip, shape_name, dtype, direction):
+    from eventstreamgpt_tpu.ops.pallas_dep_graph import dep_graph_attention_pallas
+
+    N, Q, S, H, D = DEP_GRAPH_SHAPES[shape_name]
+    q = jax.ShapeDtypeStruct((N, Q, H, D), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((N, S, H, D), dtype, sharding=one_chip)
+
+    def fwd(q_, k_, v_):
+        return dep_graph_attention_pallas(q_, k_, v_, q_offset=S - Q)
+
+    def grad(q_, k_, v_):
+        loss = lambda *a: (fwd(*a).astype(jnp.float32) ** 2).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2))(q_, k_, v_)
+
+    text = _compile(fwd if direction == "forward" else grad, q, kv, kv)
+    assert ("dep_graph_attention_bwd" in text) == (direction == "gradient")
+
+
+def test_dep_graph_attention_with_dropout_mask(one_chip):
+    from eventstreamgpt_tpu.ops.pallas_dep_graph import dep_graph_attention_pallas
+
+    N, Q, S, H, D = DEP_GRAPH_SHAPES["tutorial_4x64"]
+    q = jax.ShapeDtypeStruct((N, Q, H, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((N, S, H, D), jnp.bfloat16, sharding=one_chip)
+    keep = jax.ShapeDtypeStruct((N, Q, S, H), jnp.bool_, sharding=one_chip)
+
+    def grad(q_, k_, v_, m_):
+        def loss(*a):
+            out = dep_graph_attention_pallas(
+                *a, q_offset=S - Q, dropout_mask=m_, dropout_rate=0.1
+            )
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q_, k_, v_)
+
+    _compile(grad, q, kv, kv, keep)
+
+
+@pytest.mark.parametrize(
+    "filters", [{}, {"top_k": 10, "top_p": 0.9}], ids=["plain", "topk_topp"]
+)
+def test_fused_categorical(one_chip, filters):
+    from eventstreamgpt_tpu.ops.fused_sampling import fused_categorical
+
+    logits = jax.ShapeDtypeStruct((8, VOCAB), jnp.bfloat16, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    _compile(lambda z, k: fused_categorical(z, k, impl="pallas", **filters), logits, key)
+
+
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_vocab_gather(one_chip, direction):
+    from eventstreamgpt_tpu.ops.pallas_heads import vocab_gather
+
+    z = jax.ShapeDtypeStruct((8, 1024, VOCAB), jnp.bfloat16, sharding=one_chip)
+    ci = jax.ShapeDtypeStruct((8, 1024, 24), jnp.int32, sharding=one_chip)
+    fwd = lambda z_, ci_: vocab_gather(z_, ci_, impl="pallas")  # noqa: E731
+    grad = lambda z_, ci_: jax.grad(lambda a: fwd(a, ci_).sum())(z_)  # noqa: E731
+    _compile(fwd if direction == "forward" else grad, z, ci)
+
+
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_flash_attention_s1024_d128(one_chip, direction):
+    """The flash call of models/transformer.py's ``use_pallas`` branch with
+    the block sizes it picks at S = 1024, D = 128 (B = 8, 8 heads)."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
+
+    from eventstreamgpt_tpu.models.transformer import flash_block_sizes
+
+    B, H, S, D = 8, 8, 1024, 128
+    block_sizes = flash_block_sizes(B, H, S, D)
+    assert block_sizes.block_q == 1024
+    qkv = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+
+    def fwd(q, k, v, s):
+        return flash_attention(
+            q, k, v, segment_ids=SegmentIds(q=s, kv=s), causal=True, sm_scale=1.0,
+            block_sizes=block_sizes,
+        )
+
+    def grad(q, k, v, s):
+        loss = lambda *a: fwd(*a, s).astype(jnp.float32).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    _compile(fwd if direction == "forward" else grad, qkv, qkv, qkv, seg)
+
+
+def test_decode_megakernel_is_refused_loudly():
+    """`ops/pallas_decode_step.py` does not lower under Mosaic (PR 22,
+    CHANGES.md): the compiled impl raises instead of interpreting or
+    giving way to XLA at run time; ``auto`` is the XLA step in code."""
+    from eventstreamgpt_tpu.ops.pallas_decode_step import decode_stack_step
+
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        decode_stack_step(
+            {}, jnp.zeros((1, 1, 1, 8, 8)), jnp.zeros((1, 1, 1, 8, 8)), None, None,
+            jnp.zeros((1, 8)), jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
+            jnp.ones((1, 8), bool), windows=(0,), activation="gelu",
+            layer_norm_eps=1e-5, impl="pallas",
+        )

@@ -12,7 +12,7 @@ matter:
   permute traffic while per-hop payloads stay at block size;
 * the weak-scaling prediction derived from the static inventories
   (``COLLECTIVES.json: scaling_prediction``) keeps comm/compute within the
-  bound BASELINE.md claims (≈100% weak scaling inside an ICI domain).
+  bound BASELINE.md (pre-PR-22 record, git history) claims (≈100% weak scaling inside an ICI domain).
 """
 
 import json
@@ -130,16 +130,16 @@ class TestRingCommScaling:
 class TestScalingPrediction:
     """The second half of the collectives story: bytes/step/device ÷ ICI
     bandwidth vs the measured bench step must predict ≈100% weak scaling for
-    every audited layout (BASELINE.md "Weak-scaling prediction"). The
+    every audited layout (BASELINE.md (pre-PR-22 record, git history) "Weak-scaling prediction"). The
     ``dryrun_multichip`` artifact persists the derivation; these tests assert
     the bound FROM the artifact so the claim is re-checked whenever the dry
     run regenerates it.
     """
 
-    # Constants documented in BASELINE.md; must match __graft_entry__.py.
+    # Constants documented in BASELINE.md (pre-PR-22 record, git history); must match __graft_entry__.py.
     ICI_BYTES_PER_S = 50e9
     MEASURED_STEP_MS = 13.4
-    # The bound BASELINE.md claims: comm under 5% of the step in the
+    # The bound BASELINE.md (pre-PR-22 record, git history) claims: comm under 5% of the step in the
     # no-overlap worst case, even with generous launch-latency padding.
     MAX_COMM_COMPUTE_RATIO = 0.05
 

@@ -28,7 +28,7 @@ from eventstreamgpt_tpu.evaluation import (
 from eventstreamgpt_tpu.models.config import OptimizationConfig, StructuredTransformerConfig
 from eventstreamgpt_tpu.training import build_model, save_pretrained
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 
 class TestCRPS:
@@ -344,7 +344,7 @@ class TestTrajectoryDriver:
             fps = sorted((out_dir / split).glob("sample_*_local_rank_0.parquet"))
             assert len(fps) == 2, split
             df = pd.read_parquet(fps[0])
-            assert len(df) == 10  # every tuning/held-out subject
+            assert len(df) == 12  # every tuning/held-out subject of the committed sample
             assert {"time_delta", "dynamic_indices", "dynamic_values", "subject_id"} <= set(
                 df.columns
             )

@@ -18,8 +18,9 @@ from eventstreamgpt_tpu.ops.fused_sampling import fused_categorical, topk_topp_m
 
 pytestmark = pytest.mark.pallas
 
-ON_TPU = jax.default_backend() == "tpu"
-KERNEL = "pallas" if ON_TPU else "pallas_interpret"
+# CPU CI runs the kernel code in interpreter mode; the compiled kernel is
+# compared with the XLA tail on the chip by chip_smoke.py.
+KERNEL = "pallas_interpret"
 IMPLS = ("xla", KERNEL)
 
 

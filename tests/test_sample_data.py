@@ -1,7 +1,7 @@
 """The shipped ``sample_data/`` quickstart artifact stays valid.
 
 The repo ships a pre-built exemplar dataset (the analog of the reference's
-``/root/reference/sample_data``; regenerable via
+``sample_data``; regenerable via
 ``scripts/make_sample_data.py``) that the tutorial anchors on. These tests
 pin the artifact's contract: it parses with the production classes, feeds
 the training stack to a finite loss, and its task dataframe + labeler file

@@ -23,7 +23,7 @@ from scripts.pretrain import main as pretrain_main
 pytestmark = pytest.mark.slow  # full e2e; excluded from the fast core loop (-m "not slow")
 
 
-RAW = Path("/root/reference/sample_data/raw")
+from tests import SAMPLE_RAW_DIR as RAW  # noqa: E402  (the committed raw CSVs)
 
 DATASET_YAML = """
 do_overwrite: True

@@ -19,7 +19,7 @@ from scripts.launch_hp_sweep import main as sweep_main
 
 pytestmark = pytest.mark.slow  # full e2e; excluded from the fast core loop (-m "not slow")
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 SWEEP_YAML = """
 program: pretrain.py

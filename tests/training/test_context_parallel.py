@@ -20,7 +20,7 @@ from eventstreamgpt_tpu.training import PretrainConfig, train
 
 pytestmark = pytest.mark.slow  # full e2e; excluded from the fast core loop (-m "not slow")
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 
 @pytest.fixture(scope="module")

@@ -14,7 +14,7 @@ from eventstreamgpt_tpu.training import build_model, load_pretrained, save_pretr
 from eventstreamgpt_tpu.training.embedding import EmbeddingsOnlyModel, embed_batch, get_embeddings
 from eventstreamgpt_tpu.training.fine_tuning import FinetuneConfig
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 MODEL_KWARGS = dict(
     hidden_size=32,

@@ -30,7 +30,7 @@ from eventstreamgpt_tpu.training.fine_tuning import (
 
 pytestmark = pytest.mark.slow  # full e2e; excluded from the fast core loop (-m "not slow")
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 MODEL_KWARGS = dict(
     hidden_size=32,

@@ -35,7 +35,7 @@ from eventstreamgpt_tpu.training import (
 
 pytestmark = pytest.mark.slow  # full e2e; excluded from the fast core loop (-m "not slow")
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 MODEL_KWARGS = dict(
     hidden_size=32,
@@ -221,8 +221,9 @@ class TestTrainDriver:
             json.loads(line) for line in (save_dir / "train_log.jsonl").open()
         ]
         train_recs = [r for r in records if r["split"] == "train"]
-        # Epoch 0 had 2 batches; 1 was done pre-preemption → exactly 1 remains.
-        assert [(r["epoch"], r["step"]) for r in train_recs] == [(0, 2)]
+        # Epoch 0 has 3 batches on the committed sample (96 train subjects);
+        # 1 was done pre-preemption → exactly steps 2 and 3 remain.
+        assert [(r["epoch"], r["step"]) for r in train_recs] == [(0, 2), (0, 3)]
 
     def test_early_stopping(self, sample_dir, tmp_path):
         cfg = make_pretrain_config(sample_dir, tmp_path, max_epochs=50, patience=0, init_lr=1e-12)

@@ -30,7 +30,7 @@ from eventstreamgpt_tpu.training import (
 
 pytestmark = pytest.mark.slow  # compiles train steps; excluded from the fast loop
 
-REF_SAMPLE = Path("/root/reference/sample_data/processed/sample")
+from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
 MODEL_KWARGS = dict(
     hidden_size=32,
