@@ -50,6 +50,7 @@ global plan stream, bit-for-bit.
 from __future__ import annotations
 
 from functools import partial
+from itertools import islice
 from typing import Iterator
 
 import jax
@@ -57,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..utils.scopes import host_span
 from .config import SeqPaddingSide
 from .jax_dataset import BatchPlan, JaxDataset
 from .types import EventStreamBatch
@@ -194,6 +196,19 @@ def packed_collate_kernel(
     out = _mask_event_payload(td, di, dm, dv, dobs, event_mask)
     out["event_mask"] = event_mask
     return out
+
+
+def _chunks_under_span(items: Iterator, k: int) -> Iterator[list]:
+    """Lists of up to ``k`` items of ``items`` (the last may be shorter).
+    Making a chunk, which is where a plan iterator does its work, is one
+    ``es.host/plan`` span of an operator's trace; the span closes before the
+    chunk is handed on."""
+    while True:
+        with host_span("plan"):
+            buf = list(islice(items, k))
+        if not buf:
+            return
+        yield buf
 
 
 def _dense_pre_sliced(src, rows, cols, keep, n_rows: int, M: int, dtype) -> np.ndarray:
@@ -859,20 +874,15 @@ class DeviceDataset:
         may be shorter (``k < chunk_steps``); callers get one extra
         compilation for it at most.
         """
-        buf: list[BatchPlan] = []
-        for plan in self.dataset.plan_batches(
+        plans = self.dataset.plan_batches(
             batch_size,
             shuffle=shuffle,
             seed=seed,
             drop_last=drop_last,
             skip_batches=skip_batches,
             n_shards=self.data_shards,
-        ):
-            buf.append(plan)
-            if len(buf) == chunk_steps:
-                yield self._stack_plans(buf)
-                buf = []
-        if buf:
+        )
+        for buf in _chunks_under_span(plans, chunk_steps):
             yield self._stack_plans(buf)
 
     @staticmethod
@@ -903,28 +913,26 @@ class DeviceDataset:
         """
         ds = self.dataset
         L = seq_len or ds.max_seq_len
-        rows = ds.packed_rows_dealt(
-            batch_size, seq_len=L, shuffle=shuffle, seed=seed, n_shards=self.data_shards
-        )
 
-        buf: list[tuple] = []
-        n_ev_buf = 0
-        n_seen = 0
-        for lo_idx in range(0, len(rows), batch_size):
-            chunk = rows[lo_idx : lo_idx + batch_size]
-            if drop_short and len(chunk) < batch_size:
-                continue
-            n_seen += 1
-            if n_seen <= skip_batches:
-                continue
-            event_ids, seg, mask, n_events = self.dataset.packed_row_plan(chunk, L)
-            buf.append((event_ids.astype(np.int32), seg.astype(np.int32), mask))
-            n_ev_buf += n_events
-            if len(buf) == chunk_steps:
-                yield self._stack_packed(buf), n_ev_buf
-                buf, n_ev_buf = [], 0
-        if buf:
-            yield self._stack_packed(buf), n_ev_buf
+        def batch_plans():
+            # The epoch's repacking runs when the first plan is asked for,
+            # so it falls under the first chunk's span.
+            rows = ds.packed_rows_dealt(
+                batch_size, seq_len=L, shuffle=shuffle, seed=seed, n_shards=self.data_shards
+            )
+            n_seen = 0
+            for lo_idx in range(0, len(rows), batch_size):
+                chunk = rows[lo_idx : lo_idx + batch_size]
+                if drop_short and len(chunk) < batch_size:
+                    continue
+                n_seen += 1
+                if n_seen <= skip_batches:
+                    continue
+                event_ids, seg, mask, n_events = ds.packed_row_plan(chunk, L)
+                yield event_ids.astype(np.int32), seg.astype(np.int32), mask, n_events
+
+        for buf in _chunks_under_span(batch_plans(), chunk_steps):
+            yield self._stack_packed([b[:3] for b in buf]), sum(b[3] for b in buf)
 
     @staticmethod
     def _stack_packed(buf: list[tuple]) -> dict:

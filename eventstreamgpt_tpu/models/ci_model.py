@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..data.types import DataModality, EventStreamBatch
 from ..ops import segment_starts
+from ..utils.scopes import scope
 from .config import StructuredEventProcessingMode, StructuredTransformerConfig
 from .model_output import (
     GenerativeOutputLayerBase,
@@ -82,9 +83,12 @@ class ConditionallyIndependentGenerativeOutputLayer(GenerativeOutputLayerBase):
             )
             labels = GenerativeSequenceModelLabels()
         else:
-            loss = (
-                sum(classification_out[0].values()) + sum(regression_out[0].values()) - TTE_LL_overall
-            )
+            with scope("loss"):
+                loss = (
+                    sum(classification_out[0].values())
+                    + sum(regression_out[0].values())
+                    - TTE_LL_overall
+                )
             losses = GenerativeSequenceModelLosses(
                 classification=classification_out[0],
                 regression=regression_out[0],

@@ -29,6 +29,7 @@ from flax import struct
 from ..data.types import DataModality, EventStreamBatch
 from ..distributions import Bernoulli, Categorical
 from ..ops import safe_weighted_avg, weighted_loss
+from ..utils.scopes import scoped
 from .config import (
     StructuredTransformerConfig,
     TimeToEventGenerationHeadType,
@@ -260,6 +261,7 @@ class GenerativeOutputLayerBase(nn.Module):
         self.classification_mode_per_measurement = classification_mode_per_measurement
 
     # ------------------------------------------------------------------ TTE
+    @scoped("heads_tte")
     def get_TTE_outputs(self, batch: EventStreamBatch, encoded: Array, is_generation: bool = False):
         """TTE distribution + average log-likelihood (**not** NLL).
 
@@ -299,6 +301,7 @@ class GenerativeOutputLayerBase(nn.Module):
         return TTE_LL_overall, TTE_dist, TTE_true
 
     # -------------------------------------------------------- classification
+    @scoped("heads_cls")
     def get_classification_outputs(
         self, batch: EventStreamBatch, encoded: Array, valid_measurements: set[str]
     ):
@@ -409,6 +412,7 @@ class GenerativeOutputLayerBase(nn.Module):
         return losses, dists, labels_out
 
     # ------------------------------------------------------------ regression
+    @scoped("heads_reg")
     def get_regression_outputs(
         self,
         batch: EventStreamBatch,

@@ -16,6 +16,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..data.types import DataModality, EventStreamBatch
+from ..utils.scopes import scope
 from .config import StructuredEventProcessingMode, StructuredTransformerConfig
 from .embedding import MeasIndexGroupOptions
 from .model_output import (
@@ -148,11 +149,12 @@ class NestedAttentionGenerativeOutputLayer(GenerativeOutputLayerBase):
             losses = GenerativeSequenceModelLosses()
             labels = GenerativeSequenceModelLabels()
         else:
-            loss = (
-                sum(classification_losses_by_measurement.values())
-                + sum(regression_loss_values.values())
-                - TTE_LL_overall
-            )
+            with scope("loss"):
+                loss = (
+                    sum(classification_losses_by_measurement.values())
+                    + sum(regression_loss_values.values())
+                    - TTE_LL_overall
+                )
             losses = GenerativeSequenceModelLosses(
                 classification=classification_losses_by_measurement,
                 regression=regression_loss_values,

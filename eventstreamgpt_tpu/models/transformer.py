@@ -31,6 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..data.types import EventStreamBatch
 from ..ops import segment_starts
+from ..utils.scopes import scope, scoped
 from .config import StructuredTransformerConfig
 from .embedding import DataEmbeddingLayer
 from .structured_attention import StructuredAttention
@@ -439,14 +440,15 @@ class InnerSelfAttention(nn.Module):
         # whose contract needs it (KV caches, the fused kernels); the einsum
         # fallback contracts directly from (B, S, H, D), which removes the
         # q/k/v/output transposes that dominated the NA dep-graph blocks'
-        # "data formatting" time in the r05 profile (scripts/probe_na.py:
-        # tiny G-wide graphs pay relayout copies comparable to their matmuls).
+        # "data formatting" time in the r05 profile (tiny G-wide graphs pay
+        # relayout copies comparable to their matmuls).
         def split_heads(x):
             return x.reshape(x.shape[:-1] + (num_heads, head_dim))
 
-        query = split_heads(q_proj(hidden_states))  # (B, S, H, D)
-        key = split_heads(k_proj(hidden_states))
-        value = split_heads(v_proj(hidden_states))
+        with scope("attn_proj"):
+            query = split_heads(q_proj(hidden_states))  # (B, S, H, D)
+            key = split_heads(k_proj(hidden_states))
+            value = split_heads(v_proj(hidden_states))
 
         if static_kv_first:
             query = query[:, 1:]
@@ -1017,7 +1019,8 @@ class InnerSelfAttention(nn.Module):
         if outputs.pop("_heads_first_out"):
             attn_output = attn_output.swapaxes(-3, -2)
         attn_output = attn_output.reshape(B, q_len, embed_dim)
-        attn_output = out_proj(attn_output)
+        with scope("attn_proj"):
+            attn_output = out_proj(attn_output)
         resid_dropout = nn.Dropout(rate=float(cfg.resid_dropout), name="resid_dropout")
         attn_output = resid_dropout(attn_output, deterministic=not self.has_rng("dropout"))
         return attn_output, outputs
@@ -1044,16 +1047,21 @@ class InnerAttention(nn.Module):
                 "Only attn layer types 'global' and 'local' exist, but got `config.attention_layers`: "
                 f"{layers}. Select attn layer types from ['global', 'local'] only."
             )
-        normed = nn.LayerNorm(
-            epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="layer_norm"
-        )(hidden_states)
-        return InnerSelfAttention(
-            cfg,
-            attention_type=attention_type,
-            window_size=window_size,
-            is_dep_graph=not self.is_seq,
-            name="attention",
-        )(normed, **kwargs)
+        with scope("norm"):
+            normed = nn.LayerNorm(
+                epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="layer_norm"
+            )(hidden_states)
+        # All that lies between the projections (which name themselves
+        # inside): masks, segment ids, the kernel or the einsum, relayouts.
+        kind = {"global": "attn_global", "local": "attn_local"}[attention_type] if self.is_seq else "dep_graph"
+        with scope(kind):
+            return InnerSelfAttention(
+                cfg,
+                attention_type=attention_type,
+                window_size=window_size,
+                is_dep_graph=not self.is_seq,
+                name="attention",
+            )(normed, **kwargs)
 
 
 class InnerMLP(nn.Module):
@@ -1062,6 +1070,7 @@ class InnerMLP(nn.Module):
     config: StructuredTransformerConfig
 
     @nn.compact
+    @scoped("mlp")
     def __call__(self, hidden_states):
         cfg = self.config
         inner_dim = cfg.intermediate_size if cfg.intermediate_size is not None else 4 * cfg.hidden_size
@@ -1105,9 +1114,12 @@ class InnerBlock(nn.Module):
         hidden_states = attn_output + residual
 
         residual = hidden_states
-        normed = nn.LayerNorm(
-            epsilon=self.config.layer_norm_epsilon, dtype=self.config.compute_dtype, name="layer_norm"
-        )(hidden_states)
+        with scope("norm"):
+            normed = nn.LayerNorm(
+                epsilon=self.config.layer_norm_epsilon,
+                dtype=self.config.compute_dtype,
+                name="layer_norm",
+            )(hidden_states)
         feed_forward = InnerMLP(self.config, name="mlp")(normed)
         hidden_states = residual + feed_forward
 
@@ -1122,6 +1134,7 @@ class ConditionallyIndependentPointProcessInputLayer(nn.Module):
     config: StructuredTransformerConfig
 
     @nn.compact
+    @scoped("embed")
     def __call__(self, batch: EventStreamBatch) -> Array:
         cfg = self.config
         data_embed = DataEmbeddingLayer(
@@ -1534,9 +1547,10 @@ class ConditionallyIndependentPointProcessTransformer(nn.Module):
                 if all_attentions is not None:
                     all_attentions.append(outputs.get("attn_weights"))
 
-        hidden_states = nn.LayerNorm(
-            epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="ln_f"
-        )(hidden_states)
+        with scope("norm"):
+            hidden_states = nn.LayerNorm(
+                epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="ln_f"
+            )(hidden_states)
         if all_hidden is not None:
             all_hidden.append(hidden_states)
 
@@ -1584,6 +1598,7 @@ class NestedAttentionPointProcessInputLayer(nn.Module):
     config: StructuredTransformerConfig
 
     @nn.compact
+    @scoped("embed")
     def __call__(
         self,
         batch: EventStreamBatch,
@@ -1906,9 +1921,10 @@ class NestedAttentionPointProcessTransformer(nn.Module):
                         extra["dep_graph_module"].get("attn_weights")
                     )
 
-        hidden_states = nn.LayerNorm(
-            epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="ln_f"
-        )(hidden_states)
+        with scope("norm"):
+            hidden_states = nn.LayerNorm(
+                epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="ln_f"
+            )(hidden_states)
 
         if all_hidden is not None:
             all_hidden.append(hidden_states)

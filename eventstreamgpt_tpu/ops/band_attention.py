@@ -136,8 +136,8 @@ def dep_graph_attention(
     overhead: XLA tiles each (Q, S) logits plane as an MXU matmul against
     the ``(B·L, H, G, d)`` layout and pays relayout copies comparable to
     the matmuls themselves (~1.5 ms/step at the bench shape) plus lost
-    loop fusion in the backward (~1.1 ms) — the ``scripts/probe_na.py``
-    attribution, VERDICT r05 "Next round" #6.
+    loop fusion in the backward (~1.1 ms) — the r05 op-level attribution,
+    VERDICT r05 "Next round" #6.
 
     This formulation contains **no dot_general at all**: logits and the
     probability-weighted value sum are broadcast-multiply + lane-reduction

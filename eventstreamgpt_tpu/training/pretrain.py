@@ -55,6 +55,7 @@ from ..models.config import (
 )
 from ..models.na_model import NAPPTForGenerativeSequenceModeling
 from ..utils import config_dataclass
+from ..utils.scopes import host_span, scope
 from .checkpoint import TrainCheckpointManager, save_pretrained
 from .generative_metrics import GenerativeMetrics
 from .optimizer import build_optimizer
@@ -263,11 +264,13 @@ def _train_step_body(model, tx, with_health: bool = False) -> Callable:
             return out.loss
 
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with scope("optimizer"):
+            updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt_state)
         if with_health:
-            health = jnp.stack([loss, optax.global_norm(grads)]).astype(jnp.float32)
+            with scope("health"):
+                health = jnp.stack([loss, optax.global_norm(grads)]).astype(jnp.float32)
             return new_state, (loss, health)
         return new_state, loss
 
@@ -362,7 +365,9 @@ def make_chunked_train_step(
 
     def chunk_step(state: TrainState, arrays: dict, plans: dict, rng: jax.Array):
         def scan_body(st, plan):
-            st, out = body(st, collate(arrays, plan), rng)
+            with scope("collate"):
+                batch = collate(arrays, plan)
+            st, out = body(st, batch, rng)
             return st, out
 
         return jax.lax.scan(scan_body, state, plans)
@@ -968,6 +973,12 @@ def train(
                 rec["lr"] = float(lr_schedule(rec["step"] // accum))  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
                 log_record(rec)
 
+            def flush_logs(pending: list) -> None:
+                with host_span("log_flush"):
+                    for rec in pending:
+                        finalize_record(rec)
+                    pending.clear()
+
             def handle_window(step_in_epoch: int, stepped: int, pending: list):
                 """Shared per-dispatch bookkeeping: logs, checkpoints, stop.
 
@@ -990,25 +1001,25 @@ def train(
                     # rollback target. Checkpointing IS a host readback; the
                     # cadence (ckpt_every) bounds how often the pipeline
                     # drains.
-                    if health_mon.vetted_save(
-                        ckpt_mgr,
-                        global_step,
-                        lambda: serialization.to_state_dict(jax.device_get(state)),  # graftcheck: allow GC001 -- checkpoint readback + sentinel inspection, cadence-bounded
-                        {
-                            "epoch": epoch,
-                            "epoch_complete": False,
-                            "step_in_epoch": step_in_epoch,
-                        },
-                        epoch=epoch,
-                        progress=step_in_epoch,
-                    ):
+                    with host_span("checkpoint"):
+                        saved = health_mon.vetted_save(
+                            ckpt_mgr,
+                            global_step,
+                            lambda: serialization.to_state_dict(jax.device_get(state)),  # graftcheck: allow GC001 -- checkpoint readback + sentinel inspection, cadence-bounded
+                            {
+                                "epoch": epoch,
+                                "epoch_complete": False,
+                                "step_in_epoch": step_in_epoch,
+                            },
+                            epoch=epoch,
+                            progress=step_in_epoch,
+                        )
+                    if saved:
                         # The device_get above already drained the pipeline, so
                         # persisting the buffered window records here costs no
                         # extra sync — and bounds what a SIGKILL-style preemption
                         # can lose from train_log.jsonl to ckpt_every steps.
-                        for rec in pending:
-                            finalize_record(rec)
-                        pending.clear()
+                        flush_logs(pending)
                 if step_guard is not None and step_guard.armed:
                     if chunked_step is None or stepped == chunk_steps:
                         # Steady state: the watched step function must not
@@ -1065,11 +1076,12 @@ def train(
                         ):
                             jax.profiler.start_trace(str(profile_dir))
                             profiling = True
-                        if with_health:
-                            state, (losses, healths) = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
-                            health_mon.record(healths)
-                        else:
-                            state, losses = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
+                        with host_span("dispatch"):
+                            if with_health:
+                                state, (losses, healths) = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
+                                health_mon.record(healths)
+                            else:
+                                state, losses = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                         global_step += k
                         step_in_epoch += k
                         epoch_progress = step_in_epoch
@@ -1109,11 +1121,12 @@ def train(
                             if profile_dir and not profiling and 10 <= global_step < 20:
                                 jax.profiler.start_trace(str(profile_dir))
                                 profiling = True
-                            if with_health:
-                                state, (loss, health) = train_step(state, batch, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
-                                health_mon.record(health)
-                            else:
-                                state, loss = train_step(state, batch, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
+                            with host_span("dispatch"):
+                                if with_health:
+                                    state, (loss, health) = train_step(state, batch, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
+                                    health_mon.record(health)
+                                else:
+                                    state, loss = train_step(state, batch, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                             global_step += 1
                             epoch_progress = step_in_epoch + 1
                             faults.maybe_sigterm(global_step, shutdown)
@@ -1132,8 +1145,7 @@ def train(
                     finally:
                         batch_iter.close()
             finally:
-                for rec in pending_logs:
-                    finalize_record(rec)
+                flush_logs(pending_logs)
             if profiling:
                 jax.profiler.stop_trace()
                 profiling = False
@@ -1172,19 +1184,20 @@ def train(
 
             # Tuning eval (loss-only under the default pretraining metrics config).
             rng, eval_key = jax.random.split(rng)  # graftcheck: allow GC003 -- train consumptions above only fold_in; this split advances the base stream
-            tuning_metrics = evaluate(
-                eval_step,
-                state.params,
-                tuning_pyd,
-                oc.validation_batch_size,
-                config,
-                cfg.pretraining_metrics_config,
-                Split.TUNING,
-                mesh=mesh,
-                key=eval_key,
-                place_batch=place_batch,
-                device_data=device_tuning,
-            )
+            with host_span("eval"):
+                tuning_metrics = evaluate(
+                    eval_step,
+                    state.params,
+                    tuning_pyd,
+                    oc.validation_batch_size,
+                    config,
+                    cfg.pretraining_metrics_config,
+                    Split.TUNING,
+                    mesh=mesh,
+                    key=eval_key,
+                    place_batch=place_batch,
+                    device_data=device_tuning,
+                )
             tuning_loss = tuning_metrics.get("tuning_loss", float("nan"))
             log_record(
                 {
@@ -1202,11 +1215,12 @@ def train(
             )
 
             if tail_healthy:
-                ckpt_mgr.save(
-                    global_step,
-                    serialization.to_state_dict(jax.device_get(state)),  # graftcheck: allow GC001 -- epoch-end checkpoint readback, pipeline already drained by eval
-                    metadata={"epoch": epoch, "epoch_complete": True},
-                )
+                with host_span("checkpoint"):
+                    ckpt_mgr.save(
+                        global_step,
+                        serialization.to_state_dict(jax.device_get(state)),  # graftcheck: allow GC001 -- epoch-end checkpoint readback, pipeline already drained by eval
+                        metadata={"epoch": epoch, "epoch_complete": True},
+                    )
 
             # Early stopping (reference EarlyStopping(monitor="tuning_loss")).
             if np.isfinite(tuning_loss) and tuning_loss < best_tuning_loss - 1e-12:
