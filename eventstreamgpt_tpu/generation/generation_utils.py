@@ -40,6 +40,7 @@ from ..data.types import EventStreamBatch
 from ..models.config import StructuredEventProcessingMode, StructuredTransformerConfig
 from ..models.transformer import NAPast, init_kv_caches, time_from_deltas
 from ..ops.tensor_ops import take_event
+from ..parallel.context import current_kernel_mesh, kernel_mesh
 from .sampling import append_new_event, sample_predictions, update_last_event_data
 from .stopping_criteria import MaxLengthCriteria, StoppingCriteriaList
 
@@ -322,16 +323,20 @@ def generate(
         else _generate_na
     )
     try:
-        result = gen(
-            model,
-            params,
-            batch,
-            config,
-            key,
-            max_new_events,
-            use_cache,
-            stopping_criteria=stopping_criteria,
-        )
+        # The step programs are traced inside `kernel_mesh`, as the trainers'
+        # steps are: GSPMD cannot partition a Mosaic call, so the model's
+        # kernels (the embedding's plane) run once per batch shard there.
+        with kernel_mesh(mesh if mesh is not None else current_kernel_mesh()):
+            result = gen(
+                model,
+                params,
+                batch,
+                config,
+                key,
+                max_new_events,
+                use_cache,
+                stopping_criteria=stopping_criteria,
+            )
     except Exception:
         # A non-finite prompt can crash generation itself; surface the clear
         # validation error instead of the downstream failure (ADVICE r04).
@@ -403,6 +408,9 @@ def _model_config_signature(model, config: StructuredTransformerConfig) -> str:
 
 
 def _cached_steps(cache_key: tuple, build):
+    # The kernel mesh is read while a step is traced, and a trace is cached
+    # on the step's function object: it is part of what the steps are.
+    cache_key = cache_key + (current_kernel_mesh(),)
     hit = _STEP_CACHE.pop(cache_key, None)
     if hit is not None:
         # Re-insert on hit: eviction below is LRU, so steady-state shapes

@@ -15,6 +15,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .impl_select import LANE, round_up
+from .pallas_multihot import weighted_multihot
+
 
 def str_summary(T) -> str:
     """Returns a string summary of an array for debugging purposes.
@@ -135,64 +138,76 @@ def weighted_loss(loss_per_event: jnp.ndarray, event_mask: jnp.ndarray) -> jnp.n
     return safe_weighted_avg(loss_per_subject, (events_per_subject > 0))[0]
 
 
-# Largest (N, vocab) multi-hot plane the matmul backward may materialize;
-# above this the XLA scatter backward is kept (the plane would thrash HBM).
-_BAG_MATMUL_BWD_MAX_PLANE = 512 * 1024 * 1024
-# Narrowest table dim where the matmul backward pays for itself: the scatter
-# cost scales with the embedding dim, the multihot build does not. Measured
-# on-chip at N=8192/M=24/V=4096: dim 1024 → 8.05 ms scatter vs 1.82 ms
-# matmul; dim 256 → the builds cost more than the (small) scatter.
-_BAG_MATMUL_BWD_MIN_DIM = 512
+# Who takes the plane path (`_bag_2d`: the bag as two MXU matmuls against the
+# weighted-multihot plane of `ops.pallas_multihot`) and who keeps the gather is
+# read at trace time from static shapes, and from nothing else. Largest
+# (N, Vp) plane that may be built and saved for the backward:
+_BAG_PLANE_MAX_BYTES = 512 * 1024 * 1024
+# Narrowest table that takes the plane. TPU v5e, bf16, M=24, V=4057 (the one
+# vocabulary and dtype read), device ms of forward / table gradient (my chip
+# run 3, PR 27; PERF.md section 6). At N=16384, plane against gather +
+# scatter: D=1024 1.80 / 1.80 against 3.04 / 16.14; D=512 1.43 / 1.43 against
+# 2.24 / 6.90; D=256 1.26 / 1.25 against 1.60 / 4.42; D=128 (and 64, 32: one
+# lane tile) 1.24 / 1.24 against 0.90 / 2.94. So in training the plane wins
+# at every width read (D=128: 2.48 against 3.84 for the pair): the width term
+# is not there for training speed. It is there for callers that run the
+# forward alone, where below 256 the gather is the faster (0.90 against
+# 1.24), and for the tiny float32 models of the tests and the rehearsals
+# (D=32), whose pinned losses hold to the bit on the gather. At D=1024 there
+# is no least N: N=2048 0.23 / 0.25 against 0.33 / 1.92; N=128 0.018 / 0.028
+# against 0.020 / 0.162; N=64 0.010 / 0.023 against 0.010 / 0.105 (static
+# codes, N=64 and M=8: 0.008 / 0.020 against 0.006 / 0.061). A forward alone
+# gains nothing at those N (a serving engine's decode step: 0.010 against
+# 0.010) and now holds a Mosaic call; no benchmark cell runs a serving
+# program yet (PERF.md section 7).
+_BAG_PLANE_MIN_DIM = 256
 
 
-def _weighted_multihot(indices: jnp.ndarray, weights: jnp.ndarray, vocab: int) -> jnp.ndarray:
-    """``mh[n, v] = Σ_m weights[n, m]·(indices[n, m] == v)`` without ever
-    materializing the ``(N, M, vocab)`` one-hot (a fori accumulation over the
-    small M axis keeps peak memory at one ``(N, vocab)`` plane)."""
-    # jnp arrays up front: the loop body indexes with a traced counter, which
-    # host numpy inputs (eager callers) cannot do.
-    # Clip to the table range: the forward gathers with mode="clip", so an
-    # out-of-range index reads the edge row and its cotangent must credit
-    # that same row — an unclipped equality match would silently drop it
-    # (the XLA scatter backward credits the clipped row; parity is tested).
-    indices = jnp.clip(jnp.asarray(indices), 0, vocab - 1)
-    weights = jnp.asarray(weights)
-    iota = jnp.arange(vocab, dtype=indices.dtype)[None, :]
-    n = indices.shape[0]
+def _plane_ok(table: jnp.ndarray, n_rows: int) -> bool:
+    plane = n_rows * round_up(table.shape[0], LANE) * table.dtype.itemsize
+    return plane <= _BAG_PLANE_MAX_BYTES and table.shape[1] >= _BAG_PLANE_MIN_DIM
 
-    def body(m, acc):
-        return acc + jnp.where(iota == indices[:, m][:, None], weights[:, m][:, None], 0)
 
-    return jax.lax.fori_loop(0, indices.shape[1], body, jnp.zeros((n, vocab), weights.dtype))
+def _plane_dot(spec: str, mh: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """A contraction against the plane, float32 out. The chip's default
+    precision truncates float32 operands to bf16, a lower precision than a
+    float32 model states: float32 runs at HIGHEST (as `vocab_gather` does)."""
+    precision = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    return jnp.einsum(spec, mh, x, precision=precision, preferred_element_type=jnp.float32)
+
+
+def _table_grad(mh: jnp.ndarray, g: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """``mh.T (Vp, N) @ g (N, D)`` in float32, cut to the table's rows.
+    Duplicate indices accumulate in fp32 on the MXU; XLA's native backward
+    is a serialized scatter-add of N*M rows."""
+    return _plane_dot("nv,nd->vd", mh, g)[: table.shape[0]]
 
 
 @jax.custom_vjp
 def _bag_2d(table: jnp.ndarray, indices: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
-    """``(N, M)`` bag with a matmul table-gradient (see `embedding_bag`)."""
-    gathered = jnp.take(table, indices, axis=0, mode="clip")
-    return jnp.einsum("nmd,nm->nd", gathered, weights)
+    """``(N, M)`` bag through the plane, forward and table gradient (see
+    `embedding_bag`)."""
+    return _bag_2d_fwd(table, indices, weights)[0]
 
 
 def _bag_2d_fwd(table, indices, weights):
-    return _bag_2d(table, indices, weights), (table, indices, weights)
+    vocab = table.shape[0]
+    mh = weighted_multihot(indices, weights, vocab)
+    # Zero rows under the plane's lane padding: 8 MB of table copied, where
+    # cutting the plane to (N, V) would copy the plane.
+    padded = jnp.pad(table, ((0, mh.shape[1] - vocab), (0, 0)))
+    out = _plane_dot("nv,vd->nd", mh, padded).astype(table.dtype)
+    return out, (table, indices, mh)
 
 
 def _bag_2d_bwd(res, g):
-    table, indices, weights = res
-    # Table gradient as a single MXU contraction: mhᵀ (V, N) @ g (N, D).
-    # XLA's native backward is a serialized scatter-add of N·M rows, which
-    # profiled as the train step's single largest op at production width
-    # (~8 ms vs ~1.8 ms for this path at hidden 1024; scripts/probe_feed.py
-    # lineage). Duplicate indices accumulate in fp32 via the matmul.
-    mh = _weighted_multihot(indices, weights.astype(g.dtype), table.shape[0])
-    d_table = jnp.einsum(
-        "nv,nd->vd", mh, g, preferred_element_type=jnp.float32
-    ).astype(table.dtype)
+    table, indices, mh = res
+    d_table = _table_grad(mh, g, table).astype(table.dtype)
     # Weight cotangent re-gathers rather than saving the (N, M, D) residual;
     # when weights are not on a differentiable path (the usual case — they
     # come from batch values), XLA dead-code-eliminates this entirely.
     d_w = jnp.einsum("nmd,nd->nm", jnp.take(table, indices, axis=0, mode="clip"), g).astype(
-        weights.dtype
+        mh.dtype
     )
     return d_table, None, d_w
 
@@ -213,15 +228,15 @@ def _grouped_bag_2d_fwd(table, indices, weights):
 
 def _grouped_bag_2d_bwd(res, g):
     table, indices, weights = res
-    # One multihot+matmul per group (G is the dep-graph depth, 2-4): the
+    # One plane+matmul per group (G is the dep-graph depth, 2-4): the
     # per-(token, slot) cotangent is a D-vector, so a single flattened
-    # multihot would need an (N·M, V) plane; per-group planes stay (N, V).
+    # multihot would need an (N·M, V) plane; per-group planes stay (N, Vp).
+    # The forward keeps its one gather: G plane builds are not clearly
+    # cheaper than it, and no benchmark cell runs this model to judge.
     d_table = jnp.zeros(table.shape, jnp.float32)
     for grp in range(weights.shape[1]):
-        mh = _weighted_multihot(indices, weights[:, grp, :].astype(g.dtype), table.shape[0])
-        d_table = d_table + jnp.einsum(
-            "nv,nd->vd", mh, g[:, grp, :], preferred_element_type=jnp.float32
-        )
+        mh = weighted_multihot(indices, weights[:, grp, :], table.shape[0])
+        d_table = d_table + _table_grad(mh, g[:, grp, :], table)
     d_w = jnp.einsum(
         "nmd,ngd->ngm", jnp.take(table, indices, axis=0, mode="clip"), g
     ).astype(weights.dtype)
@@ -231,26 +246,27 @@ def _grouped_bag_2d_bwd(res, g):
 _grouped_bag_2d.defvjp(_grouped_bag_2d_fwd, _grouped_bag_2d_bwd)
 
 
-def _matmul_bwd_ok(table: jnp.ndarray, n_rows: int) -> bool:
-    plane = n_rows * table.shape[0] * table.dtype.itemsize
-    return plane <= _BAG_MATMUL_BWD_MAX_PLANE and table.shape[1] >= _BAG_MATMUL_BWD_MIN_DIM
-
-
 def embedding_bag(
     table: jnp.ndarray,
     indices: jnp.ndarray,
     weights: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Sum-mode embedding bag with padding index 0, as ``take`` + weighted sum.
+    """Sum-mode embedding bag with padding index 0.
 
     Equivalent to ``torch.nn.EmbeddingBag(mode="sum", padding_idx=0)`` with
     ``per_sample_weights``: rows with index 0 contribute nothing regardless of
     weight (reference behavior relied on at ``data_embedding_layer.py:524``).
 
-    The table gradient is computed by a weighted-multihot matmul instead of
-    XLA's scatter-add whenever the ``(N, vocab)`` plane fits a fixed budget —
-    4.4x faster at production width on TPU (the scatter was the width
-    profile's largest single op).
+    Two formulations, chosen from static shapes (`_plane_ok`). Wide tables
+    whose ``(N, Vp)`` plane fits a fixed budget: the weighted-multihot plane
+    is built once (`ops.pallas_multihot.weighted_multihot`), the forward is
+    ``mh @ table``, the table gradient ``mh.T @ g`` from the saved plane; slots
+    of one event that hold the same index are summed in float32 before the
+    plane is rounded. Otherwise ``take`` + a weighted sum, XLA's scatter-add
+    backward. On a TPU v5e at N=16384 / M=24 / V=4057 / D=1024 in bf16,
+    forward + table gradient take 2.6 ms on the plane, 13.5 ms with the
+    gather forward and a plane built in M passes (what this replaced), 19.0 ms
+    with gather and scatter (PERF.md section 6, PR 27).
 
     Args:
         table: ``(n_embeddings, dim)`` embedding table.
@@ -264,7 +280,7 @@ def embedding_bag(
     w = pad_mask if weights is None else weights.astype(table.dtype) * pad_mask
     lead = indices.shape[:-1]
     n = math.prod(lead)
-    if _matmul_bwd_ok(table, n):
+    if _plane_ok(table, n):
         out = _bag_2d(table, indices.reshape(n, -1), w.reshape(n, -1))
         return out.reshape(lead + (table.shape[-1],))
     gathered = jnp.take(table, indices, axis=0, mode="clip")  # (..., M, dim)
@@ -282,7 +298,7 @@ def grouped_embedding_bag(
     group-specific weights; gathering once and contracting against the
     ``(..., G, M)`` weights computes the identical result with a G-fold
     smaller gather and a G-fold smaller backward into the table (a per-group
-    multihot matmul under the same budget gate as `embedding_bag`). Padding
+    plane and matmul under the same gate as `embedding_bag`). Padding
     index 0 contributes nothing, as in `embedding_bag`; weights are cast to
     the table dtype so mixed precision is preserved regardless of the
     weights' dtype.
@@ -299,7 +315,7 @@ def grouped_embedding_bag(
     w = group_weights.astype(table.dtype) * pad_mask[..., None, :]
     lead = indices.shape[:-1]
     n = math.prod(lead)
-    if _matmul_bwd_ok(table, n):
+    if _plane_ok(table, n):
         out = _grouped_bag_2d(
             table, indices.reshape(n, -1), w.reshape((n,) + w.shape[-2:])
         )

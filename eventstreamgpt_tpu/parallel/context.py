@@ -87,7 +87,8 @@ def per_batch_shard(fn, *args):
     batch-major flattened row) dimension first; ``fn`` must be independent
     across it. Every mesh axis is manual inside the call (Mosaic refuses a
     partially-manual context), so operands are replicated over any axis
-    other than ``data``/``fsdp``.
+    other than ``data``/``fsdp``; where the leading dimension does not
+    divide by the batch shards, over those too.
     """
     import jax
     from jax.sharding import PartitionSpec as P
@@ -99,12 +100,11 @@ def per_batch_shard(fn, *args):
     n_shards = 1
     for a in axes:
         n_shards *= mesh.shape[a]
-    bad = [x.shape for x in jax.tree_util.tree_leaves(args) if x.shape[0] % n_shards]
-    if bad:
-        raise ValueError(
-            f"a Pallas kernel's leading (batch) dimension must divide by the "
-            f"{n_shards} batch shards of mesh axes {axes}; got shapes {bad}"
-        )
+    if any(x.shape[0] % n_shards for x in jax.tree_util.tree_leaves(args)):
+        # Rows that do not divide over the batch shards are no sharded batch
+        # (a serving engine's replicated prefill group): replicated specs,
+        # every device runs the whole call.
+        axes = None
     spec = lambda x: P(axes, *([None] * (x.ndim - 1)))  # noqa: E731
     out_shape = jax.eval_shape(fn, *args)
     return jax.shard_map(

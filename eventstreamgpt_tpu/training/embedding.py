@@ -27,6 +27,7 @@ from ..models.transformer import (
     NestedAttentionPointProcessTransformer,
 )
 from ..ops.tensor_ops import safe_masked_max, safe_weighted_avg
+from ..parallel.context import kernel_mesh
 from .fine_tuning import FinetuneConfig, init_from_pretrained_encoder
 from .pretrain import data_parallel_mesh, replicate, shard_batch
 
@@ -90,14 +91,15 @@ def get_embeddings(cfg: FinetuneConfig) -> dict[str, Path]:
     # the encoder subtree into the encoder-only template.
     params = init_from_pretrained_encoder(template, cfg.pretrained_weights_fp)
 
-    embed_step = jax.jit(
-        lambda params, batch: embed_batch(model, params, config, batch, pooling_method)
-    )
-
     # Batch-shard extraction over a data mesh (replicated params): the
     # encoder forward runs on every chip (VERDICT r02 missing #1).
     mesh = data_parallel_mesh(oc.validation_batch_size)
     params = replicate(params, mesh)
+
+    @jax.jit
+    def embed_step(params, batch):
+        with kernel_mesh(mesh):  # traced as the trainers' steps are
+            return embed_batch(model, params, config, batch, pooling_method)
 
     out_dir = Path(cfg.load_from_model_dir) / "embeddings" / (cfg.task_df_name or "all")
     written: dict[str, Path] = {}

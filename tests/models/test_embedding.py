@@ -311,3 +311,107 @@ class TestStaticModes:
         out1 = jitted(params, batch)
         out2 = layer.apply(params, batch)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
+
+
+class TestPlanePath:
+    """`ops.embedding_bag` reads its formulation from static shapes (wide
+    tables at training sizes: one weighted-multihot plane and two matmuls;
+    otherwise the gather). The layer must not be able to tell."""
+
+    @pytest.mark.parametrize(
+        "mode_kwargs",
+        [{}, {"categorical_embedding_dim": 6, "numerical_embedding_dim": 5}],
+        ids=["joint", "split"],
+    )
+    def test_layer_is_the_same_on_the_plane_as_on_the_gather(self, monkeypatch, mode_kwargs):
+        from eventstreamgpt_tpu.ops import tensor_ops
+
+        batch = make_batch()
+        layer = DataEmbeddingLayer(
+            n_total_embeddings=12,
+            out_dim=4,
+            static_embedding_mode=StaticEmbeddingMode.SUM_ALL,
+            static_weight=1 / 3,
+            dynamic_weight=2 / 3,
+            do_normalize_by_measurement_index=True,
+            **mode_kwargs,
+        )
+        params = init_layer(layer, batch)
+        cot = jnp.asarray(np.random.default_rng(3).normal(size=(2, 3, 4)).astype(np.float32))
+
+        plane_calls = []
+        bag_2d = tensor_ops._bag_2d
+        monkeypatch.setattr(
+            tensor_ops, "_bag_2d", lambda *a: plane_calls.append(1) or bag_2d(*a)
+        )
+
+        def run(min_dim):
+            monkeypatch.setattr(tensor_ops, "_BAG_PLANE_MIN_DIM", min_dim)
+            del plane_calls[:]
+
+            def apply(p):
+                out = layer.apply(p, batch)
+                return (out * cot).sum(), out
+
+            (_, out), grads = jax.value_and_grad(apply, has_aux=True)(params)
+            return out, grads, len(plane_calls)
+
+        out_g, grads_g, calls_g = run(10**9)
+        out_p, grads_p, calls_p = run(1)
+        # Dynamic data once per table; static codes have no values, so one bag.
+        assert calls_g == 0 and calls_p == (3 if mode_kwargs else 2)
+        assert not np.asarray(out_p[1, 2]).any()  # the masked event stays zero
+        np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_g), rtol=1e-6, atol=1e-6)
+        flat_g = jax.tree_util.tree_leaves_with_path(grads_g)
+        flat_p = jax.tree_util.tree_leaves(grads_p)
+        assert len(flat_g) == len(flat_p) > 0
+        for (path, g), p in zip(flat_g, flat_p):
+            assert np.abs(np.asarray(g)).sum() > 0, path
+            np.testing.assert_allclose(
+                np.asarray(p), np.asarray(g), rtol=1e-6, atol=1e-6, err_msg=str(path)
+            )
+
+    @pytest.mark.parametrize("site", ["generate", "engine"])
+    def test_programs_over_a_mesh_trace_the_plane_inside_kernel_mesh(self, monkeypatch, site):
+        """GSPMD cannot partition the plane's Mosaic call: `generate(mesh=...)`
+        and an engine with a mesh trace the model inside `kernel_mesh`, as the
+        trainers do, and sample what the one-device program samples."""
+        from eventstreamgpt_tpu.generation import generate
+        from eventstreamgpt_tpu.ops import tensor_ops
+        from eventstreamgpt_tpu.parallel import current_kernel_mesh
+        from eventstreamgpt_tpu.training.sharding import make_mesh
+        from tests.test_spec import build, engine_for, mixed_requests
+
+        monkeypatch.setattr(tensor_ops, "_BAG_PLANE_MIN_DIM", 1)
+        monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas_interpret")
+        traced_under = []
+        multihot = tensor_ops.weighted_multihot
+        monkeypatch.setattr(
+            tensor_ops,
+            "weighted_multihot",
+            lambda *a: traced_under.append(current_kernel_mesh()) or multihot(*a),
+        )
+        config, model, params, prompt, _ = build("ci")
+
+        def run(mesh):
+            del traced_under[:]
+            if site == "generate":
+                key = jax.random.PRNGKey(3)
+                return [generate(model, params, prompt, config, key, max_new_events=2, mesh=mesh)]
+            results = engine_for(model, params, config, prompt, mesh=mesh).run(
+                mixed_requests(prompt)
+            )
+            return [r.batch for r in sorted(results, key=lambda r: r.request_id)]
+
+        want = run(None)
+        assert traced_under and all(m is None for m in traced_under)
+        mesh = make_mesh(2, 1)
+        got = run(mesh)
+        assert traced_under and all(m is mesh for m in traced_under)
+        for g, w in zip(got, want, strict=True):
+            np.testing.assert_array_equal(
+                np.asarray(g.dynamic_indices), np.asarray(w.dynamic_indices)
+            )
+            np.testing.assert_allclose(
+                np.asarray(g.time_delta), np.asarray(w.time_delta), rtol=1e-5, atol=1e-6
+            )

@@ -132,6 +132,75 @@ def test_vocab_gather(one_chip, direction):
     _compile(fwd if direction == "forward" else grad, z, ci)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_embedding_bag_plane(one_chip, monkeypatch, direction, dtype):
+    """The benchmark cells' event embedding (16,384 slots of 24 measurements,
+    width 1024): the plane kernel, and no gather of ``(N, M, D)`` rows."""
+    from eventstreamgpt_tpu.ops import embedding_bag
+
+    monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas")
+    n, m, d = 16384, 24, 1024
+    table = jax.ShapeDtypeStruct((VOCAB, d), dtype, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((n, m), jnp.int32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((n, m), dtype, sharding=one_chip)
+    fwd = lambda t, i, w_: embedding_bag(t, i, w_)  # noqa: E731
+    grad = lambda t, i, w_: jax.grad(  # noqa: E731
+        lambda a: fwd(a, i, w_).astype(jnp.float32).sum()
+    )(t)
+    text = _compile(fwd if direction == "forward" else grad, table, idx, w)
+    assert "_multihot_2d" in text
+    assert f"[{n},{m},{d}]" not in text
+
+
+@pytest.mark.parametrize("program", ["rows_sharded", "rows_replicated", "generate"])
+def test_embedding_bag_plane_over_four_chips(topo, monkeypatch, program):
+    """GSPMD refuses a Mosaic call in a program over several chips, so one
+    that holds the plane's kernel is traced inside `kernel_mesh`, where the
+    kernel runs once per batch shard, or whole on every chip for rows that
+    do not divide (an engine's replicated prefill group). ``generate``: the
+    whole program of `generate(mesh=...)` for a CI model of width 256."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from eventstreamgpt_tpu.ops import embedding_bag
+    from eventstreamgpt_tpu.parallel import kernel_mesh
+
+    monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas")
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    whole = NamedSharding(mesh, P())
+    by_row = lambda x: NamedSharding(mesh, P("data", *([None] * (x.ndim - 1))))  # noqa: E731
+    sds = lambda x, sharding: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)  # noqa: E731
+    if program == "generate":
+        from __graft_entry__ import _make_model_and_batch
+        from eventstreamgpt_tpu.generation.generation_utils import _build_ci_steps
+
+        model, batch = _make_model_and_batch(batch_size=8, seq_len=8, hidden=256, vocab=512)
+        params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), batch))
+        args = (
+            jax.tree_util.tree_map(lambda x: sds(x, whole), params),
+            jax.tree_util.tree_map(lambda x: sds(x, by_row(x)), batch),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole),
+        )
+        build = lambda: _build_ci_steps(model, model.config, 8, 8, 2)["generate_program"]  # noqa: E731
+    else:
+        n = 4096 if program == "rows_sharded" else 6
+        idx = jax.ShapeDtypeStruct((n, 24), jnp.int32)
+        idx = sds(idx, by_row(idx) if program == "rows_sharded" else whole)
+        args = (
+            jax.ShapeDtypeStruct((VOCAB, 1024), jnp.bfloat16, sharding=whole),
+            idx,
+            jax.ShapeDtypeStruct((n, 24), jnp.bfloat16, sharding=idx.sharding),
+        )
+        build = lambda: jax.jit(lambda *a: embedding_bag(*a))  # noqa: E731
+    with pytest.raises(Exception, match="cannot be automatically partitioned"):
+        build().lower(*args).compile()
+    with kernel_mesh(mesh):
+        text = build().lower(*args).compile().as_text()
+    assert "_multihot_2d" in text
+
+
 @pytest.mark.parametrize("direction", ["forward", "gradient"])
 def test_flash_attention_s1024_d128(one_chip, direction):
     """The flash call of models/transformer.py's ``use_pallas`` branch with

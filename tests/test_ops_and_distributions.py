@@ -103,9 +103,9 @@ class TestTensorOps:
         from eventstreamgpt_tpu.ops import tensor_ops
         from eventstreamgpt_tpu.ops.tensor_ops import grouped_embedding_bag
 
-        # The production gate only engages the matmul backward at wide dims;
+        # The production gate only engages the plane path at wide dims;
         # force it on so the tiny test shape exercises the custom vjp.
-        monkeypatch.setattr(tensor_ops, "_BAG_MATMUL_BWD_MIN_DIM", 1)
+        monkeypatch.setattr(tensor_ops, "_BAG_PLANE_MIN_DIM", 1)
 
         n_emb, dim, B, L, M, G = 30, 8, 2, 5, 6, 3
         table = jnp.asarray(RNG.normal(size=(n_emb, dim)).astype(np.float32))
@@ -144,7 +144,7 @@ class TestTensorOps:
         from eventstreamgpt_tpu.ops import tensor_ops
         from eventstreamgpt_tpu.ops.tensor_ops import grouped_embedding_bag
 
-        monkeypatch.setattr(tensor_ops, "_BAG_MATMUL_BWD_MIN_DIM", 1)
+        monkeypatch.setattr(tensor_ops, "_BAG_PLANE_MIN_DIM", 1)
 
         n_emb, dim, B, M, G = 12, 4, 3, 5, 2
         table = jnp.asarray(RNG.normal(size=(n_emb, dim)).astype(np.float32))
@@ -174,6 +174,139 @@ class TestTensorOps:
             # The edge row must actually receive credit for the clipped slots.
             assert np.abs(np.asarray(rt[-1])).sum() > 0
             np.testing.assert_allclose(np.asarray(gt), np.asarray(rt), rtol=1e-4, atol=1e-5)
+
+    # name: (N, M, V, D, dtype, edit of the random indices)
+    PLANE_CASES = {
+        "repeated_index": (12, 6, 40, 8, np.float32, lambda i, v: i.__setitem__((0, slice(0, 3)), 7)),
+        "padding_with_weight": (12, 6, 40, 8, np.float32, lambda i, v: i.__setitem__((slice(0, 4), 1), 0)),
+        "out_of_range": (12, 6, 40, 8, np.float32, lambda i, v: i.__setitem__(([0, 5], [0, 3]), [v, v + 9])),
+        "ragged_vocab": (20, 5, 200, 8, np.float32, lambda i, v: None),
+        "ragged_rows": (300, 4, 130, 8, np.float32, lambda i, v: None),
+        "bf16": (37, 6, 150, 16, "bfloat16", lambda i, v: i.__setitem__((1, slice(0, 2)), 9)),
+    }
+
+    @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+    @pytest.mark.parametrize("case", list(PLANE_CASES))
+    def test_embedding_bag_plane_path_matches_gather(self, monkeypatch, case, impl):
+        """Forward, ``d_table`` and ``d_w`` of the plane path (one
+        weighted-multihot plane, two matmuls) == ``take`` + einsum in
+        float32, in both formulations of the plane builder."""
+        from eventstreamgpt_tpu.ops import tensor_ops
+
+        monkeypatch.setattr(tensor_ops, "_BAG_PLANE_MIN_DIM", 1)
+        monkeypatch.setenv("ESGPT_PALLAS_IMPL", impl)
+        n, m, v, d, dtype, edit = self.PLANE_CASES[case]
+        rng = np.random.default_rng(27)
+        idx = rng.integers(1, v, size=(n, m))
+        edit(idx, v)
+        indices = jnp.asarray(idx)
+        table = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32)).astype(dtype)
+        weights = jnp.asarray(rng.normal(size=(n, m)).astype(np.float32)).astype(dtype)
+        cot = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+
+        def ref(t, w):
+            t, w = t.astype(jnp.float32), w.astype(jnp.float32)
+            gathered = jnp.take(t, indices, axis=0, mode="clip")
+            return jnp.einsum("nmd,nm->nd", gathered, w * (indices != 0))
+
+        def run(fn):
+            loss = lambda t, w: (fn(t, w).astype(jnp.float32) * cot).sum()  # noqa: E731
+            return (fn(table, weights), *jax.grad(loss, argnums=(0, 1))(table, weights))
+
+        got = run(lambda t, w: embedding_bag(t, indices, w))
+        want = run(ref)
+        if case == "out_of_range":
+            assert np.abs(np.asarray(want[1][-1])).sum() > 0  # the edge row is credited
+        for g, r in zip(got, want):
+            assert g.dtype == table.dtype and g.shape == r.shape
+            r = np.asarray(r, np.float32)
+            if dtype == "bfloat16":  # within bf16's step of the float32 answer
+                tol = dict(rtol=0, atol=2.0**-7 * np.abs(r).max())
+            else:
+                tol = dict(rtol=1e-6, atol=1e-6)
+            np.testing.assert_allclose(np.asarray(g, np.float32), r, **tol)
+
+    @staticmethod
+    def _forward_eqns(fn, *args):
+        from jax._src.core import jaxprs_in_params
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for sub in jaxprs_in_params(eqn.params):
+                    yield from walk(sub)
+
+        return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    def test_embedding_bag_path_is_read_from_static_shapes(self, monkeypatch):
+        """Narrow tables (every tiny CPU model) keep the gather, to the bit;
+        at a benchmark cell's shapes the forward is the plane kernel and two
+        matmuls, and holds no gather with an ``(N, M, D)`` result."""
+        monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas_interpret")
+        sds = jax.ShapeDtypeStruct
+
+        def names_and_shapes(v, d, n, m, dtype):
+            eqns = self._forward_eqns(
+                embedding_bag,
+                sds((v, d), dtype), sds((n, m), jnp.int32), sds((n, m), dtype),
+            )
+            return (
+                {e.primitive.name for e in eqns},
+                {(e.primitive.name, tuple(o.aval.shape)) for e in eqns for o in e.outvars},
+            )
+
+        names, outs = names_and_shapes(50, 32, 64, 6, jnp.float32)
+        assert "gather" in names and "pallas_call" not in names and "while" not in names
+        assert ("gather", (64, 6, 32)) in outs
+
+        n, m, v, d = 16384, 24, 4057, 1024
+        names, outs = names_and_shapes(v, d, n, m, jnp.bfloat16)
+        assert "pallas_call" in names
+        assert ("pallas_call", (n, 4096)) in outs
+        assert not [o for o in outs if o[0] == "gather" and o[1] == (n, m, d)]
+        assert ("dot_general", (n, d)) in outs
+
+
+    @pytest.mark.parametrize("b", [8, 3], ids=["rows_sharded", "rows_replicated"])
+    def test_embedding_bag_plane_path_inside_kernel_mesh(self, monkeypatch, b):
+        """GSPMD cannot partition a Mosaic call: under `kernel_mesh` the
+        plane kernel runs once per batch shard (`per_batch_shard`), or on
+        every device whole where the rows do not divide over the shards (a
+        serving engine's replicated prefill group), and the answer is the
+        unsharded one."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from eventstreamgpt_tpu.ops import tensor_ops
+        from eventstreamgpt_tpu.parallel import kernel_mesh
+        from eventstreamgpt_tpu.training.sharding import make_mesh
+
+        monkeypatch.setattr(tensor_ops, "_BAG_PLANE_MIN_DIM", 1)
+        monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas_interpret")
+        length, m, v, d = 6, 5, 150, 8
+        rng = np.random.default_rng(28)
+        indices = jnp.asarray(rng.integers(0, v, size=(b, length, m)))
+        weights = jnp.asarray(rng.normal(size=(b, length, m)).astype(np.float32))
+        table = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32))
+        cot = jnp.asarray(rng.normal(size=(b, length, d)).astype(np.float32))
+
+        def step(t, i, w):
+            loss = lambda t_: (embedding_bag(t_, i, w) * cot).sum()  # noqa: E731
+            return embedding_bag(t, i, w), jax.grad(loss)(t)
+
+        # A function object of its own: jit's trace cache is keyed on the
+        # function, and the mesh context is read while tracing.
+        want = jax.jit(lambda *a: step(*a))(table, indices, weights)
+        mesh = make_mesh(4, 1, 2)
+        whole = NamedSharding(mesh, P())
+        rows = NamedSharding(mesh, P(("data", "fsdp"), None, None)) if b % 8 == 0 else whole
+        with kernel_mesh(mesh):
+            sharded = jax.jit(step, in_shardings=(whole, rows, rows))
+            text = sharded.lower(table, indices, weights).as_text()
+            got = sharded(table, indices, weights)
+        assert "manual_computation" in text
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-6, atol=1e-6)
 
     def test_measurement_index_normalization(self):
         mi = jnp.asarray([[1, 2, 5, 2, 2], [1, 3, 5, 3, 0]])
