@@ -220,6 +220,10 @@ def generate(
         events (fewer if a stopping criterion fired) — or a
         `GenerationOutput` wrapping it when ``return_output`` is set.
     """
+    if config.uses_layer_kinds:
+        from ..models.transformer import NO_DECODE_STATE
+
+        raise NotImplementedError(NO_DECODE_STATE)
     if batch.segment_ids is not None:
         raise NotImplementedError(
             "generate() requires padded (one subject per row) prompt batches; packed "

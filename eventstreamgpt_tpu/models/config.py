@@ -219,6 +219,25 @@ class StructuredTransformerConfig(JSONableMixin):
         layer_norm_epsilon: float = 1e-5,
         do_full_block_in_dep_graph_attention: bool | None = True,
         do_full_block_in_seq_attention: bool | None = False,
+        # Layer kinds (docs/layer_kinds.md): a mixer and a feed-forward per
+        # layer, one norm for the stack. The defaults are the classic block.
+        mixer_types: ATTENTION_TYPES_LIST_T = "mha",
+        ffn_types: ATTENTION_TYPES_LIST_T = "mlp",
+        norm_type: str = "layer_norm",
+        q_lora_rank: int | None = None,
+        kv_lora_rank: int | None = None,
+        qk_nope_head_dim: int | None = None,
+        qk_rope_head_dim: int | None = None,
+        v_head_dim: int | None = None,
+        rope_theta: float = 10000.0,
+        moe_intermediate_size: int | None = None,
+        moe_router_width: int | None = None,
+        n_routed_experts: int | None = None,
+        moe_expert_offset: int = 0,
+        n_shared_experts: int = 0,
+        num_experts_per_tok: int | None = None,
+        routed_scaling_factor: float = 1.0,
+        norm_topk_prob: bool = True,
         # Model output configuration
         TTE_generation_layer_type: str = TimeToEventGenerationHeadType.EXPONENTIAL,
         TTE_lognormal_generation_num_components: int | None = None,
@@ -389,11 +408,23 @@ class StructuredTransformerConfig(JSONableMixin):
 
         if (head_dim is None) and (hidden_size is None):
             raise ValueError("Must specify at least one of hidden size or head dim!")
-        if hidden_size is None:
+        latent_dims = (q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim)
+        latent = any(d is not None for d in latent_dims)
+        if latent:
+            # Latent attention's heads are no split of the hidden size: the
+            # core's head width is what the up-projections give it, and a
+            # `head_dim` passed beside them is not used.
+            if None in latent_dims or hidden_size is None:
+                raise ValueError(
+                    "latent attention needs hidden_size, q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
+                )
+            head_dim = qk_nope_head_dim + qk_rope_head_dim
+        elif hidden_size is None:
             hidden_size = head_dim * num_attention_heads
         elif head_dim is None:
             head_dim = hidden_size // num_attention_heads
-        if head_dim * num_attention_heads != hidden_size:
+        if not latent and head_dim * num_attention_heads != hidden_size:
             raise ValueError(
                 f"hidden_size must be divisible by num_attention_heads (got `hidden_size`: {hidden_size} "
                 f"and `num_attention_heads`: {num_attention_heads})."
@@ -437,6 +468,37 @@ class StructuredTransformerConfig(JSONableMixin):
             dep_graph_attention_layers = None
         self.dep_graph_attention_types = dep_graph_attention_types
         self.dep_graph_attention_layers = dep_graph_attention_layers
+
+        self.q_lora_rank = q_lora_rank
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rope_theta = rope_theta
+        self._set_layer_kinds(mixer_types, ffn_types, norm_type)
+        self.moe_intermediate_size = moe_intermediate_size
+        self.moe_router_width = moe_router_width
+        self.n_routed_experts = n_routed_experts
+        self.moe_expert_offset = moe_expert_offset
+        self.n_shared_experts = n_shared_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.routed_scaling_factor = routed_scaling_factor
+        self.norm_topk_prob = norm_topk_prob
+        if "routed" in self.ffn_layers:
+            if None in (moe_intermediate_size, moe_router_width, n_routed_experts, num_experts_per_tok):
+                raise ValueError(
+                    "feed-forward 'routed' needs moe_intermediate_size, moe_router_width, "
+                    "n_routed_experts (the experts held here) and num_experts_per_tok"
+                )
+            if not 0 < n_routed_experts <= moe_router_width or not (
+                0 <= moe_expert_offset <= moe_router_width - n_routed_experts
+            ):
+                raise ValueError(
+                    f"the experts held, {moe_expert_offset}..{moe_expert_offset + n_routed_experts - 1}, "
+                    f"are not among the router's {moe_router_width}"
+                )
+            if not 0 < num_experts_per_tok <= moe_router_width:
+                raise ValueError(f"num_experts_per_tok {num_experts_per_tok} of {moe_router_width} experts")
 
         self.seq_window_size = seq_window_size
         if attention_implementation not in ("einsum", "pallas_flash", "ring"):
@@ -629,6 +691,44 @@ class StructuredTransformerConfig(JSONableMixin):
 
         return jnp.bfloat16 if self.precision == "bf16" else jnp.float32
 
+    MIXER_KINDS = ("mha", "latent")
+    FFN_KINDS = ("mlp", "swiglu", "routed")
+    NORM_KINDS = ("layer_norm", "rms_norm")
+
+    def _set_layer_kinds(self, mixer_types, ffn_types, norm_type) -> None:
+        """Each layer's mixer and feed-forward kind, in the attention types'
+        mini-language. The classic block (`InnerBlock`) is every default; the
+        kinds block (`models/blocks.py`) serves ``latent`` under ``rms_norm``
+        with ``swiglu`` or ``routed``, and nothing in between yet."""
+        self.mixer_types, self.ffn_types, self.norm_type = mixer_types, ffn_types, norm_type
+        self.mixer_layers = self.expand_attention_types_params(mixer_types)
+        self.ffn_layers = self.expand_attention_types_params(ffn_types)
+        for name, layers, known in (
+            ("mixer_types", self.mixer_layers, self.MIXER_KINDS),
+            ("ffn_types", self.ffn_layers, self.FFN_KINDS),
+        ):
+            if len(layers) != self.num_hidden_layers:
+                raise ValueError(f"{name} gives {len(layers)} layers for {self.num_hidden_layers}")
+            if not set(layers) <= set(known):
+                raise ValueError(f"{name} must be of {known}; got {layers}")
+        if norm_type not in self.NORM_KINDS:
+            raise ValueError(f"norm_type must be of {self.NORM_KINDS}; got {norm_type}")
+        classic = set(self.mixer_layers) == {"mha"} and set(self.ffn_layers) == {"mlp"} and norm_type == "layer_norm"
+        kinds = set(self.mixer_layers) == {"latent"} and "mlp" not in self.ffn_layers and norm_type == "rms_norm"
+        if kinds != (self.qk_nope_head_dim is not None) or not (classic or kinds):
+            raise ValueError(
+                "layer kinds: either every default (mha, mlp, layer_norm) or latent mixers with "
+                "swiglu/routed feed-forwards under rms_norm and the latent ranks and head dims; got "
+                f"{mixer_types}, {ffn_types}, {norm_type}, qk_nope_head_dim {self.qk_nope_head_dim}"
+            )
+        if kinds and self.structured_event_processing_mode != StructuredEventProcessingMode.CONDITIONALLY_INDEPENDENT:
+            raise ValueError("the kinds block serves the conditionally-independent model only")
+
+    @property
+    def uses_layer_kinds(self) -> bool:
+        """True where the stack is built of `models/blocks.py::KindsBlock`."""
+        return self.norm_type != "layer_norm"
+
     def measurements_for(self, modality: DataModality) -> list[str]:
         return self.measurements_per_generative_mode.get(modality, [])
 
@@ -724,7 +824,9 @@ class StructuredTransformerConfig(JSONableMixin):
     def to_dict(self) -> dict[str, Any]:
         """Serializes to a plain dict, recursing into measurement configs."""
         as_dict = {
-            k: v for k, v in self.__dict__.items() if k not in ("seq_attention_layers", "_extra_kwargs")
+            k: v
+            for k, v in self.__dict__.items()
+            if k not in ("seq_attention_layers", "mixer_layers", "ffn_layers", "_extra_kwargs")
         }
         as_dict.pop("dep_graph_attention_layers", None)
         if as_dict.get("measurement_configs"):

@@ -1,0 +1,148 @@
+"""Latent attention (MLA): queries, keys and values through low-rank latents.
+
+The mixer of the kinds block (`models/blocks.py`), as the DeepSeek-V2 lineage
+writes it (GLM-4.7-Flash's ``glm4_moe_lite``), with no bias anywhere::
+
+    c_q = RMSNorm(x W_qa)                     q = c_q W_qb     heads of nope + rope
+    [c_kv ; k_r] = x W_kva                    [k_nope ; v] = RMSNorm(c_kv) W_kvb
+    q_r, k_r rotated (RoPE over the rope dims, rotate-half pairing), k_r shared by the heads
+    softmax([q_nope ; q_r] [k_nope ; k_r]^T / sqrt(nope + rope)) v, causal, inside a segment
+    out = concat(heads) W_o
+
+A position is the event's index inside its subject: it restarts at every
+segment of a packed row. The core is the ``pallas_flash`` kernel the classic
+global layers use wherever the two head widths are equal (they are published
+equal: 192 + 64 and 256), and the einsum elsewhere. There is no decode cache
+yet: a latent paged cache and the absorbed decode path are ROADMAP R2/R3.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..ops import segment_starts
+from ..utils.scopes import scope
+from .config import StructuredTransformerConfig
+from .transformer import ATTENTION_CHECKPOINT_NAME, flash_block_sizes
+
+
+class RMSNorm(nn.Module):
+    """``w * x / sqrt(mean(x^2) + eps)``, computed in float32."""
+
+    epsilon: float
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (scale * y).astype(self.dtype)
+
+
+def segment_positions(segment_ids, batch_size: int, seq_len: int):
+    """(B, S) int32: each event's index inside its segment (inside the row
+    where rows are not packed)."""
+    idx = jnp.broadcast_to(jnp.arange(seq_len, dtype=jnp.int32), (batch_size, seq_len))
+    if segment_ids is None:
+        return idx
+    first = jax.lax.cummax(jnp.where(segment_starts(segment_ids), idx, 0), axis=1)
+    return idx - first
+
+
+def rotate(x, positions, theta: float):
+    """RoPE over the last axis of ``x`` (B, S, ..., d), rotate-half pairing:
+    dimension ``i`` pairs with ``i + d/2``. Float32 inside."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # (B, S, d/2)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., : d // 2], x32[..., d // 2 :]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    config: StructuredTransformerConfig
+
+    @nn.compact
+    def __call__(self, x, attention_mask=None, segment_ids=None):
+        cfg = self.config
+        dt = cfg.compute_dtype
+        H, dn, dr, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        B, S = x.shape[:2]
+
+        def dense(features, name):
+            return nn.Dense(
+                features, use_bias=False, kernel_init=nn.initializers.normal(stddev=cfg.init_std),
+                dtype=dt, name=name,
+            )
+
+        with scope("attn_latent"):
+            c_q = RMSNorm(cfg.layer_norm_epsilon, dt, name="q_a_layernorm")(dense(cfg.q_lora_rank, "q_a_proj")(x))
+            q = dense(H * (dn + dr), "q_b_proj")(c_q).reshape(B, S, H, dn + dr)
+            kv_a = dense(cfg.kv_lora_rank + dr, "kv_a_proj_with_mqa")(x)
+            c_kv = RMSNorm(cfg.layer_norm_epsilon, dt, name="kv_a_layernorm")(kv_a[..., : cfg.kv_lora_rank])
+            kv = dense(H * (dn + dv), "kv_b_proj")(c_kv).reshape(B, S, H, dn + dv)
+            positions = segment_positions(segment_ids, B, S)
+            q_r = rotate(q[..., dn:], positions, cfg.rope_theta)
+            k_r = rotate(kv_a[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
+            query = jnp.concatenate([q[..., :dn], q_r], axis=-1)
+            key = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, dr))], axis=-1
+            )
+            value = kv[..., dn:]
+
+        with scope("attn_global"):
+            out = self._core(query, key, value, attention_mask, segment_ids)
+            out = checkpoint_name(out, ATTENTION_CHECKPOINT_NAME)
+        with scope("attn_proj"):
+            return dense(cfg.hidden_size, "o_proj")(out.reshape(B, S, H * dv))
+
+    def _core(self, query, key, value, attention_mask, segment_ids):
+        """Causal, segment-masked softmax attention over (B, S, H, d)."""
+        cfg = self.config
+        B, S, H, d = query.shape
+        scale = d**-0.5
+        want_kernel = cfg.attention_implementation == "pallas_flash"
+        if want_kernel and jax.default_backend() == "tpu" and S % 128 == 0 and value.shape[-1] == d:
+            from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
+
+            from ..parallel.context import per_batch_shard
+
+            # Padding rides as its own segment, as in the classic layers.
+            seg = segment_ids if segment_ids is not None else jnp.zeros((B, S), jnp.int32)
+            if attention_mask is not None:
+                seg = jnp.where(attention_mask, seg.astype(jnp.int32), -1)
+            block_sizes = flash_block_sizes(B, H, S, d)
+            out = per_batch_shard(
+                lambda q, k, v, s: flash_attention(
+                    q, k, v, segment_ids=SegmentIds(q=s, kv=s), causal=True, sm_scale=scale,
+                    block_sizes=block_sizes,
+                ),
+                query.swapaxes(1, 2), key.swapaxes(1, 2), value.swapaxes(1, 2), seg,
+            )
+            return out.swapaxes(1, 2).astype(value.dtype)
+        if want_kernel:
+            import warnings
+
+            warnings.warn(
+                "attention_implementation='pallas_flash' is taking the einsum path in latent "
+                f"attention: backend={jax.default_backend()!r}, S={S}, head widths {d} and "
+                f"{value.shape[-1]} (the flash kernel needs a TPU, S % 128 == 0 and equal widths)",
+                stacklevel=2,
+            )
+        logits = jnp.einsum("bqhd,bkhd->bhqk", query, key, preferred_element_type=jnp.float32) * scale
+        pos = jnp.arange(S)
+        mask = (pos[None, :] <= pos[:, None])[None, None]
+        if segment_ids is not None:
+            mask = mask & (segment_ids[:, None, :, None] == segment_ids[:, None, None, :])
+        if attention_mask is not None:
+            mask = mask & attention_mask[:, None, None, :]
+        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(logits, axis=-1).astype(value.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, value)

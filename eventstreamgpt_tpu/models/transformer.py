@@ -208,6 +208,12 @@ def paged_kv_bytes_per_block(
     return num_layers * 2 * (plane + scale)
 
 
+NO_DECODE_STATE = (
+    "latent attention has no decode cache yet (a latent paged cache and the absorbed decode "
+    "path are not built: ROADMAP R3): generate() and the serving engine cannot run this backbone"
+)
+
+
 def init_paged_kv_caches(
     config: StructuredTransformerConfig,
     batch_size: int,
@@ -378,7 +384,9 @@ def flash_block_sizes(B: int, num_heads: int, S: int, head_dim: int):
     # 128 closes the ladder in both branches so short sequences (S=128)
     # still pin explicit blocks instead of silently falling to kernel
     # defaults.
-    preferred = (1024, 512, 256, 128) if head_dim >= 128 else (512, 256, 128)
+    # Above 128 the 1024-wide backward (dkv) overflows the 16 MiB of scoped
+    # VMEM by 0.3 MiB at d=256 (compiled for the described v5e, PR 28).
+    preferred = (1024, 512, 256, 128) if head_dim == 128 else (512, 256, 128)
     bn = next((b for b in preferred if b <= S and S % b == 0), None)
     if bn is None:
         return BlockSizes.get_default(B, num_heads, S, S, head_dim)
@@ -1186,8 +1194,9 @@ def _remat_policy(config: StructuredTransformerConfig, use_flag: bool = False):
     }[mode]
 
 
-def remat_block_cls(config: StructuredTransformerConfig, use_flag: bool = False):
-    """`InnerBlock`, wrapped per the configured rematerialization policy.
+def remat_block_cls(config: StructuredTransformerConfig, use_flag: bool = False, block_cls=None):
+    """`InnerBlock` (or ``block_cls``, a block of the same call signature),
+    wrapped per the configured rematerialization policy.
 
     ``config.gradient_checkpointing`` selects the policy (VERDICT r05 #3;
     r06 MFU round): ``"none"`` (config default — at toy shapes every policy only
@@ -1203,11 +1212,12 @@ def remat_block_cls(config: StructuredTransformerConfig, use_flag: bool = False)
     ``use_gradient_checkpointing`` bool maps to ``"block"``.
     """
     policy = _remat_policy(config, use_flag)
+    block_cls = InnerBlock if block_cls is None else block_cls
     if policy is _NO_REMAT:
-        return InnerBlock
+        return block_cls
     # Args seen by the lifted transform: (module, hidden, attn_mask,
     # layer_past, use_cache, output_attentions, static_kv_first).
-    return nn.remat(InnerBlock, static_argnums=(4, 5, 6), policy=policy)
+    return nn.remat(block_cls, static_argnums=(4, 5, 6), policy=policy)
 
 
 # ------------------------------------------------------- scan-over-layers
@@ -1487,6 +1497,9 @@ class ConditionallyIndependentPointProcessTransformer(nn.Module):
         all_attentions = [] if output_attentions else None
         all_hidden = [] if output_hidden_states else None
 
+        kinds = cfg.uses_layer_kinds  # its blocks refuse a cache themselves
+        if kinds and getattr(cfg, "scan_layers", False):
+            raise NotImplementedError("scan_layers does not serve the kinds block yet")
         if getattr(cfg, "scan_layers", False):
             # Depth-independent compilation (r10): ONE pattern-period body is
             # traced and scanned over stacked (L/p, ...) parameters; per-layer
@@ -1522,7 +1535,10 @@ class ConditionallyIndependentPointProcessTransformer(nn.Module):
             if all_hidden is not None:
                 all_hidden = _ungroup_layer_trees(hidden_ys, p, n_groups)
         else:
-            block_cls = remat_block_cls(cfg, self.use_gradient_checkpointing)
+            kinds_block = None
+            if kinds:
+                from .blocks import KindsBlock as kinds_block
+            block_cls = remat_block_cls(cfg, self.use_gradient_checkpointing, kinds_block)
 
             for i in range(cfg.num_hidden_layers):
                 if all_hidden is not None:
@@ -1548,9 +1564,14 @@ class ConditionallyIndependentPointProcessTransformer(nn.Module):
                     all_attentions.append(outputs.get("attn_weights"))
 
         with scope("norm"):
-            hidden_states = nn.LayerNorm(
-                epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="ln_f"
-            )(hidden_states)
+            if kinds:
+                from .latent_attention import RMSNorm
+
+                hidden_states = RMSNorm(cfg.layer_norm_epsilon, cfg.compute_dtype, name="ln_f")(hidden_states)
+            else:
+                hidden_states = nn.LayerNorm(
+                    epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype, name="ln_f"
+                )(hidden_states)
         if all_hidden is not None:
             all_hidden.append(hidden_states)
 

@@ -80,12 +80,14 @@ def kernel_mesh(mesh: Mesh | None):
         _STATE.kernel_mesh = prev
 
 
-def per_batch_shard(fn, *args):
-    """``fn(*args)``, once per batch shard of the active `kernel_mesh`.
+def per_batch_shard(fn, *args, replicated=()):
+    """``fn(*args, *replicated)``, once per batch shard of the active
+    `kernel_mesh`.
 
     Every array in ``args`` and in the result must carry the batch (or the
     batch-major flattened row) dimension first; ``fn`` must be independent
-    across it. Every mesh axis is manual inside the call (Mosaic refuses a
+    across it. ``replicated`` are operands every shard sees whole (weights).
+    Every mesh axis is manual inside the call (Mosaic refuses a
     partially-manual context), so operands are replicated over any axis
     other than ``data``/``fsdp``; where the leading dimension does not
     divide by the batch shards, over those too.
@@ -96,7 +98,7 @@ def per_batch_shard(fn, *args):
     mesh = current_kernel_mesh()
     axes = tuple(a for a in _BATCH_AXES if mesh is not None and mesh.shape.get(a, 1) > 1)
     if not axes:
-        return fn(*args)
+        return fn(*args, *replicated)
     n_shards = 1
     for a in axes:
         n_shards *= mesh.shape[a]
@@ -106,11 +108,12 @@ def per_batch_shard(fn, *args):
         # every device runs the whole call.
         axes = None
     spec = lambda x: P(axes, *([None] * (x.ndim - 1)))  # noqa: E731
-    out_shape = jax.eval_shape(fn, *args)
+    out_shape = jax.eval_shape(fn, *args, *replicated)
+    whole = jax.tree_util.tree_map(lambda x: P(), tuple(replicated))
     return jax.shard_map(
         fn,
         mesh=mesh,
-        in_specs=jax.tree_util.tree_map(spec, args),
+        in_specs=jax.tree_util.tree_map(spec, args) + whole,
         out_specs=jax.tree_util.tree_map(spec, out_shape),
         check_vma=False,
-    )(*args)
+    )(*args, *replicated)

@@ -496,6 +496,10 @@ class GenerationEngine:
         health_retries: int = 0,
         validate_prompts: bool = True,
     ):
+        if config.uses_layer_kinds:
+            from ..models.transformer import NO_DECODE_STATE
+
+            raise NotImplementedError(NO_DECODE_STATE)
         self.model = model
         self.params = params
         self.config = config

@@ -53,6 +53,7 @@ from ..models.config import (
     StructuredEventProcessingMode,
     StructuredTransformerConfig,
 )
+from ..models.moe import ROUTING_COLLECTION, routing_counters
 from ..models.na_model import NAPPTForGenerativeSequenceModeling
 from ..utils import config_dataclass
 from ..utils.scopes import host_span, scope
@@ -60,7 +61,7 @@ from .checkpoint import TrainCheckpointManager, save_pretrained
 from .generative_metrics import GenerativeMetrics
 from .optimizer import build_optimizer
 
-SKIP_CFG_PARAMS = {"seq_attention_layers", "dep_graph_attention_layers"}
+SKIP_CFG_PARAMS = {"seq_attention_layers", "dep_graph_attention_layers", "mixer_layers", "ffn_layers"}
 
 
 # --------------------------------------------------------------------- state
@@ -240,7 +241,7 @@ def replicate(tree: Any, mesh: Mesh) -> Any:
 
 
 # ----------------------------------------------------------------- train step
-def _train_step_body(model, tx, with_health: bool = False) -> Callable:
+def _train_step_body(model, tx, with_health: bool = False, with_routing: bool = False) -> Callable:
     """The un-jitted ``(state, batch, rng) -> (state, loss)`` step body.
 
     Shared verbatim by the per-batch step (`make_train_step`) and the
@@ -254,16 +255,29 @@ def _train_step_body(model, tx, with_health: bool = False) -> Callable:
     the step already has in registers — no extra host traffic, no change to
     the parameter/loss numerics — and is read back only at the training
     loop's existing flush cadence (``reliability/sentinel.py``).
+
+    ``with_routing=True`` (with ``with_health``) appends the routed layers'
+    counters of the step, ``(state, (loss, health, routing))``: an int32
+    ``[token-expert pairs computed here, largest load of one held expert]``
+    (`models/moe.py::routing_counters`), read back with the health vector and
+    never inside the step.
     """
+    if with_routing and not with_health:
+        raise ValueError("the routing counters are returned beside the health vector: with_health=True")
 
     def train_step(state: TrainState, batch: EventStreamBatch, rng: jax.Array):
         dropout_rng = jax.random.fold_in(rng, state.step)
 
         def loss_fn(params):
+            if with_routing:
+                out, sown = model.apply(
+                    params, batch, rngs={"dropout": dropout_rng}, mutable=[ROUTING_COLLECTION]
+                )
+                return out.loss, routing_counters(sown)
             out = model.apply(params, batch, rngs={"dropout": dropout_rng})
-            return out.loss
+            return out.loss, None
 
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
+        (loss, routing), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
         with scope("optimizer"):
             updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -271,7 +285,7 @@ def _train_step_body(model, tx, with_health: bool = False) -> Callable:
         if with_health:
             with scope("health"):
                 health = jnp.stack([loss, optax.global_norm(grads)]).astype(jnp.float32)
-            return new_state, (loss, health)
+            return new_state, ((loss, health, routing) if with_routing else (loss, health))
         return new_state, loss
 
     return train_step
@@ -322,6 +336,7 @@ def make_chunked_train_step(
     packed: bool = False,
     with_health: bool = False,
     out_state_shardings=None,
+    with_routing: bool = False,
 ) -> Callable:
     """A jitted ``(state, arrays, plans, rng) -> (state, losses)`` program
     that runs ``k`` collate+train steps in ONE dispatch.
@@ -339,9 +354,11 @@ def make_chunked_train_step(
     ``device_data.arrays``. Pretraining ignores per-subject light fields
     (labels, subject ids), which is why the scanned batch carries none.
     ``with_health`` stacks the per-step sentinel health vectors alongside
-    the losses: the output becomes ``(state, (losses, healths))``.
+    the losses: the output becomes ``(state, (losses, healths))``, and with
+    ``with_routing`` ``(state, (losses, healths, routings))``, the routed
+    layers' counters of every step (`_train_step_body`).
     """
-    body = _train_step_body(model, tx, with_health=with_health)
+    body = _train_step_body(model, tx, with_health=with_health, with_routing=with_routing)
 
     if packed:
         kern = device_data.packed_kernel()
@@ -836,6 +853,9 @@ def train(
         # a few percent.
         chunk_steps = max(min(log_every, ckpt_every, 16), 1)
     chunk_steps = int(chunk_steps)
+    # A routed feed-forward's counters ride out beside the health vector and
+    # reach train_log.jsonl at the log flush (the scanned path only).
+    with_routing = with_health and "routed" in config.ffn_layers
     chunked_step = (
         make_chunked_train_step(
             model,
@@ -844,6 +864,7 @@ def train(
             packed=use_packed,
             with_health=with_health,
             out_state_shardings=state_shardings,
+            with_routing=with_routing,
         )
         if device_train is not None
         else None
@@ -934,6 +955,7 @@ def train(
             epoch_t0 = time.perf_counter()
             window_t0, window_events, window_n = time.perf_counter(), 0, 0
             window_losses: list = []
+            window_routing: list = []
             epoch_skip = resume_skip if epoch == resume_epoch else 0
             if rollback_ctl is not None:
                 # Excise any window a previous rollback marked poisoned: the
@@ -952,18 +974,19 @@ def train(
             def flush_window() -> dict:
                 """Closes the current logging window into a record whose
                 losses stay device arrays (`finalize_record` converts)."""
-                nonlocal window_t0, window_events, window_n, window_losses
+                nonlocal window_t0, window_events, window_n, window_losses, window_routing
                 dt = time.perf_counter() - window_t0
                 rec = {
                     "split": str(Split.TRAIN),
                     "epoch": epoch,
                     "step": global_step,
                     "_losses": [jnp.atleast_1d(l) for l in window_losses],
+                    "_routing": window_routing,
                     "events_per_sec": window_events / dt if dt > 0 else None,
                     "step_time_ms": 1000.0 * dt / max(window_n, 1),
                 }
                 window_t0, window_events, window_n = time.perf_counter(), 0, 0
-                window_losses = []
+                window_losses, window_routing = [], []
                 return rec
 
             def finalize_record(rec: dict) -> None:
@@ -971,6 +994,11 @@ def train(
                 schedule, a tiny eager jnp computation) touch the host."""
                 rec["train_loss"] = float(jnp.mean(jnp.concatenate(rec.pop("_losses"))))  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
                 rec["lr"] = float(lr_schedule(rec["step"] // accum))  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
+                routing = rec.pop("_routing")
+                if routing:
+                    routing = np.concatenate([np.asarray(r) for r in routing])  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
+                    rec["moe_pairs_per_step"] = float(routing[:, 0].mean())  # graftcheck: allow GC001 -- a host array, read back on the line above at the flush
+                    rec["moe_load_max"] = int(routing[:, 1].max())  # graftcheck: allow GC001 -- a host array, as above
                 log_record(rec)
 
             def flush_logs(pending: list) -> None:
@@ -1078,8 +1106,9 @@ def train(
                             profiling = True
                         with host_span("dispatch"):
                             if with_health:
-                                state, (losses, healths) = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
+                                state, (losses, healths, *routings) = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                                 health_mon.record(healths)
+                                window_routing.extend(routings)
                             else:
                                 state, losses = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                         global_step += k

@@ -208,11 +208,16 @@ def set_dotted(d: dict[str, Any], key: str, value: Any) -> None:
 
 
 def parse_override_value(raw: str) -> Any:
-    """Parses a CLI override value using YAML rules (ints, floats, lists, null)."""
+    """Parses a CLI override value using YAML rules (ints, floats, lists, null).
+    YAML 1.1 reads ``1e-05`` (Python's own spelling of that float) as a string:
+    a mantissa without a point is a float here too."""
     try:
-        return yaml.safe_load(raw)
+        value = yaml.safe_load(raw)
     except yaml.YAMLError:
         return raw
+    if isinstance(value, str) and re.fullmatch(r"[+-]?\d+[eE][+-]?\d+", value):
+        return float(value)
+    return value
 
 
 def deep_merge(dst: dict, src: dict) -> dict:

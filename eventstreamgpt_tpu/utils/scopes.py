@@ -23,10 +23,16 @@ SCOPES = (
     "embed",  # the input layer: data embedding, time encoding, static codes
     "norm",  # every LayerNorm of a block and ln_f
     "attn_proj",  # q, k, v and output projections
+    "attn_latent",  # latent attention between the normed input and q, k, v: its four
+    # down/up projections, the two latent norms, RoPE, the concatenations
     "attn_global",  # what lies between the projections in a global layer
     "attn_local",  # ... in a local layer
     "dep_graph",  # NA's dependency-graph attention and its plumbing
-    "mlp",  # the feed-forward block
+    "mlp",  # the feed-forward block (the classic MLP, the dense SwiGLU)
+    "moe_router",  # a routed layer's logits, sigmoid, top-k and weights
+    "moe_dispatch",  # ordering the (row, expert) pairs by expert, gathering rows, combining back
+    "moe_experts",  # the grouped products of the experts held here
+    "moe_shared",  # the shared expert
     "heads_tte",  # time-to-event head and its log-likelihood
     "heads_cls",  # classification heads and their losses
     "heads_reg",  # regression heads and their losses

@@ -1,0 +1,195 @@
+"""The kinds block (`models/blocks.py`): latent attention and the routed
+feed-forward against a plain reference in float32 on seeded random weights,
+the share of one chip against the uncut layer, no dropped row, RoPE's restart
+at a packed segment, the routing counters, and what raises."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from eventstreamgpt_tpu.models.blocks import KindsBlock
+from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
+from eventstreamgpt_tpu.models.latent_attention import LatentAttention
+from eventstreamgpt_tpu.models.moe import RoutedFeedForward, held_experts_output
+
+from . import layer_kinds_reference as ref
+
+KINDS = dict(
+    hidden_size=32, num_attention_heads=4, num_hidden_layers=3, intermediate_size=48,
+    seq_attention_types=["global"], mixer_types="latent", ffn_types=[[["swiglu"], 1], [["routed"], 46]],
+    norm_type="rms_norm", activation_function="silu",
+    q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=12, rope_theta=1e6,
+    moe_intermediate_size=24, moe_router_width=16, n_routed_experts=16, moe_expert_offset=0,
+    n_shared_experts=1, num_experts_per_tok=4, routed_scaling_factor=1.8,
+    attention_dropout=0.0, input_dropout=0.0, resid_dropout=0.0, init_std=0.5,
+)
+
+
+def kinds_config(**kwargs):
+    return StructuredTransformerConfig(**{**KINDS, **kwargs})
+
+
+def inputs(B=2, L=12, seed=0):
+    x = jax.random.normal(jax.random.PRNGKey(seed), (B, L, KINDS["hidden_size"]))
+    segment_ids = jnp.asarray(np.repeat([[0] * 5 + [1] * 4 + [2] * 3, [0] * 7 + [1] * 5], 1, axis=0)[:B])
+    mask = jnp.ones((B, L), bool).at[1, L - 2 :].set(False)
+    return x, mask, segment_ids
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
+def test_latent_attention_agrees_with_the_plain_reference(packed):
+    cfg = kinds_config()
+    x, mask, segment_ids = inputs()
+    segment_ids = segment_ids if packed else None
+    module = LatentAttention(cfg)
+    params = module.init(jax.random.PRNGKey(1), x, mask, segment_ids)
+    got = module.apply(params, x, mask, segment_ids)
+    want = ref.latent_attention(x, params["params"], cfg, mask, segment_ids)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_routed_feed_forward_agrees_with_the_plain_reference(impl, monkeypatch):
+    monkeypatch.setenv("ESGPT_PALLAS_IMPL", impl)
+    cfg = kinds_config(n_routed_experts=6, moe_expert_offset=3)
+    x, mask, _ = inputs(B=2, L=64)
+    module = RoutedFeedForward(cfg)
+    params = module.init(jax.random.PRNGKey(2), x)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (16,))  # a selection bias that chooses otherwise
+    params = {"params": {**params["params"], "e_score_correction_bias": bias}}
+    got = module.apply(params, x)
+    routed, shared = ref.routed_feed_forward(x, params["params"], cfg)
+    np.testing.assert_allclose(got, routed + shared, rtol=2e-5, atol=2e-5)
+    grads = jax.grad(lambda p: jnp.sum(module.apply(p, x) ** 2))(params)["params"]
+    want = jax.grad(lambda p: jnp.sum(sum(ref.routed_feed_forward(x, p, cfg)) ** 2))(params["params"])
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * float(jnp.abs(w).max()), err_msg=str(path))
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """One chip's share is a range of the router's experts. The routed parts
+    of all the shares, with the shared expert counted once, are the layer that
+    holds every expert."""
+    whole = kinds_config(n_routed_experts=16)
+    x, _, _ = inputs(B=2, L=32, seed=3)
+    module = RoutedFeedForward(whole)
+    params = module.init(jax.random.PRNGKey(4), x)
+    uncut = module.apply(params, x)
+    p = params["params"]
+    shared = ref.swiglu(x, p["shared_experts"])
+    total = shared
+    for first in range(0, 16, 2):
+        share = kinds_config(n_routed_experts=2, moe_expert_offset=first)
+        held = {
+            **{k: v for k, v in p.items() if not k.startswith("experts_")},
+            **{k: v[first : first + 2] for k, v in p.items() if k.startswith("experts_")},
+        }
+        total = total + RoutedFeedForward(share).apply({"params": held}, x) - shared
+    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
+    routed, ref_shared = ref.routed_feed_forward(x, p, whole)
+    np.testing.assert_allclose(uncut, routed + ref_shared, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_no_row_is_dropped_when_every_row_chooses_the_same_experts(impl):
+    """The worst load: every row's every choice is held here. All four chunks
+    of the buffer run and every pair is computed."""
+    n, k, h, inner, held = 128, 4, 16, 8, 4
+    rows = jax.random.normal(jax.random.PRNGKey(5), (n, h))
+    w = [jax.random.normal(jax.random.PRNGKey(6 + i), s) * 0.3 for i, s in enumerate([(held, h, inner)] * 2 + [(held, inner, h)])]
+    chosen = jnp.broadcast_to(jnp.arange(k), (n, k))
+    weights = jnp.full((n, k), 0.25)
+    got, counters = held_experts_output(rows, chosen, weights, *w, offset=0, impl=impl)
+    want = sum(0.25 * ((jax.nn.silu(rows @ w[0][e]) * (rows @ w[1][e])) @ w[2][e]) for e in range(k))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert counters.tolist() == [[n * k, n]]
+
+
+def test_rope_restarts_at_a_packed_segment():
+    """A packed row gives what its histories give when run apart."""
+    cfg = kinds_config()
+    x, _, segment_ids = inputs(B=1)
+    block = KindsBlock(cfg, layer_id=1)
+    params = block.init(jax.random.PRNGKey(7), x, None, None, False, False, False, segment_ids)
+    packed, _ = block.apply(params, x, None, None, False, False, False, segment_ids)
+    for lo, hi in ((0, 5), (5, 9), (9, 12)):
+        apart, _ = block.apply(params, x[:, lo:hi], None, None, False, False, False, None)
+        np.testing.assert_allclose(packed[:, lo:hi], apart, rtol=2e-5, atol=2e-5)
+    unpacked, _ = block.apply(params, x, None, None, False, False, False, None)
+    assert float(jnp.abs(unpacked[:, 5:] - packed[:, 5:]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("layer_id", [0, 1], ids=["swiglu", "routed"])
+def test_block_agrees_with_the_plain_reference(layer_id):
+    cfg = kinds_config(n_routed_experts=8, moe_expert_offset=8)
+    x, mask, segment_ids = inputs()
+    block = KindsBlock(cfg, layer_id=layer_id)
+    params = block.init(jax.random.PRNGKey(8), x, mask, None, False, False, False, segment_ids)
+    got, _ = block.apply(params, x, mask, None, False, False, False, segment_ids)
+    want = ref.block(x, params["params"], cfg, layer_id, mask, segment_ids)
+    real = np.asarray(mask)  # a row that holds no event is routed nowhere; the encoder zeroes it
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real], rtol=3e-5, atol=3e-5)
+
+
+def test_counters_equal_a_count_made_on_the_host():
+    cfg = kinds_config(n_routed_experts=4, moe_expert_offset=2)
+    x, mask, _ = inputs(B=2, L=32, seed=9)
+    module = RoutedFeedForward(cfg)
+    params = module.init(jax.random.PRNGKey(10), x)
+    assert set(params) == {"params"}  # `init` gives parameters alone
+    _, sown = module.apply(params, x, mask, mutable=["routing"])
+    (counters,) = sown["routing"]["counters"]
+    scores = jax.nn.sigmoid(x.reshape(-1, 32) @ params["params"]["router"])
+    chosen = np.asarray(jax.lax.top_k(scores, 4)[1])[np.asarray(mask).reshape(-1)]
+    loads = [(chosen == e).sum() for e in range(2, 6)]
+    assert counters.tolist() == [sum(loads), max(loads)]
+
+
+def test_the_selection_bias_chooses_and_does_not_weigh():
+    """A selection bias that favours the held experts changes which experts a
+    row is given and not the scores they are weighted by; it is zero at
+    initialisation and gets no gradient."""
+    cfg = kinds_config(n_routed_experts=4, moe_expert_offset=2, init_std=0.1)
+    x = jax.random.normal(jax.random.PRNGKey(11), (4, 64, 32))
+    module = RoutedFeedForward(cfg)
+    params = module.init(jax.random.PRNGKey(13), x)
+    assert float(jnp.abs(params["params"]["e_score_correction_bias"]).max()) == 0.0
+    bias = jnp.zeros(16).at[2:6].set(10.0)  # every row's four choices are the four held experts
+    biased = {"params": {**params["params"], "e_score_correction_bias": bias}}
+    out, sown = module.apply(biased, x, mutable=["routing"])
+    assert sown["routing"]["counters"][0].tolist() == [4 * 256, 256]
+
+    p = params["params"]
+    rows = x.reshape(-1, 32)
+    scores = jax.nn.sigmoid(rows @ p["router"])[:, 2:6]
+    weights = cfg.routed_scaling_factor * scores / scores.sum(-1, keepdims=True)  # by score alone
+    swiglu = lambda r, g, u, d: (jax.nn.silu(r @ g) * (r @ u)) @ d  # noqa: E731
+    want = swiglu(rows, *(p["shared_experts"][n]["kernel"] for n in ("gate_proj", "up_proj", "down_proj")))
+    for i in range(4):
+        want = want + weights[:, i : i + 1] * swiglu(
+            rows, p["experts_gate_proj"][i], p["experts_up_proj"][i], p["experts_down_proj"][i]
+        )
+    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=1e-5, atol=1e-6)
+    grads = jax.grad(lambda q: jnp.sum(module.apply(q, x) ** 2))(biased)["params"]
+    assert float(jnp.abs(grads["e_score_correction_bias"]).max()) == 0.0
+    assert float(jnp.abs(grads["router"]).max()) > 0.0
+
+
+def test_what_the_configuration_refuses():
+    with pytest.raises(ValueError, match="layer kinds"):
+        kinds_config(norm_type="layer_norm")
+    with pytest.raises(ValueError, match="layer kinds"):
+        kinds_config(ffn_types="mlp")
+    with pytest.raises(ValueError, match="latent attention needs"):
+        kinds_config(q_lora_rank=None)
+    with pytest.raises(ValueError, match="are not among the router's"):
+        kinds_config(n_routed_experts=8, moe_expert_offset=9)
+    with pytest.raises(ValueError, match="conditionally-independent"):
+        kinds_config(
+            structured_event_processing_mode="nested_attention", measurements_per_dep_graph_level=[[], ["a"]]
+        )
+    cfg = kinds_config()
+    assert cfg.head_dim == 12 and cfg.uses_layer_kinds and cfg.ffn_layers == ["swiglu", "routed", "routed"]
+    assert StructuredTransformerConfig.from_dict(cfg.to_dict()) == cfg
+    assert not StructuredTransformerConfig(hidden_size=16, head_dim=4).uses_layer_kinds

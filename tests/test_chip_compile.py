@@ -228,6 +228,52 @@ def test_flash_attention_s1024_d128(one_chip, direction):
     _compile(fwd if direction == "forward" else grad, qkv, qkv, qkv, seg)
 
 
+def test_flash_attention_s1024_d256_takes_512_wide_blocks(one_chip):
+    """Latent attention's core (`models/latent_attention.py`): 20 heads of
+    256 at S = 1024, 16 rows. At 1024-wide blocks the backward (dkv) overflows
+    the 16 MiB of scoped VMEM by 0.3 MiB; `flash_block_sizes` gives 512."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
+
+    from eventstreamgpt_tpu.models.transformer import flash_block_sizes
+
+    B, H, S, D = 16, 20, 1024, 256
+    block_sizes = flash_block_sizes(B, H, S, D)
+    assert block_sizes.block_q == 512
+    qkv = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+
+    def grad(q, k, v, s):
+        loss = lambda *a: flash_attention(  # noqa: E731
+            *a, segment_ids=SegmentIds(q=s, kv=s), causal=True, sm_scale=D**-0.5, block_sizes=block_sizes
+        ).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    _compile(grad, qkv, qkv, qkv, seg)
+
+
+def test_held_experts_at_the_cells_shapes(one_chip, monkeypatch):
+    """The routed layer's dispatch, grouped products and combine
+    (`models/moe.py::held_experts_output`), forward and gradient, at
+    `glm47flash_ep8.pretrain_packed`'s shapes: 16,384 rows of 2,048, top-4,
+    8 held experts of inner width 1,536, bf16."""
+    from eventstreamgpt_tpu.models.moe import held_experts_output
+
+    monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas")
+    n, k, h, inner, held = 16384, 4, 2048, 1536, 8
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (
+        sds((n, h), jnp.bfloat16), sds((n, k), jnp.int32), sds((n, k), jnp.float32),
+        sds((held, h, inner), jnp.bfloat16), sds((held, h, inner), jnp.bfloat16), sds((held, inner, h), jnp.bfloat16),
+    )
+
+    def grad(rows, chosen, weights, *w):
+        loss = lambda rows, weights, *w: held_experts_output(rows, chosen, weights, *w, offset=0)[0].sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(rows, weights, *w)
+
+    text = _compile(grad, *args)
+    assert " while(" in text  # the buffer is walked as far as the held pairs reach
+
+
 def test_decode_megakernel_is_refused_loudly():
     """`ops/pallas_decode_step.py` does not lower under Mosaic (PR 22,
     CHANGES.md): the compiled impl raises instead of interpreting or

@@ -28,9 +28,17 @@ PARENT_LOSSES = {
     "ci_w1024.pretrain_padded": ["0x1.5cdbf20000000p+3", "0x1.6d66840000000p+3", "0x1.5976780000000p+3", "0x1.5f61e20000000p+3"],
     "na_w1024.pretrain": ["0x1.5c6b2a0000000p+3", "0x1.70c5700000000p+3", "0x1.59d9620000000p+3", "0x1.602e640000000p+3"],
 }
-CELLS = sorted(PARENT_LOSSES)
-# Scopes a model does not have: CI has no dependency graph.
-ABSENT = {"ci_w1024.pretrain_packed": {"dep_graph"}, "ci_w1024.pretrain_padded": {"dep_graph"}, "na_w1024.pretrain": set()}
+KINDS_CELL = "glm47flash_ep8.pretrain_packed"  # added by PR 28: it has no parent to be equal to
+CELLS = sorted(PARENT_LOSSES) + [KINDS_CELL]
+# Scopes a model does not have: CI has no dependency graph, the classic block
+# none of the kinds block's (docs/layer_kinds.md), the kinds block no local layer.
+KINDS = {"attn_latent", "moe_router", "moe_dispatch", "moe_experts", "moe_shared"}
+ABSENT = {
+    "ci_w1024.pretrain_packed": {"dep_graph"} | KINDS,
+    "ci_w1024.pretrain_padded": {"dep_graph"} | KINDS,
+    "na_w1024.pretrain": KINDS,
+    KINDS_CELL: {"dep_graph", "attn_local"},
+}
 # Instructions of the scan body with an op_name and no es. scope, at most:
 # constants and broadcasts the compiler hoists, the scan's own slicing, the
 # residual adds and the masking between layers.
@@ -48,7 +56,6 @@ def compiled():
 
             from benchmark.harness import cohort as cohort_lib
             from benchmark.harness import loader
-            from benchmark.harness.pretrain import Program
             from eventstreamgpt_tpu.parallel.context import kernel_mesh
 
             with pytest.MonkeyPatch.context() as patch:
@@ -56,7 +63,7 @@ def compiled():
                 cell = tiny_cell(name)
                 cohort = cohort_lib.make_cohort(cell["cohort"], SEED)
                 work = Path(tempfile.mkdtemp(prefix="scopes_"))
-                prog = Program(cell, cohort, loader.load_reference(cell), SEED, work)
+                prog = loader.load_job(cell).Program(cell, cohort, loader.load_reference(cell), SEED, work)
                 plans, _ = prog.next_plans()
                 with kernel_mesh(prog.mesh):
                     text = prog.step.lower(prog.state, prog.device_data.arrays, plans, prog.rng).compile().as_text()
@@ -104,7 +111,7 @@ def test_most_of_the_scan_body_is_under_a_scope(name, compiled):
     assert unscoped / len(body) <= UNSCOPED_SHARE
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", sorted(PARENT_LOSSES))
 def test_the_losses_of_one_dispatch_are_the_parents_bit_for_bit(name, compiled):
     _, losses = compiled(name)
     assert losses == PARENT_LOSSES[name]
