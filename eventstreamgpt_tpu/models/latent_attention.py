@@ -26,7 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import segment_starts
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
-from .transformer import ATTENTION_CHECKPOINT_NAME, flash_block_sizes
+from .transformer import ATTENTION_CHECKPOINT_NAME
 
 
 class RMSNorm(nn.Module):
@@ -110,23 +110,17 @@ class LatentAttention(nn.Module):
         scale = d**-0.5
         want_kernel = cfg.attention_implementation == "pallas_flash"
         if want_kernel and jax.default_backend() == "tpu" and S % 128 == 0 and value.shape[-1] == d:
-            from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
-
+            from ..ops.pallas_flash import flash_attention
             from ..parallel.context import per_batch_shard
 
-            # Padding rides as its own segment, as in the classic layers.
+            # Padding rides as its own segment, as in the classic layers; the
+            # op reads (B, S, H, d) as the projections leave it.
             seg = segment_ids if segment_ids is not None else jnp.zeros((B, S), jnp.int32)
             if attention_mask is not None:
                 seg = jnp.where(attention_mask, seg.astype(jnp.int32), -1)
-            block_sizes = flash_block_sizes(B, H, S, d)
-            out = per_batch_shard(
-                lambda q, k, v, s: flash_attention(
-                    q, k, v, segment_ids=SegmentIds(q=s, kv=s), causal=True, sm_scale=scale,
-                    block_sizes=block_sizes,
-                ),
-                query.swapaxes(1, 2), key.swapaxes(1, 2), value.swapaxes(1, 2), seg,
-            )
-            return out.swapaxes(1, 2).astype(value.dtype)
+            return per_batch_shard(
+                lambda q, k, v, s: flash_attention(q, k, v, s, sm_scale=scale), query, key, value, seg
+            ).astype(value.dtype)
         if want_kernel:
             import warnings
 

@@ -365,39 +365,6 @@ def make_causal_mask(
     return mask
 
 
-def flash_block_sizes(B: int, num_heads: int, S: int, head_dim: int):
-    """The block sizes the ``pallas_flash`` global layers hand the kernel.
-
-    The kernel's default 128-wide blocks leave the MXU badly
-    underfed at long sequence lengths; the sweet spot depends on
-    head_dim (scripts/probe_flash_blocks.py, fwd+bwd per global
-    layer at B=8/L=1024, quiet-window sustained protocol):
-    d=128 → 1.72 ms at 1024-wide vs 1.90 at 512 / 5.08 at 128 /
-    9.2 at defaults; d=64 → 4.0 ms at 512-wide vs 5.8 at 256 /
-    11.5 at defaults (and the splash causal kernel measures 9.5 —
-    flash+big-blocks wins). Pick the largest measured-good width
-    that divides the sequence length; otherwise keep the kernel's
-    defaults.
-    """
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
-    # 128 closes the ladder in both branches so short sequences (S=128)
-    # still pin explicit blocks instead of silently falling to kernel
-    # defaults.
-    # Above 128 the 1024-wide backward (dkv) overflows the 16 MiB of scoped
-    # VMEM by 0.3 MiB at d=256 (compiled for the described v5e, PR 28).
-    preferred = (1024, 512, 256, 128) if head_dim == 128 else (512, 256, 128)
-    bn = next((b for b in preferred if b <= S and S % b == 0), None)
-    if bn is None:
-        return BlockSizes.get_default(B, num_heads, S, S, head_dim)
-    return BlockSizes(
-        block_q=bn, block_k_major=bn, block_k=bn, block_b=1,
-        block_q_major_dkv=bn, block_k_major_dkv=bn,
-        block_k_dkv=bn, block_q_dkv=bn,
-        block_k_major_dq=bn, block_k_dq=bn, block_q_dq=bn,
-    )
-
-
 class InnerSelfAttention(nn.Module):
     """Multi-head causal self-attention with optional local windowing.
 
@@ -827,9 +794,12 @@ class InnerSelfAttention(nn.Module):
             )
             pad_mask = attention_mask if attention_mask is not None else jnp.ones((B, S), bool)
             seg = jnp.where(pad_mask, base_seg.astype(jnp.int32), -1)
-            # The fused kernels' contract is heads-first (B, H, S, D); fused
-            # paths exclude the cache branches, so this is the only transpose.
-            query, key, value = heads_first(query), heads_first(key), heads_first(value)
+            if not use_pallas:
+                # The ring, splash and band paths' contract is heads-first
+                # (B, H, S, D); fused paths exclude the cache branches, so
+                # this is the only transpose. The flash op reads the
+                # projections' (B, S, H, D) as it is.
+                query, key, value = heads_first(query), heads_first(key), heads_first(value)
 
         if use_dep_fused:
             from ..ops.band_attention import dep_graph_attention
@@ -881,35 +851,24 @@ class InnerSelfAttention(nn.Module):
             )
             outputs = {"present_key_value": None, "_heads_first_out": True}
         elif use_pallas:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                SegmentIds,
-                flash_attention,
-            )
-
-            block_sizes = flash_block_sizes(B, num_heads, S, query.shape[-1])
-
-            # GPT-Neo lineage: logits are NOT scaled by 1/sqrt(head_dim).
-            # bf16 q/k/v ride the MXU directly (the kernel accumulates its
-            # softmax statistics in fp32); fp32 mode keeps fp32 inputs.
-            kernel_dt = dt if dt == jnp.bfloat16 else jnp.float32
+            from ..ops.pallas_flash import flash_attention
             from ..parallel.context import per_batch_shard
 
+            # GPT-Neo lineage: logits are NOT scaled by 1/sqrt(head_dim).
+            # bf16 q/k/v ride the MXU directly (the kernels keep logits and
+            # softmax statistics in fp32); fp32 mode keeps fp32 inputs. Block
+            # and chunk sizes follow the static shapes
+            # (`ops.pallas_flash.flash_block_sizes`), what is visited the
+            # segment ids; the bounds are computed per batch shard.
+            kernel_dt = dt if dt == jnp.bfloat16 else jnp.float32
             attn_output = per_batch_shard(
-                lambda q, k, v, s: flash_attention(
-                    q,
-                    k,
-                    v,
-                    segment_ids=SegmentIds(q=s, kv=s),
-                    causal=True,
-                    sm_scale=1.0,
-                    block_sizes=block_sizes,
-                ),
+                lambda q, k, v, s: flash_attention(q, k, v, s, sm_scale=1.0),
                 query.astype(kernel_dt),
                 key.astype(kernel_dt),
                 value.astype(kernel_dt),
                 seg,
             ).astype(value.dtype)
-            outputs = {"present_key_value": None, "_heads_first_out": True}
+            outputs = {"present_key_value": None, "_heads_first_out": False}
         elif use_band:
             from ..ops.band_attention import band_local_attention
 

@@ -40,7 +40,7 @@ import optax
 from flax import serialization, struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..data.config import PytorchDatasetConfig
+from ..data.config import PytorchDatasetConfig, SeqPaddingSide
 from ..data.device_dataset import DeviceDataset
 from ..data.jax_dataset import JaxDataset
 from ..data.prefetch import prefetch_to_device
@@ -403,6 +403,13 @@ def make_chunked_train_step(
     )
 
 
+def _plan_kept_lengths(plans: dict, dataset: JaxDataset) -> np.ndarray:
+    """Events each row of a stacked padded plan chunk keeps (a history is cropped at the row)."""
+    off = np.asarray(dataset.data.subject_event_offsets, np.int64)
+    idx = np.asarray(plans["subject_indices"], np.int64)
+    return np.minimum(off[idx + 1] - off[idx], dataset.max_seq_len)
+
+
 def _plan_event_count(plans: dict, dataset: JaxDataset) -> int:
     """Exact real-event count of a (possibly sliced) stacked plan chunk.
 
@@ -412,10 +419,19 @@ def _plan_event_count(plans: dict, dataset: JaxDataset) -> int:
     """
     if "event_mask" in plans:  # packed plans carry the mask directly
         return int(np.asarray(plans["event_mask"]).sum())
-    off = np.asarray(dataset.data.subject_event_offsets, np.int64)
-    idx = np.asarray(plans["subject_indices"], np.int64)
-    kept = np.minimum(off[idx + 1] - off[idx], dataset.max_seq_len)
-    return int(kept[np.asarray(plans["valid_mask"])].sum())
+    return int(_plan_kept_lengths(plans, dataset)[np.asarray(plans["valid_mask"])].sum())
+
+
+def _plan_segment_ids(plans: dict, dataset: JaxDataset) -> np.ndarray:
+    """``(k, B, L)`` segment ids as the global layers see them (padding
+    ``-1``), from a stacked plan chunk on the host."""
+    if "event_mask" in plans:  # packed plans carry ids and mask directly
+        return np.where(np.asarray(plans["event_mask"]), np.asarray(plans["segment_ids"]), -1)
+    L = dataset.max_seq_len
+    kept = np.where(np.asarray(plans["valid_mask"]), _plan_kept_lengths(plans, dataset), 0)[..., None]
+    pos = np.arange(L)
+    real = pos < kept if dataset.seq_padding_side == SeqPaddingSide.RIGHT else pos >= L - kept
+    return np.where(real, 0, -1)
 
 
 def make_eval_step(model) -> Callable:
@@ -870,6 +886,17 @@ def train(
         else None
     )
 
+    # The share of dense chunk pairs the global layers' flash op visits
+    # (`ops/pallas_flash.py`), from the host's own plans at the log flush.
+    flash_chunks = None
+    flash_len = packed_L if use_packed else train_pyd.max_seq_len
+    if chunked_step is not None and config.attention_implementation == "pallas_flash" and flash_len % 128 == 0:
+        from ..ops.pallas_flash import flash_block_sizes, visited_share
+
+        latent = "latent" in config.mixer_layers
+        width = config.qk_nope_head_dim + config.qk_rope_head_dim if latent else config.head_dim
+        flash_chunks = flash_block_sizes(oc.batch_size, flash_len, config.num_attention_heads, width)[2:]
+
     # Recompilation sentinel (analysis/compile_guard.py): every steady-state
     # shape is seen during the first in-process epoch, so from the second
     # epoch on the active step function must dispatch cached executables
@@ -956,6 +983,7 @@ def train(
             window_t0, window_events, window_n = time.perf_counter(), 0, 0
             window_losses: list = []
             window_routing: list = []
+            window_visited: list = []
             epoch_skip = resume_skip if epoch == resume_epoch else 0
             if rollback_ctl is not None:
                 # Excise any window a previous rollback marked poisoned: the
@@ -974,7 +1002,7 @@ def train(
             def flush_window() -> dict:
                 """Closes the current logging window into a record whose
                 losses stay device arrays (`finalize_record` converts)."""
-                nonlocal window_t0, window_events, window_n, window_losses, window_routing
+                nonlocal window_t0, window_events, window_n, window_losses, window_routing, window_visited
                 dt = time.perf_counter() - window_t0
                 rec = {
                     "split": str(Split.TRAIN),
@@ -982,11 +1010,12 @@ def train(
                     "step": global_step,
                     "_losses": [jnp.atleast_1d(l) for l in window_losses],
                     "_routing": window_routing,
+                    "_visited": window_visited,
                     "events_per_sec": window_events / dt if dt > 0 else None,
                     "step_time_ms": 1000.0 * dt / max(window_n, 1),
                 }
                 window_t0, window_events, window_n = time.perf_counter(), 0, 0
-                window_losses, window_routing = [], []
+                window_losses, window_routing, window_visited = [], [], []
                 return rec
 
             def finalize_record(rec: dict) -> None:
@@ -999,6 +1028,9 @@ def train(
                     routing = np.concatenate([np.asarray(r) for r in routing])  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
                     rec["moe_pairs_per_step"] = float(routing[:, 0].mean())  # graftcheck: allow GC001 -- a host array, read back on the line above at the flush
                     rec["moe_load_max"] = int(routing[:, 1].max())  # graftcheck: allow GC001 -- a host array, as above
+                visited = rec.pop("_visited")
+                if visited:
+                    rec["attn_blocks_visited_share"] = sum(visited) / len(visited)  # host floats, one a dispatch
                 log_record(rec)
 
             def flush_logs(pending: list) -> None:
@@ -1111,6 +1143,9 @@ def train(
                                 window_routing.extend(routings)
                             else:
                                 state, losses = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
+                        if flash_chunks is not None:
+                            # host arithmetic on the plan: what the flash op's walk visits
+                            window_visited.append(visited_share(_plan_segment_ids(plans, train_pyd), *flash_chunks))
                         global_step += k
                         step_in_epoch += k
                         epoch_progress = step_in_epoch

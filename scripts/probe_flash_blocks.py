@@ -1,13 +1,18 @@
-"""Block-size tuning probe for the Pallas flash-attention kernel.
+"""Size sweep for the global layers' flash attention op (`ops/pallas_flash.py`).
 
-Measures fwd+bwd cost of one global-attention layer at the production-width
-shapes (``scripts/probe_scale.py``'s sweep points) across kernel block
-configurations, using the honest sustained-timing protocol
-(``utils/benchmarking.py``, the readback-subtraction protocol). The winner feeds ``models/transformer.py``'s block-size choice.
+Measures forward and forward+backward of one global-attention layer at the
+benchmark cells' shapes, on segment ids drawn like the cells' (log-normal
+history lengths, median 200, first-fit into packed rows; one history a row
+with a padding tail for the padded cell), across the op's rows and heads a grid step and
+its chunk widths, with the honest sustained-timing protocol
+(``utils/benchmarking.py``, the readback-subtraction protocol). The winner
+feeds `ops.pallas_flash.flash_block_sizes`; the table is in PERF.md section 6
+(PR 29). ``--stock`` also times jax's stock kernel at the blocks the parent
+commit gave it, for the same inputs.
 
 Run on the real chip:
 
-    python scripts/probe_flash_blocks.py
+    python scripts/probe_flash_blocks.py [--stock]
 """
 
 from __future__ import annotations
@@ -18,105 +23,136 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))  # repo root
 
+from eventstreamgpt_tpu.ops.pallas_flash import (  # noqa: E402
+    FlashSizes,
+    flash_attention,
+    flash_block_sizes,
+    visited_share,
+)
 from eventstreamgpt_tpu.utils.benchmarking import (  # noqa: E402
-    dispatch_echo_ms,
     drain,
     readback_echo_ms,
     wait_for_quiet,
 )
 
-
-def make_inputs(B, H, L, D, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (B, H, L, D), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (B, H, L, D), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (B, H, L, D), jnp.bfloat16)
-    # Production packed batches carry segment ids; include them so the
-    # measurement matches the training kernel invocation exactly.
-    seg = jnp.zeros((B, L), jnp.int32).at[:, L // 2 :].set(1)
-    return q, k, v, seg
+# name, B, H, S, d, sm_scale, packed, longest history
+SHAPES = [
+    ("ci_w1024.pretrain_packed", 16, 8, 1024, 128, 1.0, True, 512),
+    ("ci_w1024.pretrain_padded", 64, 8, 256, 128, 1.0, False, 256),
+    ("glm47flash_ep8.pretrain_packed", 16, 20, 1024, 256, 256**-0.5, True, 512),
+]
+CHUNKS = [(128, 128), (256, 128), (256, 256), (512, 256)]
 
 
-def layer_cost_ms(q, k, v, seg, block_sizes, n_pipeline=20, repeats=2):
-    from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
+def cell_segment_ids(B: int, S: int, packed: bool, cap: int, seed: int = 0) -> np.ndarray:
+    """``[B, S]`` segment ids as the cells' feeds make them, padding ``-1``."""
+    rng = np.random.default_rng(seed)
+    lengths = np.clip(rng.lognormal(np.log(200), 0.6, 64 * B).astype(int), 4, cap)
+    if not packed:
+        return np.where(np.arange(S)[None, :] < lengths[:B, None], 0, -1).astype(np.int32)
+    rows: list[list[int]] = []
+    for n in lengths:  # first fit
+        for row in rows:
+            if sum(row) + n <= S:
+                row.append(n)
+                break
+        else:
+            rows.append([n])
+    seg = np.full((B, S), -1, np.int32)
+    for b, row in enumerate(rows[:B]):
+        seg[b, : sum(row)] = np.repeat(np.arange(len(row)), row)
+    return seg
 
-    def fwd(q, k, v):
-        out = flash_attention(
-            q, k, v, segment_ids=SegmentIds(q=seg, kv=seg), causal=True,
-            sm_scale=1.0, block_sizes=block_sizes,
-        )
-        return (out.astype(jnp.float32) ** 2).sum()
 
-    grad_fn = jax.jit(jax.value_and_grad(fwd, argnums=(0, 1, 2)))
-
-    # Warm/compile.
-    loss, grads = grad_fn(q, k, v)
-    drain(loss)
-
+def cost_ms(fn, q, k, v, n_pipeline=30, repeats=3):
+    """Sustained ms a call of ``fn(q, k, v) -> array(s)``: back-to-back
+    dispatches (the device runs them in order), one wait at the end."""
+    step = jax.jit(fn)
+    drain(step(q, k, v))
     best = float("inf")
     for _ in range(repeats):
         rtt = readback_echo_ms()
-        qq = q
         t0 = time.perf_counter()
         for _ in range(n_pipeline):
-            loss, (dq, dk, dv) = grad_fn(qq, k, v)
-            qq = qq + 0.0 * dq  # chain steps so the device cannot overlap them
-        drain(loss)
+            out = step(q, k, v)
+        drain(out)
         window = 1000.0 * (time.perf_counter() - t0) - rtt
         best = min(best, max(window, 0.0) / n_pipeline)
     return best
 
 
+def with_gradient(fwd):
+    """``fwd`` and the gradient of its sum in q, k and v."""
+
+    def both(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    return fwd, both
+
+
+def ours(seg, scale, sizes, H):
+    def fwd(q, k, v):  # [B, S, H * d] as a projection leaves them
+        heads = lambda x: x.reshape(*x.shape[:2], H, -1)  # noqa: E731
+        return flash_attention(heads(q), heads(k), heads(v), seg, sm_scale=scale, sizes=sizes).reshape(q.shape)
+
+    return with_gradient(fwd)
+
+
+def stock(seg, scale, d, H):
+    """The parent commit's call: heads-first, blocks of 1,024 (512 above width 128)."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes, SegmentIds, flash_attention
+
+    def fwd(q, k, v):
+        shape = q.shape
+        q, k, v = (x.reshape(*x.shape[:2], H, -1) for x in (q, k, v))
+        S = q.shape[1]
+        bn = min(1024 if d == 128 else 512, S)
+        blocks = BlockSizes(
+            block_q=bn, block_k_major=bn, block_k=bn, block_b=1,
+            block_q_major_dkv=bn, block_k_major_dkv=bn, block_k_dkv=bn, block_q_dkv=bn,
+            block_k_major_dq=bn, block_k_dq=bn, block_q_dq=bn,
+        )
+        out = flash_attention(
+            q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), segment_ids=SegmentIds(q=seg, kv=seg),
+            causal=True, sm_scale=scale, block_sizes=blocks,
+        )
+        return out.swapaxes(1, 2).reshape(shape)
+
+    return with_gradient(fwd)
+
+
 def main():
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
-    shapes = [
-        ("h1024_hd128", 8, 8, 1024, 128),
-        ("h1024_hd64", 8, 16, 1024, 64),
-    ]
-    configs = []
-    for bn in (128, 256, 512, 1024):
-        configs.append((f"sym{bn}", lambda L, bn=bn: BlockSizes(
-            block_q=min(bn, L), block_k_major=min(bn, L), block_k=min(bn, L), block_b=1,
-            block_q_major_dkv=min(bn, L), block_k_major_dkv=min(bn, L),
-            block_k_dkv=min(bn, L), block_q_dkv=min(bn, L),
-            block_k_major_dq=min(bn, L), block_k_dq=min(bn, L), block_q_dq=min(bn, L),
-        )))
-    # Asymmetric: wide k blocks, narrower q blocks (and vice versa).
-    configs.append(("q256_k1024", lambda L: BlockSizes(
-        block_q=256, block_k_major=min(1024, L), block_k=min(1024, L), block_b=1,
-        block_q_major_dkv=256, block_k_major_dkv=min(1024, L),
-        block_k_dkv=min(1024, L), block_q_dkv=256,
-        block_k_major_dq=min(1024, L), block_k_dq=min(1024, L), block_q_dq=256,
-    )))
-    configs.append(("q1024_k256", lambda L: BlockSizes(
-        block_q=min(1024, L), block_k_major=256, block_k=256, block_b=1,
-        block_q_major_dkv=min(1024, L), block_k_major_dkv=256,
-        block_k_dkv=256, block_q_dkv=min(1024, L),
-        block_k_major_dq=256, block_k_dq=256, block_q_dq=min(1024, L),
-    )))
-    configs.append(("default", lambda L: None))
-
-    for shape_name, B, H, L, D in shapes:
-        q, k, v, seg = make_inputs(B, H, L, D)
+    with_stock = "--stock" in sys.argv[1:]
+    for name, B, H, S, d, scale, packed, cap in SHAPES:
+        ks = jax.random.split(jax.random.PRNGKey(0), 3)
+        q, k, v = (jax.random.normal(kk, (B, S, H * d), jnp.bfloat16) for kk in ks)
+        seg_np = cell_segment_ids(B, S, packed, cap)
+        seg = jnp.asarray(seg_np)
         echo, contended = wait_for_quiet()
-        print(f"== {shape_name} B={B} H={H} L={L} D={D} "
-              f"(echo {echo:.2f} ms, contended={contended})")
-        for name, mk in configs:
-            bs = mk(L)
-            try:
-                ms = layer_cost_ms(q, k, v, seg, bs)
-            except Exception as e:  # invalid block config for this shape
-                print(f"  {name:>12}: FAILED ({type(e).__name__}: {str(e)[:80]})")
+        chosen = flash_block_sizes(B, S, H, d)
+        print(f"== {name} B={B} H={H} S={S} d={d} real={float((seg_np >= 0).mean()):.3f} "
+              f"chosen={tuple(chosen)} (echo {echo:.2f} ms, contended={contended})", flush=True)
+        if with_stock:
+            fwd, both = stock(seg, scale, d, H)
+            print(f"  {'stock':>20}: fwd {cost_ms(fwd, q, k, v):7.3f}  fwd+bwd {cost_ms(both, q, k, v):7.3f} ms/layer", flush=True)
+        for cq, ck in CHUNKS:
+            if cq > S or ck > S:
                 continue
-            # Useful FLOPs: causal halves the L^2 plane; fwd 2 matmuls,
-            # bwd ~5 matmul-equivalents (dq, dk, dv + recompute).
-            flops = 0.5 * (2 + 5) * 2 * B * H * L * L * D
-            eff = flops / (ms / 1000.0) / 197e12
-            print(f"  {name:>12}: {ms:7.3f} ms/layer fwd+bwd  (~{100*eff:.1f}% of peak)")
+            groups = sorted({g for g in (1, 2, 4, 5, 8, 10, chosen.heads) if H % g == 0})
+            for rows, group in [(1, 1)] * (chosen.rows > 1) + [(chosen.rows, g) for g in groups]:
+                sizes = FlashSizes(rows, group, cq, ck)
+                share = visited_share(seg_np, cq, ck)
+                try:
+                    fwd, both = ours(seg, scale, sizes, H)
+                    f_ms, b_ms = cost_ms(fwd, q, k, v), cost_ms(both, q, k, v)
+                except Exception as e:  # a size Mosaic refuses at this shape
+                    print(f"  {str(tuple(sizes)):>20}: FAILED ({type(e).__name__}: {str(e)[:120]})", flush=True)
+                    continue
+                print(f"  {str(tuple(sizes)):>20}: fwd {f_ms:7.3f}  fwd+bwd {b_ms:7.3f} ms/layer  visited {share:.3f}", flush=True)
 
 
 if __name__ == "__main__":

@@ -26,6 +26,9 @@ pytestmark = pytest.mark.pallas
 # geometry (8 heads of 128); the synthetic cohort's unified vocabulary.
 DEP_GRAPH_SHAPES = {"tutorial_4x64": (8192, 3, 4, 4, 64), "wide_8x128": (8192, 3, 4, 8, 128)}
 VOCAB = 4057
+# what `ops.pallas_flash.flash_block_sizes` gives at the cells' head widths (rows, heads, chunk_q, chunk_k)
+FLASH_SIZES_D128 = (1, 8, 256, 256)
+FLASH_SIZES_D256 = (1, 10, 128, 128)
 
 
 @pytest.fixture(scope="module")
@@ -201,54 +204,87 @@ def test_embedding_bag_plane_over_four_chips(topo, monkeypatch, program):
     assert "_multihot_2d" in text
 
 
-@pytest.mark.parametrize("direction", ["forward", "gradient"])
-def test_flash_attention_s1024_d128(one_chip, direction):
-    """The flash call of models/transformer.py's ``use_pallas`` branch with
-    the block sizes it picks at S = 1024, D = 128 (B = 8, 8 heads)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
+def _flash_compile(one_chip, B, H, S, D, scale, direction="gradient"):
+    """`ops.pallas_flash.flash_attention` in the projections' layout
+    ``(B, S, H, D)`` with the sizes it picks, compiled for the chip."""
+    from eventstreamgpt_tpu.ops.pallas_flash import flash_attention
 
-    from eventstreamgpt_tpu.models.transformer import flash_block_sizes
-
-    B, H, S, D = 8, 8, 1024, 128
-    block_sizes = flash_block_sizes(B, H, S, D)
-    assert block_sizes.block_q == 1024
-    qkv = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=one_chip)
     seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
 
     def fwd(q, k, v, s):
-        return flash_attention(
-            q, k, v, segment_ids=SegmentIds(q=s, kv=s), causal=True, sm_scale=1.0,
-            block_sizes=block_sizes,
-        )
+        with jax.named_scope("layer"):  # a model's modules stand around the op's scope
+            return flash_attention(q, k, v, s, sm_scale=scale)
 
     def grad(q, k, v, s):
         loss = lambda *a: fwd(*a, s).astype(jnp.float32).sum()  # noqa: E731
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    _compile(fwd if direction == "forward" else grad, qkv, qkv, qkv, seg)
+    return _compile(fwd if direction == "forward" else grad, qkv, qkv, qkv, seg)
 
 
-def test_flash_attention_s1024_d256_takes_512_wide_blocks(one_chip):
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_flash_attention_s1024_d128(one_chip, direction):
+    """The flash op of models/transformer.py's ``use_pallas`` branch with
+    the sizes it picks at S = 1024, D = 128 (B = 8, 8 heads)."""
+    from eventstreamgpt_tpu.ops.pallas_flash import flash_block_sizes
+
+    assert flash_block_sizes(8, 1024, 8, 128) == FLASH_SIZES_D128
+    _flash_compile(one_chip, 8, 8, 1024, 128, 1.0, direction)
+
+
+def test_flash_attention_s1024_d256(one_chip):
     """Latent attention's core (`models/latent_attention.py`): 20 heads of
-    256 at S = 1024, 16 rows. At 1024-wide blocks the backward (dkv) overflows
-    the 16 MiB of scoped VMEM by 0.3 MiB; `flash_block_sizes` gives 512."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds, flash_attention
+    256 at S = 1024, 16 rows. A grid step holds ten heads of a row: q, k, v,
+    do and the two gradients, 5 MiB each, are 60 MiB double-buffered in the
+    dkv kernel, inside the 100 MiB the calls ask for."""
+    from eventstreamgpt_tpu.ops.pallas_flash import flash_block_sizes
 
-    from eventstreamgpt_tpu.models.transformer import flash_block_sizes
+    assert flash_block_sizes(16, 1024, 20, 256) == FLASH_SIZES_D256
+    _flash_compile(one_chip, 16, 20, 1024, 256, 256**-0.5)
 
-    B, H, S, D = 16, 20, 1024, 256
-    block_sizes = flash_block_sizes(B, H, S, D)
-    assert block_sizes.block_q == 512
-    qkv = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
-    seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
 
-    def grad(q, k, v, s):
-        loss = lambda *a: flash_attention(  # noqa: E731
-            *a, segment_ids=SegmentIds(q=s, kv=s), causal=True, sm_scale=D**-0.5, block_sizes=block_sizes
-        ).astype(jnp.float32).sum()
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+@pytest.mark.parametrize("batch", [64, 32], ids=["ci_w1024.pretrain_padded", "na_w1024.pretrain"])
+def test_flash_attention_s256_takes_four_rows_a_step(one_chip, batch):
+    """Rows of 256 events, 8 heads of 128: the padded cell's 64 rows a step
+    and the NA cell's 32 (its sequence module's global layers)."""
+    from eventstreamgpt_tpu.ops.pallas_flash import flash_block_sizes
 
-    _compile(grad, qkv, qkv, qkv, seg)
+    assert flash_block_sizes(batch, 256, 8, 128).rows == 4
+    _flash_compile(one_chip, batch, 8, 256, 128, 1.0)
+
+
+def test_flash_attention_head_width_64_rides_in_a_256_lane_group(one_chip):
+    """``chip_smoke.py``'s parity model: 4 heads of 64 at S = 256. A head
+    narrower than a lane tile is a slice of its group's block."""
+    from eventstreamgpt_tpu.ops.pallas_flash import flash_block_sizes
+
+    assert flash_block_sizes(4, 256, 4, 64) == (4, 4, 256, 256)
+    _flash_compile(one_chip, 4, 4, 256, 64, 1.0)
+
+
+def test_flash_kernels_carry_the_scope_and_the_names_the_roofline_reads(one_chip):
+    """What a device trace shows of the op: the three Mosaic calls are named
+    as `benchmark/metrics/flash_attn_roofline.py::KERNELS` finds them, and
+    every operation of the forward and of the backward rule (JAX traces a
+    custom_vjp's rules without the caller's name stack) is under
+    ``es.attn_global``."""
+    import re
+
+    from benchmark.harness.scopes import scope_of
+    from benchmark.metrics.flash_attn_roofline import KERNELS
+
+    text = _flash_compile(one_chip, 16, 8, 1024, 128, 1.0)
+    calls = dict(re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text))
+    assert len(calls) == 3
+    for stem in ("flash_attention", "flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+        (name,) = [n for n in calls if n.startswith(stem)]
+        assert any(needle in name for needle in KERNELS)
+        assert scope_of(calls[name])[0] == "attn_global"
+    phases = {scope_of(path)[1] for path in calls.values()}
+    assert phases == {"forward", "backward"}
+    op_names = [n for n in re.findall(r'op_name="([^"]*)"', text) if "flash" in n or "es." in n]
+    assert op_names and all(scope_of(n)[0] == "attn_global" for n in op_names)
 
 
 def test_held_experts_at_the_cells_shapes(one_chip, monkeypatch):

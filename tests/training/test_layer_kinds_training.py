@@ -70,6 +70,48 @@ def test_scripts_pretrain_trains_checkpoints_and_resumes(sample_dir, tmp_path):
     assert steps[-1] == 6
 
 
+def test_train_log_holds_the_share_of_chunk_pairs_the_flash_op_visits(sample_dir, tmp_path):
+    """``attn_blocks_visited_share``: host arithmetic on the packed plans at
+    the log flush (`ops.pallas_flash.visited_share`), beside the routing
+    counters, wherever the configuration asks for ``pallas_flash`` and the
+    rows are whole chunks. Off the chip the einsum path runs; the plans'
+    segments are the same."""
+    save_dir = tmp_path / "pretrain"
+    overrides = [o for o in _overrides(sample_dir, save_dir, 2) if "packed_seq_len" not in o]
+    with pytest.warns(UserWarning, match="taking the einsum path"):
+        pretrain_main(
+            overrides
+            + ["trainer_config.packed_seq_len=512", "config.attention_implementation=pallas_flash", "do_overwrite=true"]
+        )
+    log = [json.loads(line) for line in (save_dir / "train_log.jsonl").read_text().splitlines()]
+    train = [rec for rec in log if rec.get("split") == "train" and "train_loss" in rec]
+    # rows of two 256-event chunks: the diagonal pairs always, the third where a history spans the chunks' border
+    assert train and all(0.5 <= rec["attn_blocks_visited_share"] <= 0.75 for rec in train)
+
+
+def test_padded_plans_give_one_segment_a_row_with_its_padding_tail():
+    from types import SimpleNamespace
+
+    from eventstreamgpt_tpu.data.config import SeqPaddingSide
+    from eventstreamgpt_tpu.ops.pallas_flash import visited_share
+    from eventstreamgpt_tpu.training.pretrain import _plan_segment_ids
+
+    offsets = np.asarray([0, 100, 400, 420])  # histories of 100, 300 and 20 events
+    plans = {"subject_indices": np.asarray([[0, 1], [2, 0]]), "valid_mask": np.asarray([[True, True], [True, False]])}
+    for side, real_first in ((SeqPaddingSide.RIGHT, True), (SeqPaddingSide.LEFT, False)):
+        dataset = SimpleNamespace(
+            data=SimpleNamespace(subject_event_offsets=offsets), max_seq_len=256, seq_padding_side=side
+        )
+        seg = _plan_segment_ids(plans, dataset)
+        assert seg.shape == (2, 2, 256)
+        assert ((seg == 0).sum(-1) == [[100, 256], [20, 0]]).all()  # cropped at the row; an invalid row is padding
+        assert (seg[0, 0, 0] == 0) == real_first
+    # a padded query sees its padded predecessors, so only the causal half is skipped: 3 of 4 pairs a row
+    assert visited_share(seg := _plan_segment_ids(plans, SimpleNamespace(
+        data=SimpleNamespace(subject_event_offsets=offsets), max_seq_len=256, seq_padding_side=SeqPaddingSide.RIGHT
+    )), 128) == 12 / 16
+
+
 def test_generate_and_the_engine_refuse_the_backbone():
     from eventstreamgpt_tpu.generation.generation_utils import generate
     from eventstreamgpt_tpu.models.ci_model import CIPPTForGenerativeSequenceModeling
