@@ -55,7 +55,7 @@ class Sizes:
     """Every size the phases use. `FULL` is what the chip runs; the CPU
     rehearsal test (tests/test_chip_smoke.py) passes a tiny instance."""
 
-    # data (the bench.py recipe: ~4k unified vocabulary)
+    # data (~4k unified vocabulary)
     n_train: int = 512
     n_tuning: int = 64
     n_event_types: int = 40
@@ -63,7 +63,7 @@ class Sizes:
     n_meds: int = 500
     mean_seq_len: int = 200
     data_max_seq_len: int = 256
-    # CI at full width (bench.py "width-1024 probe": 166.6M parameters)
+    # CI at full width (the benchmark's ci_w1024: 166.6M parameters)
     hidden: int = 1024
     layers: int = 12
     heads: int = 8
@@ -80,7 +80,7 @@ class Sizes:
     # replayed through generate() (the engine's attention-width parity
     # condition, tests/test_engine.py `mixed_requests`).
     requests: tuple = ((24, 16), (40, 8), (64, 24), (100, 12), (17, 20), (96, 32))
-    # NA at the tutorial shape (bench.py NA section)
+    # NA at the tutorial shape
     na_hidden: int = 256
     na_heads: int = 4
     na_layers: int = 2
@@ -403,8 +403,7 @@ def phase_serve(save_dir: Path, train_ds, sz: Sizes) -> None:
     )
     say(
         f"[serve] engine: {sz.n_slots} slots, max_len {sz.serve_max_len}, "
-        f"sampling_impl={engine.stats().get('sampling_impl')}, "
-        f"decode_step_impl={engine.stats().get('decode_step_impl')}"
+        f"sampling_impl={engine.stats().get('sampling_impl')}"
     )
     reqs = [
         Request(
@@ -641,7 +640,7 @@ def phase_attention_parity(seq_len: int, hidden: int, expect_kernels: bool) -> N
 
 def phase_timing(n: int = 4096, chain: int = 32) -> None:
     """Printed, not asserted: does ``block_until_ready`` wait for the
-    computation, or return at dispatch (utils/benchmarking.py docstring)?"""
+    computation, or return at dispatch?"""
     import jax
     import jax.numpy as jnp
 

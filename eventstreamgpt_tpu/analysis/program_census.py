@@ -3,7 +3,7 @@
 The stack emits dozens of distinct compiled programs — pretrain layouts
 (dp/tp/scan/fsdp), the serving engine's decode + per-bucket prefill +
 boundary pack (float, quantized-cache, and fused-sampling variants), the
-online service's per-replica programs, and the bench width-ladder rungs.
+online service's per-replica programs, and the width-ladder rungs.
 Tier B gates a hand-picked canonical list at toy shapes; Tier C is the
 **census**: every ``aot_programs`` provider registers its program factories
 here (`register_aot_provider` — the hooks live in ``training/sharding.py``,
@@ -63,12 +63,12 @@ REPO_ROOT = Path(__file__).resolve().parents[2]
 MEMORY_PATH = REPO_ROOT / "MEMORY.json"
 COLLECTIVES_PATH = REPO_ROOT / "COLLECTIVES.json"
 
-# The documented serving/training chip budget (docs/scaling.md, bench.py).
+# The documented serving/training chip budget (docs/scaling.md).
 HBM_BUDGET_GB = 16.0
 # Scaled-shape policy: width >= 2048 is where HBM-fit reasoning becomes
 # real (the replicated 4096 train state cannot fit a 16 GB chip) and where
 # the FSDP gradient sweep's reduce-scatter must be visible in the
-# kind-resolved inventory. 12 layers matches the bench ladder geometry.
+# kind-resolved inventory. 12 layers, as the benchmark's ci_w1024.
 SCALED_WIDTHS = (2048, 4096)
 SCALED_LAYERS = 12
 
@@ -335,8 +335,7 @@ def aot_surface() -> dict[str, set[str]]:
         | {
             f"engine_sampling_shard:{k}"
             for k in pc.canonical_sharded_sampling_engine_programs(8)
-        }
-        | {f"engine_megakernel:{k}" for k in pc.canonical_megakernel_engine_program()},
+        },
         "service": {f"service:{k}" for k in pc.canonical_service_programs(8)},
         "fleet": {f"engine_tp:{k}" for k in pc.canonical_tp_engine_programs(4, 2)}
         | {f"engine_swap:{k}" for k in pc.canonical_swap_engine_programs()}

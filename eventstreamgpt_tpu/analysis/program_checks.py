@@ -492,41 +492,6 @@ def canonical_composed_engine_programs(n_data: int = 4, n_model: int = 2) -> dic
     return engine.aot_programs(bucket_len=8, group=2, include_prefill_stream=True)
 
 
-def canonical_megakernel_engine_program() -> dict:
-    """The r20 fused decode megakernel engine, unsharded (one device — the
-    single-replica topology the persistent kernel targets):
-    ``decode_step_impl="pallas_interpret"`` routes the CI decode inner step
-    through ``ops/pallas_decode_step.py`` — the whole layer stack (LN →
-    qkv → cursor write → attention → MLP → event-mask zeroing) as ONE
-    Pallas grid, in interpreter mode on CPU (same program structure as the
-    TPU Mosaic compile modulo the kernel body). The decode program is
-    gated f64-free and host-transfer-free — the kernel must not smuggle
-    callbacks into the serving hot loop — and against a zero-collective
-    budget (``engine_megakernel_1dev``: single device ⇒ any collective is
-    a bug). Returns the full ``aot_programs`` dict so the Tier C census
-    covers every program this topology compiles."""
-    import jax
-
-    from ..serving import GenerationEngine
-
-    ge = _graft_entry()
-    model, batch = ge._make_model_and_batch(batch_size=2, seq_len=8)
-    params = model.init(jax.random.PRNGKey(0), batch)
-    engine = GenerationEngine(
-        model,
-        params,
-        model.config,
-        template=batch,
-        n_slots=4,
-        max_len=12,
-        decode_chunk=2,
-        min_bucket=8,
-        decode_step_impl="pallas_interpret",
-    )
-    assert engine._decode_step_resolved == "pallas_interpret"
-    return engine.aot_programs(bucket_len=8, group=2)
-
-
 def canonical_tp_engine_programs(n_data: int = 4, n_model: int = 2) -> dict:
     """The serve-time tensor-parallel engine programs on a
     ``data×model`` mesh (``serving/engine.py`` with a ``model`` axis): the
@@ -908,11 +873,6 @@ def run_program_checks(
         programs[f"engine_sampling_shard:{label}"] = (fn, args)
     for label, (fn, args) in canonical_composed_engine_programs(4, 2).items():
         programs[f"engine_composed:{label}"] = (fn, args)
-    # The r20 fused decode megakernel (single-replica topology, interpreter
-    # mode): the persistent Pallas layer-stack kernel must stay callback-
-    # free inside the decode hot loop and zero-collective by construction.
-    for label, (fn, args) in canonical_megakernel_engine_program().items():
-        programs[f"engine_megakernel:{label}"] = (fn, args)
 
     lowered = {}
     for label, (fn, args) in programs.items():
@@ -971,7 +931,6 @@ def run_program_checks(
             "engine_composed_prefill_compute_dp4_tp2"
         )
         budget_keys["engine_composed:admit"] = "engine_composed_admit_dp4_tp2"
-        budget_keys["engine_megakernel:decode"] = "engine_megakernel_1dev"
         for label, budget_key in budget_keys.items():
             log(f"compiling {label} for the collective budget gate")
             compiled = lowered[label].compile()

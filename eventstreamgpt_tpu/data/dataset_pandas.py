@@ -642,7 +642,7 @@ class Dataset(DatasetBase[pd.DataFrame, Any]):
                 per_key = work[work[key_col].notna()].groupby(key_col).size()
 
             # One cutoff for every key (same N_total), one vectorized compare
-            # — no per-key Python (VERDICT r03 weak #6).
+            # — no per-key Python.
             cutoff = count_or_proportion(
                 num_possible, self.config.min_valid_vocab_element_observations
             )
@@ -745,7 +745,7 @@ class Dataset(DatasetBase[pd.DataFrame, Any]):
         work = work[work[val_col].notna()]
 
         # 5. Outlier detector fit (one grouped aggregation over all keys —
-        # Preprocessor.fit_grouped; VERDICT r03 weak #6), then filter
+        # Preprocessor.fit_grouped), then filter
         # outliers with vectorized per-row param alignment.
         if self.config.outlier_detector_config is not None:
             M = self._get_preprocessing_model(self.config.outlier_detector_config, for_fit=True)

@@ -2,7 +2,7 @@
 
 Why this exists: a host-collated feed pays, for every batch, the collation
 on the host and a ``device_put`` of the ~2.6 MB dense batch, serialized in
-front of the step (``scripts/probe_feed.py`` separates the two). Caching
+front of the step. Caching
 *host* collation would not touch the per-batch transfer. The design reason
 that holds on any host: few large device programs, small per-step host
 traffic.
@@ -70,7 +70,7 @@ __all__ = ["DeviceDataset", "padded_collate_kernel", "packed_collate_kernel"]
 # tables at upload time: collation then needs NO element-level gathers — TPU
 # gathers at (B, L, M) element granularity measured ~1.6 ms each on this
 # chip, while the dynamic-slice/row-gather formulations over dense tables run
-# the whole collate in ~0.25 ms (scripts/probe_feed.py). The dense tables
+# the whole collate in ~0.25 ms (before PR 22). The dense tables
 # cost ``M / avg_fill`` more HBM than CSR (~1.6x on the bench cohort); both
 # representations stop fitting HBM at roughly the same cohort scale, which is
 # what the residency gate is for.

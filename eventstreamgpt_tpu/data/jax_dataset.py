@@ -309,7 +309,7 @@ class JaxDataset(SeedableMixin, TimeableMixin):
         # Window bounds computed vectorized up front; the remaining per-row
         # work is ragged-list slicing, done over plain numpy/python objects
         # (no pandas row objects) so host cost stays linear in task rows with
-        # small constants (VERDICT weak #6: the previous iterrows version was
+        # small constants (the previous iterrows version was
         # pandas-overhead-bound at MIMIC scale).
         cached = cached_data.set_index("subject_id")
         in_cache = task_df["subject_id"].isin(cached.index)

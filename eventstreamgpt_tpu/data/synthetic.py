@@ -4,8 +4,8 @@ Fabricates the on-disk artifacts the data layer consumes — ``DL_reps/
 {split}_0.parquet`` + ``vocabulary_config.json`` +
 ``inferred_measurement_configs.json`` in the reference's exact schema
 (``/root/reference/sample_data/processed/sample/``) — at configurable scale.
-Used by ``bench.py`` so the benchmark exercises the real pipeline (parquet →
-``JaxDataset`` → host collation → device) rather than a resident synthetic
+Used by ``chip_smoke.py`` so the chip run exercises the real pipeline (parquet →
+``JaxDataset`` → collation → device) rather than a resident synthetic
 batch, and by tests needing bigger-than-sample fixtures.
 
 Shape targets mirror the MIMIC-IV tutorial config (BASELINE.json config 2):
@@ -186,7 +186,7 @@ def write_synthetic_raw_csvs(
     ``admit_vitals.csv`` (MRN, admit/disch range events, department,
     per-vitals-timestamp HR/temp readings) shaped like
     ``/root/reference/sample_data/raw/*.csv`` but with configurable row
-    counts — the input side of the ETL benchmark (VERDICT r02 next #6).
+    counts — the input side of an ETL run at scale.
     Returns ``raw_dir``.
     """
     raw_dir = Path(raw_dir)

@@ -343,7 +343,7 @@ def generate(
             )
     except Exception:
         # A non-finite prompt can crash generation itself; surface the clear
-        # validation error instead of the downstream failure (ADVICE r04).
+        # validation error instead of the downstream failure.
         _check_prompt()
         raise
     _check_prompt()
@@ -419,7 +419,7 @@ def _cached_steps(cache_key: tuple, build):
     if hit is not None:
         # Re-insert on hit: eviction below is LRU, so steady-state shapes
         # (the eval loop's one batch shape) can't be churned out by
-        # one-off shapes (VERDICT r04 weak #8).
+        # one-off shapes.
         _STEP_CACHE[cache_key] = hit
         return hit
     steps = build()
@@ -553,8 +553,8 @@ def _generate_ci(
     # On-device decode loop: with KV caches and no data-dependent stopping
     # criteria (the common path — MaxLength bounds fold into max_new_events),
     # the ENTIRE generation (preallocation, prefix, scan, final masking) is
-    # one jitted program — a single dispatch per call (VERDICT r02 weak #6,
-    # r05 #5). The per-step key-split sequence matches the Python loop
+    # one jitted program — a single dispatch per call.
+    # The per-step key-split sequence matches the Python loop
     # exactly, so both paths sample identical trajectories.
     if use_cache and stopping_criteria is None:
         return steps["generate_program"](params, batch, key)
@@ -685,7 +685,7 @@ def _build_na_steps(model, config, B, input_len, max_new_events):
     def generate_program(params, prompt_batch, key):
         """Whole cached NA generation — tail preallocation, prefix pass,
         first event's level walk, decode scan, final masking — as ONE device
-        program (one dispatch per `generate()` call; VERDICT r05 #5).
+        program (one dispatch per `generate()` call).
         Key-split order matches the step-by-step path exactly."""
         cursor = jnp.asarray(input_len, jnp.int32)
         past = None
@@ -764,7 +764,7 @@ def _generate_na(
     # On-device NA decode: with caches and no data-dependent stopping
     # criteria, the whole generation (preallocation, prefix, every event's
     # level walk, final masking) is one jitted program — a single dispatch
-    # per call (VERDICT r02 weak #6, r05 #5). The key-split sequence matches
+    # per call. The key-split sequence matches
     # the Python path exactly.
     if use_cache and stopping_criteria is None:
         return steps["generate_program"](params, batch, key)

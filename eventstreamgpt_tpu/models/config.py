@@ -506,13 +506,13 @@ class StructuredTransformerConfig(JSONableMixin):
                 f"attention_implementation must be 'einsum', 'pallas_flash', or 'ring'; got "
                 f"{attention_implementation}"
             )
-        # Cross-backend note (ADVICE r04): under 'pallas_flash', narrow-window
+        # Cross-backend note: under 'pallas_flash', narrow-window
         # local layers use the backend-independent band einsum on CPU too, so
         # off-TPU evals of pallas_flash checkpoints are fp32-rounding-close to
         # TPU, not bit-exact; 'einsum' remains the bit-exact-everywhere path.
         self.attention_implementation = attention_implementation
-        # Rematerialization policy for the encoder blocks (VERDICT r05 #3;
-        # r06 MFU round). "none" saves all activations (fastest when they fit HBM;
+        # Rematerialization policy for the encoder blocks
+        # (r06 MFU round). "none" saves all activations (fastest when they fit HBM;
         # at toy shapes every policy only adds recompute), "block" re-runs
         # each block's forward in its backward (nn.remat, minimum memory),
         # "dots" / "dots_no_batch" are jax.checkpoint selective policies
@@ -520,10 +520,8 @@ class StructuredTransformerConfig(JSONableMixin):
         # and "save_attention" composes dots_no_batch with
         # save_only_these_names on the checkpoint-named attention outputs
         # so the backward never re-executes the flash/splash/band attention
-        # custom-calls — the production-width policy candidate (the bench
-        # width probe A/Bs it against dots_no_batch every run and reports
-        # both; docs/performance.md). Measured A/Bs: BASELINE.md (pre-PR-22 record, git history)
-        # "Rematerialization" tables.
+        # custom-calls (docs/performance.md). The benchmark's width-1024
+        # configuration names dots_no_batch.
         if gradient_checkpointing not in (
             "none", "block", "dots", "dots_no_batch", "save_attention"
         ):
@@ -550,7 +548,7 @@ class StructuredTransformerConfig(JSONableMixin):
         # broadcast-reduce attention (ops/band_attention.dep_graph_attention)
         # instead of batched tiny dot_generals. Numerics-parity gated in
         # tests (tests/models/test_dep_graph_fused.py); False restores the
-        # einsum path for A/Bs (bench.py records both every run).
+        # einsum path for A/Bs.
         self.dep_graph_fused_attention = dep_graph_fused_attention
         # Which implementation the fused dep-graph walk runs on: None/"auto"
         # resolves per backend (the hand-tiled Pallas kernel on TPU, the
@@ -682,7 +680,7 @@ class StructuredTransformerConfig(JSONableMixin):
     def compute_dtype(self):
         """The activation/matmul dtype implied by ``precision``.
 
-        Mixed-precision discipline (VERDICT r02 #1): bf16 activations and
+        Mixed-precision discipline: bf16 activations and
         matmuls, fp32 parameters, fp32 softmax and losses. The reference's
         closest analog is ``torch.set_float32_matmul_precision("high")``
         (``/root/reference/scripts/pretrain.py:24``).

@@ -315,7 +315,7 @@ class GenerativeOutputLayerBase(nn.Module):
 
         is_observed_score = self.IsObservedLayer(encoded).astype(jnp.float32)
 
-        # Head-stack lever (r06 MFU round, VERDICT r05 next-round #2): when this call covers only a
+        # Head-stack lever (r06 MFU round): when this call covers only a
         # narrow span of the unified vocabulary — the NA per-level walk,
         # where e.g. the event_type level needs ~1% of the columns — project
         # just those spans of the head kernel (column-exact; see

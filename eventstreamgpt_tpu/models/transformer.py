@@ -696,7 +696,7 @@ class InnerSelfAttention(nn.Module):
         # the splash-attention kernel with a block-banded `LocalMask`, whose
         # scheduler skips blocks entirely outside the window — so the default
         # alternating ["local", "global"] stack stays on fused kernels end to
-        # end (VERDICT r02 #4). Falls back to the einsum path whenever kernel
+        # end. Falls back to the einsum path whenever kernel
         # preconditions don't hold (KV cache, dep-graph static-kv, attention
         # dropout, attention-weight outputs, non-TPU backends).
         fused_ok = (
@@ -706,7 +706,7 @@ class InnerSelfAttention(nn.Module):
             and not output_attentions
             and (float(cfg.attention_dropout) == 0.0 or not self.has_rng("dropout"))
         )
-        # Fused dep-graph rows (VERDICT r05 weak #5 / next #6): the NA walk's
+        # Fused dep-graph rows: the NA walk's
         # (B·L, G+1) flattened graphs are far too small for MXU-shaped
         # attention — the batched dot_generals pay layout copies comparable
         # to their FLOPs. ops/band_attention.dep_graph_attention re-expresses
@@ -750,8 +750,8 @@ class InnerSelfAttention(nn.Module):
         # Narrow-window local layers skip the kernels entirely: the chunked
         # band einsum (ops/band_attention.py) touches only a (C, 2C) logits
         # plane per window-sized chunk and measured ~35-45% faster fwd+bwd
-        # than the splash kernel's best block shape at production width
-        # (scripts/probe_local_band.py). It is backend-independent (pure
+        # than the splash kernel's best block shape at production width,
+        # window <= 128 (before PR 22). It is backend-independent (pure
         # einsums), so it activates under the fused gate on CPU too; splash
         # remains the local path for wide windows, where its block-skipping
         # scheduler amortizes.
@@ -874,8 +874,7 @@ class InnerSelfAttention(nn.Module):
 
             # chunk_size is left at its default C=window — the settled
             # production choice: fatter chunks win layer microbenches but
-            # lose the interleaved step-level A/B (BASELINE.md (pre-PR-22 record, git history)); the knob
-            # stays for per-deployment tuning via probes.
+            # lost the interleaved step-level A/B (before PR 22).
             attn_output = band_local_attention(query, key, value, seg, self.window_size)
             outputs = {"present_key_value": None, "_heads_first_out": True}
         elif use_splash:
@@ -1157,9 +1156,9 @@ def remat_block_cls(config: StructuredTransformerConfig, use_flag: bool = False,
     """`InnerBlock` (or ``block_cls``, a block of the same call signature),
     wrapped per the configured rematerialization policy.
 
-    ``config.gradient_checkpointing`` selects the policy (VERDICT r05 #3;
-    r06 MFU round): ``"none"`` (config default — at toy shapes every policy only
-    adds recompute, BASELINE.md (pre-PR-22 record, git history) "Rematerialization"), ``"block"``
+    ``config.gradient_checkpointing`` selects the policy
+    (r06 MFU round): ``"none"`` (config default — at toy shapes every policy only
+    adds recompute), ``"block"``
     (whole-block ``nn.remat``, minimum memory), ``"dots"`` /
     ``"dots_no_batch"`` (``jax.checkpoint`` selective policies saving matmul
     outputs — the memory/FLOPs middle ground for configs whose activations

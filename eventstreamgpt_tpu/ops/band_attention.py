@@ -4,8 +4,8 @@ The reference's "local" attention layers (sliding window, default 32 —
 ``/root/reference/EventStream/transformer/transformer.py:109-118``) touch a
 band of at most ``window`` keys per query, so any formulation that sweeps an
 ``(L, L)`` plane — blocked or not — is overhead. Device measurements at
-production width (``scripts/probe_local_band.py`` / ``probe_splash_blocks.py``,
-B=8, L=1024, window=32, fwd+bwd per layer, sustained protocol):
+production width before PR 22 (B=8, L=1024, window=32, fwd+bwd per layer; not
+measured on the current code):
 
 * splash kernel, best block shape (its 128x128 default): 1.45 ms
 * this band einsum: measured ~35-45% faster in the same windows
@@ -136,8 +136,7 @@ def dep_graph_attention(
     overhead: XLA tiles each (Q, S) logits plane as an MXU matmul against
     the ``(B·L, H, G, d)`` layout and pays relayout copies comparable to
     the matmuls themselves (~1.5 ms/step at the bench shape) plus lost
-    loop fusion in the backward (~1.1 ms) — the r05 op-level attribution,
-    VERDICT r05 "Next round" #6.
+    loop fusion in the backward (~1.1 ms) — the r05 op-level attribution.
 
     This formulation contains **no dot_general at all**: logits and the
     probability-weighted value sum are broadcast-multiply + lane-reduction

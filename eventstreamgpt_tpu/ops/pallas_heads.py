@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the head stack's vocabulary-plane gathers.
 
-Device profiling of the production train step (BASELINE.md (pre-PR-22 record, git history) "remaining hot
-spots") attributed ~40% of the toy-shape head cost to XLA's lowering of
+Device profiling of the production train step (before PR 22) attributed
+~40% of the toy-shape head cost to XLA's lowering of
 the multivariate-regression head's last-axis gathers and their backward
 scatter on the ``(B, L, 2*vocab)`` projection plane
 (``generative_layers.py`` `GaussianIndexedRegressionLayer`, mirroring the

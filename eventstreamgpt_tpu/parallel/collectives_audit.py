@@ -1,6 +1,6 @@
 """Communication audit: collective inventory of a compiled sharded program.
 
-VERDICT r05 #4: the multi-chip dry run proves the parallel layouts *execute*;
+The multi-chip dry run proves the parallel layouts *execute*;
 this module quantifies what they *communicate* — without hardware. The
 compiled HLO names every collective XLA GSPMD inserted (op kind + output
 shape), so per-layout communication volume is a static property of the

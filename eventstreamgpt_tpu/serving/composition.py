@@ -120,16 +120,6 @@ MATRIX: tuple[Cell, ...] = (
         "test_sharded_sampling_matches_xla_tail",
     ),
     Cell(
-        "decode megakernel",
-        "int8 KV cache",
-        "composes",
-        "quantize-on-write / dequantize-on-read fused into the kernel "
-        "body; the fused-XLA variant matches the reference engine "
-        "bitwise, interpret mode within the r09 envelope",
-        pinned_by="tests/test_decode_megakernel.py::TestEngineParity::"
-        "test_int8_cache_composes",
-    ),
-    Cell(
         "int8 KV cache",
         "online service",
         "composes",
@@ -171,45 +161,6 @@ MATRIX: tuple[Cell, ...] = (
         "raises",
         "the dep-graph caches reset per event and do not page",
         match="nested-attention models",
-    ),
-    Cell(
-        "decode megakernel",
-        "speculative decoding",
-        "raises",
-        "spec replaces the decode step with the draft-chunk/verify "
-        "program pair, which the kernel does not fuse yet",
-        match="megakernel x spec",
-    ),
-    Cell(
-        "decode megakernel",
-        "paged KV cache",
-        "raises",
-        "the kernel reads monolithic (B, H, M, D) cache planes; the "
-        "block-table indirection is not fused yet",
-        match="megakernel x paged",
-    ),
-    Cell(
-        "decode megakernel",
-        "serving mesh",
-        "raises",
-        "the layer grid is not yet shard_mapped over the slot/model axes",
-        match="megakernel x mesh",
-    ),
-    Cell(
-        "decode megakernel",
-        "nested attention",
-        "raises",
-        "NA decode walks per-event dep-graph levels through its own fused "
-        "kernels (ops/pallas_dep_graph.py)",
-        match="megakernel x NA",
-    ),
-    Cell(
-        "decode megakernel",
-        "scan_layers checkpoints",
-        "raises",
-        "the kernel stacks unrolled h{i} params into its grid axis; "
-        "migrate stacked checkpoints with unstack_layer_params",
-        match="unstack_layer_params",
     ),
     Cell(
         "speculative decoding",

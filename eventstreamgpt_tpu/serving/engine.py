@@ -453,14 +453,6 @@ class GenerationEngine:
             cursor, dequantized on read inside the attention contraction
             (`ops.kv_quant`; docs/serving.md "Quantized decode cache" for
             the tolerance contract and the slots-per-chip math).
-        decode_step_impl: the CI decode inner-step implementation.
-            ``None``/``"auto"`` run the A/B-measured production default
-            (fused XLA); ``"pallas"``/``"pallas_interpret"`` route the
-            whole layer stack through the fused decode megakernel
-            (`ops.pallas_decode_step`; docs/performance.md "The decode
-            megakernel" for the fusion boundary and when each side wins).
-            NA models, paged caches, spec, scan_layers checkpoints and
-            serving meshes raise loudly here (issue #21).
     """
 
     def __init__(
@@ -490,7 +482,6 @@ class GenerationEngine:
         block_size: int = 16,
         num_blocks: int | None = None,
         spec: Optional[SpecConfig] = None,
-        decode_step_impl: str | None = None,
         greedy: bool = False,
         health_sentinel: bool = True,
         health_retries: int = 0,
@@ -735,80 +726,6 @@ class GenerationEngine:
             self._tables = np.zeros((self.n_slots, blocks_per_slot), np.int32)
         elif num_blocks is not None:
             raise ValueError("num_blocks requires paged_kv=True")
-
-        # r20 decode megakernel (ops/pallas_decode_step.py): fuse the CI
-        # decode inner step — per-layer LN/qkv/cursor-write/attention/MLP +
-        # the between-layer event-mask zeroing — into one persistent Pallas
-        # kernel. `auto` resolves to the A/B-measured production default
-        # (fused XLA; bench.py `decode_step_impl_winner` names it, the r06
-        # discipline), so the kernel is explicit opt-in; the interpret mode
-        # is the CI parity gate. Composition matrix (docs/serving.md): kvq
-        # and hot-swap compose; NA / paged / spec / scan_layers / meshes
-        # are loud errors below (issue #21 tracks the closure).
-        self.decode_step_impl = decode_step_impl
-        if decode_step_impl in (None, "auto"):
-            self._decode_step_resolved = "xla"
-        elif decode_step_impl == "pallas":
-            from ..ops.pallas_decode_step import MOSAIC_REFUSAL
-
-            raise NotImplementedError(MOSAIC_REFUSAL)
-        elif decode_step_impl in ("pallas_interpret", "xla"):
-            self._decode_step_resolved = decode_step_impl
-        else:
-            raise ValueError(
-                f"decode_step_impl must be one of None/'auto'/'pallas'/"
-                f"'pallas_interpret'/'xla', got {decode_step_impl!r}"
-            )
-        if self._decode_step_resolved != "xla":
-            if self._is_na:
-                raise ValueError(
-                    "the decode megakernel fuses the CI one-event step only; "
-                    "nested-attention decode walks the per-event dep-graph "
-                    "levels through their own fused kernels "
-                    "(ops/pallas_dep_graph.py) and does not route through it "
-                    "(tracked as ROADMAP item 3, composition closure — the "
-                    "megakernel x NA cell; issue #21). Nearest supported "
-                    "configuration: CI engines with decode_step_impl set, or "
-                    "NA engines with decode_step_impl='xla'"
-                )
-            if spec is not None:
-                raise ValueError(
-                    "speculative decoding replaces the decode step with the "
-                    "draft-chunk/verify program pair, which the megakernel "
-                    "does not fuse yet (tracked as ROADMAP item 3, "
-                    "composition closure — the megakernel x spec cell; issue "
-                    "#21). Nearest supported configurations: spec with "
-                    "decode_step_impl='xla' (the fused sampling tail still "
-                    "applies), or the megakernel without spec"
-                )
-            if self.paged_kv:
-                raise ValueError(
-                    "the decode megakernel reads the monolithic (B, H, M, D) "
-                    "cache planes; the paged pool's block-table indirection "
-                    "is not fused yet (tracked as ROADMAP item 3, "
-                    "composition closure — the megakernel x paged cell; "
-                    "issue #21). Nearest supported configurations: "
-                    "monolithic caches (kv_cache_dtype='int8' composes), or "
-                    "paged_kv with decode_step_impl='xla'"
-                )
-            if getattr(config, "scan_layers", False):
-                raise ValueError(
-                    "the decode megakernel stacks the unrolled h{i} layer "
-                    "params into its leading grid axis; scan_layers "
-                    "checkpoints store the stacked h_scan layout instead — "
-                    "migrate with models.transformer.unstack_layer_params "
-                    "(or run with decode_step_impl='xla')"
-                )
-            if mesh is not None:
-                raise ValueError(
-                    "the decode megakernel is single-device for now: its "
-                    "layer grid is not yet shard_mapped over the slot/model "
-                    "mesh axes (tracked as ROADMAP item 3, composition "
-                    "closure — the megakernel x mesh cell; issue #21). "
-                    "Nearest supported configurations: an unsharded engine "
-                    "with the megakernel, or a mesh with "
-                    "decode_step_impl='xla'"
-                )
 
         self.scheduler = Scheduler(
             self.n_slots,
@@ -1335,87 +1252,15 @@ class GenerationEngine:
             return NAPast(seq_past=seq, dep_graph_past=new.dep_graph_past)
         return self._merge_rows(active, new, old)
 
-    def _mega_apply(self, params, view, caches):
-        """The CI decode forward through the fused decode-step megakernel.
-
-        Splits ``model.apply`` at its natural seams: the input layer and
-        the ``ln_f`` + output-layer epilogue run as ordinary flax
-        submodule applies on the SAME param subtrees the full model uses,
-        while the entire layer stack between them runs as one
-        `ops.pallas_decode_step.decode_stack_step` call. Weights restack
-        inside the jit from the ``params`` argument, so hot-swap flips
-        keep working; quantized caches pass their scale tables through
-        and quantize-on-write inside the kernel (`ops.kv_quant` parity).
-        Returns the same `GenerativeSequenceModelOutput` shape the model
-        call yields (preds + refreshed per-layer cache tuple).
-        """
-        import flax.linen as nn
-
-        from ..models.ci_model import (
-            ConditionallyIndependentGenerativeOutputLayer,
-        )
-        from ..models.transformer import (
-            ConditionallyIndependentPointProcessInputLayer,
-        )
-        from ..ops.pallas_decode_step import decode_stack_step, stack_layer_weights
-
-        cfg = self.config
-        p = params["params"]
-        enc = p["encoder"]
-        embeds = ConditionallyIndependentPointProcessInputLayer(cfg).apply(
-            {"params": enc["input_layer"]}, view
-        )
-        quantized = caches[0].key_scale is not None
-        windows = tuple(
-            cfg.seq_window_size if t == "local" else 0
-            for t in cfg.seq_attention_layers
-        )
-        h, nkc, nvc, nks, nvs, nmask, nlen = decode_stack_step(
-            stack_layer_weights(enc, cfg.num_hidden_layers),
-            jnp.stack([c.key for c in caches]),
-            jnp.stack([c.value for c in caches]),
-            jnp.stack([c.key_scale for c in caches]) if quantized else None,
-            jnp.stack([c.value_scale for c in caches]) if quantized else None,
-            embeds[:, 0, :],
-            caches[0].length,
-            view.event_mask[:, 0],
-            caches[0].mask,
-            windows=windows,
-            activation=cfg.activation_function,
-            layer_norm_eps=float(cfg.layer_norm_epsilon),
-            impl=self._decode_step_resolved,
-        )
-        encoded = nn.LayerNorm(
-            epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype
-        ).apply({"params": enc["ln_f"]}, h[:, None, :])
-        out = ConditionallyIndependentGenerativeOutputLayer(cfg).apply(
-            {"params": p["output_layer"]}, view, encoded, is_generation=True
-        )
-        new_caches = tuple(
-            KVCache(
-                key=nkc[i],
-                value=nvc[i],
-                mask=nmask,
-                length=nlen,
-                key_scale=None if nks is None else nks[i],
-                value_scale=None if nvs is None else nvs[i],
-            )
-            for i in range(cfg.num_hidden_layers)
-        )
-        return out.replace(past_key_values=new_caches)
-
     # CI decode: one event per slot per step, scanned decode_chunk times.
     def _decode_step_ci(self, params, st: SlotState) -> SlotState:
         config = self.config
         active = st.live & ~st.done
         new_keys, step_keys = _vmap_split(st.keys)
         view = _trim_to_event(st.big, st.cursor - 1)
-        if self._decode_step_resolved != "xla":
-            out = self._mega_apply(params, view, st.caches)
-        else:
-            out = self.model.apply(
-                params, view, past=st.caches, use_cache=True, is_generation=True
-            )
+        out = self.model.apply(
+            params, view, past=st.caches, use_cache=True, is_generation=True
+        )
         preds_last = _slice_preds_at(out.preds, jnp.asarray(0))
         em_last = take_event(st.big.event_mask, st.cursor - 1)
         sample = self._sample_rows(preds_last, em_last, step_keys, active=active)
@@ -3842,8 +3687,8 @@ class GenerationEngine:
 
         ``config`` / ``max_len`` / ``params_bytes`` override the engine's
         own geometry so capacity stays honest at widths this engine was not
-        built at: the bench width ladder reports slots/chip for each ladder
-        config (hidden 1024 → 4096) through the SAME accounting instead of
+        built at: a wider config (hidden 1024 → 4096) reads slots/chip
+        through the SAME accounting instead of
         extrapolating from the probe shape (r10 satellite). The per-slot
         content-row term is measured from THIS engine's state and re-scaled
         by the ``max_len`` ratio (content rows grow with sequence capacity,
@@ -3971,7 +3816,6 @@ class GenerationEngine:
                 "active_slot_steps": active,
                 "wasted_decode_frac": round(1.0 - active / max(total, 1), 4),
                 "sampling_impl": self.sampling_impl_resolved,
-                "decode_step_impl": self._decode_step_resolved,
                 "greedy": self.greedy,
                 "health_sentinel": self.health_sentinel,
                 "health_quarantined_total": self._health_quarantined,
@@ -4246,10 +4090,6 @@ def _census_programs():
         "engine_composed:prefill_b8": "engine_composed_prefill_dp4_tp2",
         "engine_composed:prefill_compute_b8": "engine_composed_prefill_compute_dp4_tp2",
         "engine_composed:admit": "engine_composed_admit_dp4_tp2",
-        # r20 megakernel: the persistent Pallas layer-stack decode on the
-        # single-replica topology — zero collectives by construction, and
-        # the kernel body must stay callback-free in the hot loop.
-        "engine_megakernel:decode": "engine_megakernel_1dev",
     }
     out = {}
     for prefix, programs in (
@@ -4271,7 +4111,6 @@ def _census_programs():
         # engine so every program it compiles carries committed budgets.
         ("engine_sampling_shard", pc.canonical_sharded_sampling_engine_programs(8)),
         ("engine_composed", pc.canonical_composed_engine_programs(4, 2)),
-        ("engine_megakernel", pc.canonical_megakernel_engine_program()),
     ):
         # Composed engines run the spec program set (draft/verify/...), so
         # they take the spec donation map.

@@ -459,7 +459,7 @@ def evaluate(
 
     Fill rows in the final short batch are blanked + flagged by
     ``valid_mask``; loss parts re-weight by the valid count so no subject is
-    double-counted (VERDICT weak #5). ``place_batch`` overrides the default
+    double-counted. ``place_batch`` overrides the default
     data-sharded placement — context-parallel callers pass ``shard_batch_cp``
     so the event axis lands on the ``context`` mesh axis up front instead of
     being resharded at every ring-attention boundary. ``device_data`` (a
@@ -1163,7 +1163,7 @@ def train(
                     # Asynchronous host input pipeline: collation + device_put
                     # run in a background thread with a depth-2 device buffer,
                     # so the host path overlaps the previous step's compute
-                    # (VERDICT r02 #2). Event counts are computed host-side in
+                    # Event counts are computed host-side in
                     # the worker — reading them here would otherwise force a
                     # device sync every step.
                     batch_iter = prefetch_to_device(

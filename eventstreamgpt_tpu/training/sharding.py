@@ -294,9 +294,9 @@ def train_state_bytes(n_params: int, adam_moments: int = 2, grad_bytes: int = 4)
     """Analytic steady-state training footprint of ``n_params`` parameters:
     fp32 params + fp32 Adam ``mu``/``nu`` + one transient fp32 gradient tree
     (activations excluded — they scale with batch/remat policy, not width
-    alone). The bench width ladder holds this against the documented
-    16 GB/chip HBM budget to decide which rungs fit replicated and which
-    are FSDP-only."""
+    alone). The census's width ladder (`analysis/program_census.py`) holds
+    this against the documented 16 GB/chip HBM budget to decide which rungs
+    fit replicated and which are FSDP-only."""
     return int(n_params) * (4 * (1 + adam_moments) + grad_bytes)
 
 

@@ -304,8 +304,8 @@ def zero_shot_evaluation(
 
     # Zero-shot is the most generation-hungry workload in the framework
     # (num_samples x generate per batch); shard the expanded batch over a
-    # data mesh so all chips decode (VERDICT r02 missing #1; the reference
-    # runs this under Lightning DDP).
+    # data mesh so all chips decode (the reference runs this under
+    # Lightning DDP).
     mesh = data_parallel_mesh(batch_size * num_samples)
 
     engine = None

@@ -238,7 +238,7 @@ def configure_compile_cache() -> str:
     ``<checkout>/.jax_cache`` (derived from this package's location,
     git-ignored): the path is part of the cache key, so it is never a
     tempfile, pid, or timestamp. Called by the device-running entry points
-    (``chip_smoke.py``, ``bench.py``, ``scripts/*.py`` ``__main__``), not
+    (``chip_smoke.py``, ``scripts/*.py`` ``__main__``), not
     at import. Returns the directory in effect.
     """
     import os

@@ -113,7 +113,9 @@ class TestScanForwardParity:
 
         loss_u = model.apply(params, batch).loss
         loss_s = scan_model.apply(sparams, batch).loss
-        assert np.asarray(loss_u).tobytes() == np.asarray(loss_s).tobytes()
+        # Two separately compiled programs: equal within one float32 ulp.
+        loss_u, loss_s = np.float32(loss_u), np.float32(loss_s)
+        assert abs(loss_u - loss_s) <= np.spacing(abs(loss_u))
 
         gu = jax.grad(lambda p: model.apply(p, batch).loss)(params)
         gs = unstack_layer_params(

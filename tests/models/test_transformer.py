@@ -199,8 +199,7 @@ class TestRematPolicies:
     """Every gradient_checkpointing policy computes identical loss + grads.
 
     Rematerialization only changes WHAT is recomputed in the backward, never
-    the math; the r05 width A/B (scripts/probe_remat.py, BASELINE.md (pre-PR-22 record, git history)) picks
-    speed, this pins correctness.
+    the math; a measured step picks for speed, this pins correctness.
     """
 
     def test_policies_match_no_remat(self):

@@ -308,18 +308,3 @@ def test_held_experts_at_the_cells_shapes(one_chip, monkeypatch):
 
     text = _compile(grad, *args)
     assert " while(" in text  # the buffer is walked as far as the held pairs reach
-
-
-def test_decode_megakernel_is_refused_loudly():
-    """`ops/pallas_decode_step.py` does not lower under Mosaic (PR 22,
-    CHANGES.md): the compiled impl raises instead of interpreting or
-    giving way to XLA at run time; ``auto`` is the XLA step in code."""
-    from eventstreamgpt_tpu.ops.pallas_decode_step import decode_stack_step
-
-    with pytest.raises(NotImplementedError, match="Mosaic"):
-        decode_stack_step(
-            {}, jnp.zeros((1, 1, 1, 8, 8)), jnp.zeros((1, 1, 1, 8, 8)), None, None,
-            jnp.zeros((1, 8)), jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
-            jnp.ones((1, 8), bool), windows=(0,), activation="gelu",
-            layer_norm_eps=1e-5, impl="pallas",
-        )

@@ -22,7 +22,6 @@ behind a router as ONE composed engine and requires per-request outputs
 identical to the synchronous single-engine reference.
 """
 
-import copy
 import re
 from pathlib import Path
 
@@ -141,48 +140,6 @@ def _paged_na(ci, na):
     return engine_for(model, params, config, prompt, paged_kv=True)
 
 
-def _mega_spec(ci, na):
-    config, model, params, prompt, _ = ci
-    return engine_for(
-        model, params, config, prompt,
-        spec=spec_for(ci), decode_step_impl="pallas_interpret",
-    )
-
-
-def _mega_paged(ci, na):
-    config, model, params, prompt, _ = ci
-    return engine_for(
-        model, params, config, prompt,
-        paged_kv=True, block_size=4, decode_step_impl="pallas_interpret",
-    )
-
-
-def _mega_mesh(ci, na):
-    from eventstreamgpt_tpu.training.sharding import make_mesh
-
-    config, model, params, prompt, _ = ci
-    return engine_for(
-        model, params, config, prompt,
-        mesh=make_mesh(2, 1), decode_step_impl="pallas_interpret",
-    )
-
-
-def _mega_na(ci, na):
-    config, model, params, prompt, _ = na
-    return engine_for(
-        model, params, config, prompt, decode_step_impl="pallas_interpret"
-    )
-
-
-def _mega_scan(ci, na):
-    config, model, params, prompt, _ = ci
-    scan_cfg = copy.deepcopy(config)
-    scan_cfg.scan_layers = True
-    return engine_for(
-        model, params, scan_cfg, prompt, decode_step_impl="pallas_interpret"
-    )
-
-
 def _spec_criteria(ci, na):
     from eventstreamgpt_tpu.generation.stopping_criteria import MaxLengthCriteria
 
@@ -212,11 +169,6 @@ OPEN_BUILDERS = {
     ("paged KV cache", "speculative decoding"): _paged_spec,
     ("paged KV cache", "tensor parallelism"): _paged_tp,
     ("paged KV cache", "nested attention"): _paged_na,
-    ("decode megakernel", "speculative decoding"): _mega_spec,
-    ("decode megakernel", "paged KV cache"): _mega_paged,
-    ("decode megakernel", "serving mesh"): _mega_mesh,
-    ("decode megakernel", "nested attention"): _mega_na,
-    ("decode megakernel", "scan_layers checkpoints"): _mega_scan,
     ("speculative decoding", "device stopping criteria"): _spec_criteria,
     ("multi_op sampling tail", "top_k/top_p filtering"): _multiop_filter,
     ("fork() branched rollouts", "monolithic KV cache"): _fork_monolithic,

@@ -5,6 +5,7 @@ import enum
 import json
 from pathlib import Path
 
+import jax
 import pytest
 
 from eventstreamgpt_tpu.utils import (
@@ -160,3 +161,21 @@ def test_unstructure_and_flat():
     obj = _Outer()
     assert unstructure(obj) == {"name": "hi", "inner": {"x": 1, "color": "red"}}
     assert to_dict_flat({"a": {"b": 1}, "c": 2}) == {"a.b": 1, "c": 2}
+
+
+def test_compile_cache_is_one_fixed_place(monkeypatch):
+    """`JAX_COMPILATION_CACHE_DIR` wins untouched; otherwise one fixed path
+    inside the checkout, never a tempfile/pid/time."""
+    from eventstreamgpt_tpu.utils.config_tool import configure_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert configure_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == was  # nothing set in code
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        expected = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert configure_compile_cache() == configure_compile_cache() == expected
+        assert jax.config.jax_compilation_cache_dir == expected
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

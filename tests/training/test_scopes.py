@@ -145,7 +145,7 @@ def test_one_contract_and_no_knob():
             for used in re.findall(rf"\b{call}\(\"([a-z_]+)\"", text):
                 assert used in known, (path, call, used)
     assert names <= set(program_scopes.SCOPES) | {"host/" + n for n in program_scopes.HOST_SPANS}
-    for path in [*_program_files(), *(REPO / "benchmark").rglob("*.py"), REPO / "chip_smoke.py", REPO / "bench.py"]:
+    for path in [*_program_files(), *(REPO / "benchmark").rglob("*.py"), REPO / "chip_smoke.py"]:
         assert "include_metadata_in_key" not in path.read_text(), path
 
 
