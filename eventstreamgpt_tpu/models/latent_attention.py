@@ -14,6 +14,27 @@ segment of a packed row. The core is the ``pallas_flash`` kernel the classic
 global layers use wherever the two head widths are equal (they are published
 equal: 192 + 64 and 256), and the einsum elsewhere. There is no decode cache
 yet: a latent paged cache and the absorbed decode path are ROADMAP R2/R3.
+
+**How q, k and v are assembled.** Two formulations of the lines above, one
+parameter tree (``q_a_proj``, ``q_a_layernorm``, ``q_b_proj``,
+``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``, ``o_proj``):
+
+* *In the flash op's layout* (`ops/pallas_rope_join.py`), where a kernel is
+  taken (`ops.impl_select.resolve_impl`: a TPU backend, or
+  ``$ESGPT_PALLAS_IMPL=pallas_interpret`` anywhere) and the head widths allow
+  it (``nope + rope`` whole 128-lane tiles, an even ``rope`` of at most 128,
+  ``v_head_dim == nope + rope``): ``kv_b_proj``'s kernel is sliced per head
+  into a value kernel and a key kernel padded with ``rope`` zero columns a
+  head (`split_kv_kernel`: 4.6M elements at the published widths), so
+  ``value`` and the key's nope part leave their products as ``[B, S, H * d]``
+  row-major, as ``q_b_proj`` leaves the query; one in-place Pallas pass then
+  rotates the query's rope lanes and writes the rotated shared key part into
+  every head's. Nothing between the products and the flash kernels, forward
+  or backward, is sliced, concatenated or re-laid by XLA (which otherwise
+  lays all of it out events-minor: `PERF.md` section 6, PR 31).
+* *With XLA's slices and concatenations* (`rotate`) everywhere else: the CPU,
+  head widths such as the tests' 8 + 4. Asked for ``pallas_flash`` where a
+  kernel is taken and the widths refuse, the layer warns, as `_core` does.
 """
 
 from __future__ import annotations
@@ -66,6 +87,41 @@ def rotate(x, positions, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
 
 
+def split_kv_kernel(kernel, heads: int, nope: int, rope: int):
+    """``kv_b_proj``'s kernel ``[r, heads * (nope + v)]`` as a key kernel
+    ``[r, heads * (nope + rope)]``, a head's nope columns followed by ``rope``
+    zero columns (the lanes `ops.pallas_rope_join.rope_join` fills), and a
+    value kernel ``[r, heads * v]``. Sliced where the weights are, so the
+    products' outputs are whole heads and nothing slices the activations."""
+    per_head = kernel.reshape(kernel.shape[0], heads, -1)
+    key = jnp.pad(per_head[..., :nope], ((0, 0), (0, 0), (0, rope)))
+    return _whole_gradient(key.reshape(kernel.shape[0], -1)), per_head[..., nope:].reshape(kernel.shape[0], -1)
+
+
+@jax.custom_vjp
+def _whole_gradient(w):
+    """``w``; its cotangent behind an optimization barrier. The key kernel's
+    gradient is then a plain ``[r, heads * d]`` product like the value
+    kernel's: without it XLA folds the per-head slice into the product and
+    transposes the product's big operand, ``dkey``, for it."""
+    return w
+
+
+_whole_gradient.defvjp(lambda w: (w, None), lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
+class _Kernel(nn.Module):
+    """A bias-free ``nn.Dense``'s parameter, leaf for leaf, for a caller that
+    slices it before the product."""
+
+    shape: tuple[int, int]
+    init_std: float
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.normal(stddev=self.init_std), self.shape, jnp.float32)
+
+
 class LatentAttention(nn.Module):
     config: StructuredTransformerConfig
 
@@ -82,26 +138,69 @@ class LatentAttention(nn.Module):
                 dtype=dt, name=name,
             )
 
+        impl = self._assembly_impl()
         with scope("attn_latent"):
             c_q = RMSNorm(cfg.layer_norm_epsilon, dt, name="q_a_layernorm")(dense(cfg.q_lora_rank, "q_a_proj")(x))
-            q = dense(H * (dn + dr), "q_b_proj")(c_q).reshape(B, S, H, dn + dr)
+            q = dense(H * (dn + dr), "q_b_proj")(c_q)
             kv_a = dense(cfg.kv_lora_rank + dr, "kv_a_proj_with_mqa")(x)
             c_kv = RMSNorm(cfg.layer_norm_epsilon, dt, name="kv_a_layernorm")(kv_a[..., : cfg.kv_lora_rank])
-            kv = dense(H * (dn + dv), "kv_b_proj")(c_kv).reshape(B, S, H, dn + dv)
             positions = segment_positions(segment_ids, B, S)
-            q_r = rotate(q[..., dn:], positions, cfg.rope_theta)
-            k_r = rotate(kv_a[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
-            query = jnp.concatenate([q[..., :dn], q_r], axis=-1)
-            key = jnp.concatenate(
-                [kv[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, dr))], axis=-1
-            )
-            value = kv[..., dn:]
+            if impl == "xla":
+                q = q.reshape(B, S, H, dn + dr)
+                kv = dense(H * (dn + dv), "kv_b_proj")(c_kv).reshape(B, S, H, dn + dv)
+                q_r = rotate(q[..., dn:], positions, cfg.rope_theta)
+                k_r = rotate(kv_a[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
+                query = jnp.concatenate([q[..., :dn], q_r], axis=-1)
+                key = jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, dr))], axis=-1
+                )
+                value = kv[..., dn:]
+            else:
+                # q, k and v in the flash op's layout, [B, S, H * d] row-major: the key/value and
+                # nope/rope splits made on the weights, RoPE and the join in one pass in place.
+                from ..ops.pallas_rope_join import rope_join
+                from ..parallel.context import per_batch_shard
+
+                kernel = _Kernel((cfg.kv_lora_rank, H * (dn + dv)), cfg.init_std, name="kv_b_proj")()
+                w_key, w_value = split_kv_kernel(kernel.astype(dt), H, dn, dr)
+                value = jnp.dot(c_kv, w_value)
+                query, key = per_batch_shard(
+                    lambda q, k, r, p: rope_join(
+                        q, k, r, p, heads=H, rope=dr, theta=cfg.rope_theta, interpret=impl == "pallas_interpret"
+                    ),
+                    q, jnp.dot(c_kv, w_key), kv_a[..., cfg.kv_lora_rank :], positions,
+                )
+                query, key, value = (a.reshape(B, S, H, dv) for a in (query, key, value))
 
         with scope("attn_global"):
             out = self._core(query, key, value, attention_mask, segment_ids)
             out = checkpoint_name(out, ATTENTION_CHECKPOINT_NAME)
         with scope("attn_proj"):
             return dense(cfg.hidden_size, "o_proj")(out.reshape(B, S, H * dv))
+
+    def _assembly_impl(self) -> str:
+        """``"pallas"`` / ``"pallas_interpret"``: q, k and v are assembled in
+        the flash op's layout (`ops/pallas_rope_join.py`); ``"xla"``: slices,
+        `rotate` and concatenations. Chosen as every kernel of ``ops/`` is
+        (`ops.impl_select.resolve_impl`: the kernel on a TPU), where the head
+        widths allow it."""
+        from ..ops.impl_select import resolve_impl
+        from ..ops.pallas_rope_join import rope_join_applies
+
+        cfg = self.config
+        impl = resolve_impl(None, "latent attention's assembly")
+        if impl == "xla" or rope_join_applies(cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim):
+            return impl
+        if cfg.attention_implementation == "pallas_flash":
+            import warnings
+
+            warnings.warn(
+                "latent attention is assembling q, k and v with XLA's slices and concatenations: head widths "
+                f"nope {cfg.qk_nope_head_dim}, rope {cfg.qk_rope_head_dim}, value {cfg.v_head_dim} (the in-place "
+                "pass needs nope + rope a multiple of 128, an even rope of at most 128 and value == nope + rope)",
+                stacklevel=2,
+            )
+        return "xla"
 
     def _core(self, query, key, value, attention_mask, segment_ids):
         """Causal, segment-masked softmax attention over (B, S, H, d)."""

@@ -10,7 +10,7 @@ import pytest
 
 from eventstreamgpt_tpu.models.blocks import KindsBlock
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
-from eventstreamgpt_tpu.models.latent_attention import LatentAttention
+from eventstreamgpt_tpu.models.latent_attention import LatentAttention, split_kv_kernel
 from eventstreamgpt_tpu.models.moe import RoutedFeedForward, held_experts_output
 
 from . import layer_kinds_reference as ref
@@ -47,6 +47,85 @@ def test_latent_attention_agrees_with_the_plain_reference(packed):
     got = module.apply(params, x, mask, segment_ids)
     want = ref.latent_attention(x, params["params"], cfg, mask, segment_ids)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# Head widths the in-place assembly takes (`ops/pallas_rope_join.py`): a head of two lane tiles, the published split.
+ALIGNED = dict(num_attention_heads=2, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256, init_std=0.2)
+
+
+def latent_layer(monkeypatch, impl, precision, packed):
+    """One latent-attention layer at lane-aligned widths under ``impl``: its
+    parameters, output, the ``(query, key, value)`` its core was handed, and
+    the gradients of ``x`` and of the parameters."""
+    cfg = kinds_config(**ALIGNED, precision=precision)
+    x, mask, segment_ids = inputs()
+    x, segment_ids = x.astype(cfg.compute_dtype), segment_ids if packed else None
+    seen = []
+    core = LatentAttention._core
+    module = LatentAttention(cfg)
+    with monkeypatch.context() as patch:
+        patch.setenv("ESGPT_PALLAS_IMPL", impl)
+        patch.setattr(LatentAttention, "_core", lambda self, *a: seen.append(a[:3]) or core(self, *a))
+        params = module.init(jax.random.PRNGKey(1), x, mask, segment_ids)
+        out, qkv = module.apply(params, x, mask, segment_ids), seen[-1]
+        weigh = jax.random.normal(jax.random.PRNGKey(2), out.shape)
+        loss = lambda p, x: jnp.sum(module.apply(p, x, mask, segment_ids).astype(jnp.float32) * weigh)  # noqa: E731
+        grads = jax.grad(loss, argnums=(0, 1))(params, x)
+    return params, out, qkv, grads
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_assembled_latent_attention_agrees_with_the_present_formulation(monkeypatch, precision, packed):
+    """q, k and v assembled in the flash op's layout (the key/value split on
+    the weights, RoPE and the join as one Pallas pass, interpreted here)
+    against the slices, `rotate` and concatenations: the same parameter tree
+    from the same key; ``query``, ``key`` and ``value`` to one ulp of the
+    compute dtype; the output and every gradient within this file's
+    tolerances in float32, within two ulps in bfloat16."""
+    p_new, out_new, qkv_new, grads_new = latent_layer(monkeypatch, "pallas_interpret", precision, packed)
+    p_old, out_old, qkv_old, grads_old = latent_layer(monkeypatch, "xla", precision, packed)
+    assert jax.tree_util.tree_structure(p_new) == jax.tree_util.tree_structure(p_old)
+    assert len(jax.tree_util.tree_leaves(p_new)) == 7
+    for a, b in zip(jax.tree_util.tree_leaves(p_new), jax.tree_util.tree_leaves(p_old)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    ulp = float(jnp.finfo(qkv_new[0].dtype).eps)
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    for name, a, b in zip(("query", "key", "value"), qkv_new, qkv_old):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(f32(a), f32(b), rtol=ulp, atol=1e-6, err_msg=name)
+    # float32: this file's tolerances. bfloat16: the output to an ulp (it reads equal), a gradient to two
+    # (the pass sums dkey's rope lanes over the heads in float32, the present formulation rounds the sum).
+    out_tol, grad_tol = (2e-5, 1e-3) if precision == "fp32" else (ulp, 2 * ulp)
+    np.testing.assert_allclose(f32(out_new), f32(out_old), rtol=out_tol, atol=out_tol)
+    flat_new, flat_old = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (grads_new, grads_old))
+    assert len(flat_new) == 8  # the seven leaves and x
+    for (path, g), (_, w) in zip(flat_new, flat_old):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        scale = float(np.abs(f32(w)).max())
+        assert scale > 0, path
+        np.testing.assert_allclose(f32(g), f32(w), rtol=grad_tol, atol=grad_tol * scale, err_msg=str(path))
+
+
+def test_the_split_kernels_gradient_lands_in_the_one_leaf():
+    """`split_kv_kernel`: the value kernel is a head's value columns, the key
+    kernel its nope columns with zeros where `rope_join` writes; every element
+    of the leaf takes its gradient from exactly one place and what the padding
+    columns are handed goes nowhere."""
+    heads, nope, rope, v = 3, 5, 3, 8
+    leaf = jax.random.normal(jax.random.PRNGKey(0), (4, heads * (nope + v)))
+    (key, value), vjp = jax.vjp(lambda w: split_kv_kernel(w, heads, nope, rope), leaf)
+    per_head = np.asarray(leaf).reshape(4, heads, nope + v)
+    key = np.asarray(key).reshape(4, heads, nope + rope)
+    np.testing.assert_array_equal(key[..., :nope], per_head[..., :nope])
+    np.testing.assert_array_equal(key[..., nope:], 0.0)
+    np.testing.assert_array_equal(np.asarray(value).reshape(4, heads, v), per_head[..., nope:])
+    d_key = np.full((4, heads, nope + rope), 2.0, np.float32)
+    d_key[..., nope:] = np.nan  # the padding's cotangent is dropped, not multiplied by zero
+    (d_leaf,) = vjp((jnp.asarray(d_key.reshape(4, -1)), jnp.full((4, heads * v), 3.0)))
+    assert d_leaf.shape == leaf.shape
+    want = np.concatenate([np.full((4, heads, nope), 2.0), np.full((4, heads, v), 3.0)], axis=-1)
+    np.testing.assert_array_equal(np.asarray(d_leaf).reshape(4, heads, nope + v), want)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])

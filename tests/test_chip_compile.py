@@ -308,3 +308,109 @@ def test_held_experts_at_the_cells_shapes(one_chip, monkeypatch):
 
     text = _compile(grad, *args)
     assert " while(" in text  # the buffer is walked as far as the held pairs reach
+
+
+# `glm47flash_ep8.pretrain_packed`'s latent attention: 16 rows of 1,024 events, 20 heads of 192 + 64 (value 256)
+MLA_SHAPE = dict(B=16, S=1024, H=20, nope=192, rope=64)
+
+
+def test_rope_join_and_its_transpose_at_the_cells_shapes(one_chip):
+    """`ops.pallas_rope_join.rope_join` (latent attention's RoPE and
+    nope/rope join, in place on ``[16, 1024, 20 * 256]`` bf16) and its
+    transpose compile for the chip, and both Mosaic calls carry
+    ``es.attn_latent``: JAX traces a custom_vjp's rules without the caller's
+    name stack, so a rule that did not name its scope would leave
+    ``scoped_pct.train`` short of the pass's time."""
+    import re
+
+    from benchmark.harness.scopes import scope_of
+    from eventstreamgpt_tpu.ops.pallas_rope_join import rope_join
+
+    B, S, H, nope, rope = MLA_SHAPE.values()
+    wide = jax.ShapeDtypeStruct((B, S, H * (nope + rope)), jnp.bfloat16, sharding=one_chip)
+    k_r = jax.ShapeDtypeStruct((B, S, rope), jnp.bfloat16, sharding=one_chip)
+    positions = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+
+    def grad(q, k, r, p):
+        def loss(q, k, r):
+            with jax.named_scope("layer"):
+                query, key = rope_join(q * 2, k * 2, r, p, heads=H, rope=rope, theta=1e6)
+            return (query.astype(jnp.float32) * key.astype(jnp.float32)).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, r)
+
+    text = _compile(grad, wide, wide, k_r, positions)
+    calls = dict(re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text))
+    assert sorted(n.rstrip(".0123456789") for n in calls) == ["rope_join", "rope_join_transpose"]
+    assert {scope_of(path) for path in calls.values()} == {("attn_latent", "forward"), ("attn_latent", "backward")}
+    # in place: each call's big outputs are its big operands
+    assert text.count("output_to_operand_aliasing") == 2
+
+
+def _latent_layer(one_chip, monkeypatch, **widths):
+    """One `LatentAttention` layer of `benchmark/configs/glm47flash_ep8.json`
+    as the chip's backend traces it (the module asks `jax.default_backend`),
+    with shapes for its forward + gradient at the cell's rows."""
+    import json
+    from pathlib import Path
+
+    from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
+    from eventstreamgpt_tpu.models.latent_attention import LatentAttention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ESGPT_PALLAS_IMPL", raising=False)
+    cell = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / "glm47flash_ep8.json").read_text())
+    module = LatentAttention(StructuredTransformerConfig(**{**cell["config"], **widths}))
+    B, S = MLA_SHAPE["B"], MLA_SHAPE["S"]
+    x = jax.ShapeDtypeStruct((B, S, module.config.hidden_size), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), None, jnp.zeros(seg.shape, seg.dtype)))
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+
+    def grad(p, x_, seg_):
+        return jax.grad(lambda p, x_: module.apply(p, x_, None, seg_).astype(jnp.float32).sum(), argnums=(0, 1))(p, x_)
+
+    return grad, (params, x, seg)
+
+
+def test_latent_attention_layer_holds_no_relayout_between_its_products_and_the_flash_kernels(one_chip, monkeypatch):
+    """The cell's shapes engage the assembly, and then nothing of q, k, v or
+    their gradients (168 MB each) is sliced, concatenated or re-laid by XLA
+    between the four latent products and the three flash kernels: the entry
+    computation holds no ``copy``, ``slice``, ``pad_*`` or ``select_*`` over
+    100 MB (with XLA's own assembly it holds 7 copies and 4 slices). One is
+    left that is not latent attention's: the flash op's ``di`` (`ops/
+    pallas_flash.py::_backward`), whose float32 ``o * do`` XLA re-lays
+    events-minor before it reduces it."""
+    import re
+
+    import numpy as np
+
+    grad, args = _latent_layer(one_chip, monkeypatch)
+    text = _compile(grad, *args)
+    assert "rope_join" in text and "rope_join_transpose" in text
+    entry = text[text.index("ENTRY") :]
+    itemsize = {"bf16": 2, "f32": 4, "s32": 4}
+    moved = []
+    for name, dtype, dims, op_name in re.findall(
+        r'%((?:copy|slice|split|pad_|select_)[\w.\-]*) = (\w+)\[([\d,]+)\][^\n]*?op_name="([^"]*)"', entry
+    ):
+        if np.prod([int(n) for n in dims.split(",")]) * itemsize.get(dtype, 4) > 100e6:
+            moved.append((name, f"{dtype}[{dims}]", op_name))
+    flash_di = [m for m in moved if "es.attn_global/jit(_backward)/mul" in m[2]]
+    assert [m for m in moved if m not in flash_di] == [], moved
+    assert len(flash_di) <= 1
+
+
+def test_latent_attention_warns_once_where_the_widths_are_not_lane_aligned(one_chip, monkeypatch):
+    """Heads of 96 + 32 are one lane tile, but a value of 64 is not a key's
+    width: asked for ``pallas_flash`` on a TPU, the layer assembles q, k and v
+    with XLA and says so, once a trace."""
+    import warnings
+
+    grad, args = _latent_layer(one_chip, monkeypatch, qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=64)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        jax.eval_shape(grad, *args)
+    assembly = [w for w in caught if "assembling q, k and v with XLA" in str(w.message)]
+    assert len(assembly) == 1, [str(w.message) for w in caught]
