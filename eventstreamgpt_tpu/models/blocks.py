@@ -1,12 +1,14 @@
 """The kinds block: a layer that names its mixer and its feed-forward.
 
-``h <- h + mixer(norm(h))``, ``h <- h + ffn(norm(h))`` with the mixer and the
-feed-forward of layer ``layer_id`` read from the configuration
-(``mixer_layers``, ``ffn_layers``; docs/layer_kinds.md). Each kind is a small
-module of its own: latent attention (`models/latent_attention.py`), the gated
-and the routed feed-forward (`models/moe.py`). The classic block
-(`InnerBlock`: LayerNorm, multi-head attention, GELU MLP, every cache branch)
-is not migrated here; the encoder builds one stack or the other.
+``h <- h + part(norm(h))`` once or twice a layer: the mixer of layer
+``layer_id`` first, where it names one, then its feed-forward, where it names
+one, each behind a norm of its own (``mixer_layers``, ``ffn_layers``;
+docs/layer_kinds.md). Each kind is a small module: latent attention
+(`models/latent_attention.py`), the Mamba-2 mixer (`models/state_space.py`),
+grouped-query attention (here), the gated and the routed feed-forward
+(`models/moe.py`). The classic block (`InnerBlock`: LayerNorm, multi-head
+attention, GELU MLP, every cache branch) is not migrated here; the encoder
+builds one stack or the other.
 
 The call signature is `InnerBlock`'s, so that the encoder's loop and
 `remat_block_cls` serve both. There is no decode state yet: a call that asks
@@ -15,13 +17,53 @@ for a cache raises.
 
 from __future__ import annotations
 
+import functools
+
 import flax.linen as nn
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
-from .latent_attention import LatentAttention, RMSNorm
+from .latent_attention import LatentAttention, RMSNorm, bias_free_dense, causal_core
 from .moe import RoutedFeedForward, SwiGLU
-from .transformer import NO_DECODE_STATE
+from .state_space import Mamba2Mixer
+from .transformer import ATTENTION_CHECKPOINT_NAME, NO_DECODE_STATE
+
+
+class GroupedQueryAttention(nn.Module):
+    """``softmax(q k^T / sqrt(d)) v`` with ``num_attention_heads`` query heads
+    of ``head_dim`` over ``num_key_value_heads`` key/value heads (query head
+    ``i`` reads key/value head ``i // (heads / kv heads)``), causal inside the
+    packed segment, no bias and no rotary embedding (``nemotron_h`` applies
+    none). The core is `latent_attention.causal_core`; the key/value heads are
+    repeated to the query heads before it (the flash op takes one head count)."""
+
+    config: StructuredTransformerConfig
+
+    @nn.compact
+    def __call__(self, x, attention_mask=None, segment_ids=None):
+        cfg = self.config
+        heads, d = cfg.num_attention_heads, cfg.head_dim
+        kv_heads = cfg.num_key_value_heads or heads
+        batch, seq_len = x.shape[:2]
+
+        dense = functools.partial(bias_free_dense, cfg)
+        with scope("attn_proj"):
+            query = dense(heads * d, "q_proj")(x).reshape(batch, seq_len, heads, d)
+            key = dense(kv_heads * d, "k_proj")(x).reshape(batch, seq_len, kv_heads, d)
+            value = dense(kv_heads * d, "v_proj")(x).reshape(batch, seq_len, kv_heads, d)
+        with scope("attn_global"):
+            key, value = (jnp.repeat(a, heads // kv_heads, axis=2) for a in (key, value))
+            out = causal_core(cfg, query, key, value, attention_mask, segment_ids, "grouped-query attention")
+            out = checkpoint_name(out, ATTENTION_CHECKPOINT_NAME)
+        with scope("attn_proj"):
+            return dense(cfg.hidden_size, "o_proj")(out.reshape(batch, seq_len, heads * d))
+
+
+# A mixer kind's module and the name of its parameters in the layer's tree.
+MIXERS = {"latent": (LatentAttention, "self_attn"), "mha": (GroupedQueryAttention, "self_attn"), "ssm": (Mamba2Mixer, "mixer")}
+
 
 class KindsBlock(nn.Module):
     config: StructuredTransformerConfig
@@ -44,19 +86,23 @@ class KindsBlock(nn.Module):
             raise NotImplementedError(NO_DECODE_STATE)
         if output_attentions or static_kv_first or not self.is_seq:
             raise NotImplementedError("the kinds block gives no attention weights and serves sequence layers only")
+        mixer, ffn = cfg.mixer_layers[self.layer_id], cfg.ffn_layers[self.layer_id]
 
         def norm(name, x):
             with scope("norm"):
                 return RMSNorm(cfg.layer_norm_epsilon, cfg.compute_dtype, name=name)(x)
 
-        mixed = LatentAttention(cfg, name="self_attn")(
-            norm("input_layernorm", hidden_states), attention_mask, segment_ids
-        )
-        hidden_states = hidden_states + mixed
-        normed = norm("post_attention_layernorm", hidden_states)
-        if cfg.ffn_layers[self.layer_id] == "routed":
-            fed = RoutedFeedForward(cfg, name="mlp")(normed, attention_mask)
-        else:
-            with scope("mlp"):
-                fed = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(normed)
-        return hidden_states + fed, {}
+        if mixer != "none":
+            module, name = MIXERS[mixer]
+            mixed = module(cfg, name=name)(norm("input_layernorm", hidden_states), attention_mask, segment_ids)
+            hidden_states = hidden_states + mixed
+        if ffn != "none":
+            # One norm a part: a layer that is a feed-forward alone has the layer's one norm.
+            normed = norm("post_attention_layernorm" if mixer != "none" else "input_layernorm", hidden_states)
+            if ffn == "routed":
+                fed = RoutedFeedForward(cfg, name="mlp")(normed, attention_mask)
+            else:
+                with scope("mlp"):
+                    fed = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(normed)
+            hidden_states = hidden_states + fed
+        return hidden_states, {}

@@ -238,6 +238,15 @@ class StructuredTransformerConfig(JSONableMixin):
         num_experts_per_tok: int | None = None,
         routed_scaling_factor: float = 1.0,
         norm_topk_prob: bool = True,
+        moe_expert_form: str = "swiglu",
+        moe_shared_expert_intermediate_size: int | None = None,
+        num_key_value_heads: int | None = None,
+        mamba_num_heads: int | None = None,
+        mamba_head_dim: int | None = None,
+        mamba_n_groups: int | None = None,
+        ssm_state_size: int | None = None,
+        mamba_conv_kernel: int = 4,
+        mamba_chunk_size: int = 128,
         # Model output configuration
         TTE_generation_layer_type: str = TimeToEventGenerationHeadType.EXPONENTIAL,
         TTE_lognormal_generation_num_components: int | None = None,
@@ -424,7 +433,9 @@ class StructuredTransformerConfig(JSONableMixin):
             hidden_size = head_dim * num_attention_heads
         elif head_dim is None:
             head_dim = hidden_size // num_attention_heads
-        if not latent and head_dim * num_attention_heads != hidden_size:
+        # (The kinds block's attention projects to `head_dim x heads` of its own, as its
+        # configuration publishes them: 128 x 32 over a hidden size of 2,688.)
+        if not latent and norm_type != "rms_norm" and head_dim * num_attention_heads != hidden_size:
             raise ValueError(
                 f"hidden_size must be divisible by num_attention_heads (got `hidden_size`: {hidden_size} "
                 f"and `num_attention_heads`: {num_attention_heads})."
@@ -475,6 +486,14 @@ class StructuredTransformerConfig(JSONableMixin):
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
         self.rope_theta = rope_theta
+        self.num_key_value_heads = num_key_value_heads
+        self.mamba_num_heads = mamba_num_heads
+        self.mamba_head_dim = mamba_head_dim
+        self.mamba_n_groups = mamba_n_groups
+        self.ssm_state_size = ssm_state_size
+        self.mamba_conv_kernel = mamba_conv_kernel
+        self.mamba_chunk_size = mamba_chunk_size
+        self.num_attention_heads = num_attention_heads
         self._set_layer_kinds(mixer_types, ffn_types, norm_type)
         self.moe_intermediate_size = moe_intermediate_size
         self.moe_router_width = moe_router_width
@@ -484,6 +503,10 @@ class StructuredTransformerConfig(JSONableMixin):
         self.num_experts_per_tok = num_experts_per_tok
         self.routed_scaling_factor = routed_scaling_factor
         self.norm_topk_prob = norm_topk_prob
+        self.moe_expert_form = moe_expert_form
+        self.moe_shared_expert_intermediate_size = moe_shared_expert_intermediate_size
+        if moe_expert_form not in self.EXPERT_FORMS:
+            raise ValueError(f"moe_expert_form must be of {self.EXPERT_FORMS}; got {moe_expert_form}")
         if "routed" in self.ffn_layers:
             if None in (moe_intermediate_size, moe_router_width, n_routed_experts, num_experts_per_tok):
                 raise ValueError(
@@ -651,7 +674,6 @@ class StructuredTransformerConfig(JSONableMixin):
 
         self.head_dim = head_dim
         self.hidden_size = hidden_size
-        self.num_attention_heads = num_attention_heads
         self.attention_dropout = attention_dropout
         self.input_dropout = input_dropout
         self.resid_dropout = resid_dropout
@@ -689,15 +711,19 @@ class StructuredTransformerConfig(JSONableMixin):
 
         return jnp.bfloat16 if self.precision == "bf16" else jnp.float32
 
-    MIXER_KINDS = ("mha", "latent")
-    FFN_KINDS = ("mlp", "swiglu", "routed")
+    MIXER_KINDS = ("mha", "latent", "ssm", "none")
+    FFN_KINDS = ("mlp", "swiglu", "routed", "none")
     NORM_KINDS = ("layer_norm", "rms_norm")
+    EXPERT_FORMS = ("swiglu", "relu2")
 
     def _set_layer_kinds(self, mixer_types, ffn_types, norm_type) -> None:
         """Each layer's mixer and feed-forward kind, in the attention types'
-        mini-language. The classic block (`InnerBlock`) is every default; the
-        kinds block (`models/blocks.py`) serves ``latent`` under ``rms_norm``
-        with ``swiglu`` or ``routed``, and nothing in between yet."""
+        mini-language. The classic block (`InnerBlock`) is every default. Under
+        ``rms_norm`` the stack is built of the kinds block (`models/blocks.py`):
+        a layer names a mixer (``latent``, ``ssm``, ``mha`` with grouped
+        key/value heads, or ``none``) and a feed-forward (``swiglu``,
+        ``routed`` or ``none``), at least one of the two, and each mixer kind
+        in use has its sizes (docs/layer_kinds.md)."""
         self.mixer_types, self.ffn_types, self.norm_type = mixer_types, ffn_types, norm_type
         self.mixer_layers = self.expand_attention_types_params(mixer_types)
         self.ffn_layers = self.expand_attention_types_params(ffn_types)
@@ -711,16 +737,43 @@ class StructuredTransformerConfig(JSONableMixin):
                 raise ValueError(f"{name} must be of {known}; got {layers}")
         if norm_type not in self.NORM_KINDS:
             raise ValueError(f"norm_type must be of {self.NORM_KINDS}; got {norm_type}")
-        classic = set(self.mixer_layers) == {"mha"} and set(self.ffn_layers) == {"mlp"} and norm_type == "layer_norm"
-        kinds = set(self.mixer_layers) == {"latent"} and "mlp" not in self.ffn_layers and norm_type == "rms_norm"
-        if kinds != (self.qk_nope_head_dim is not None) or not (classic or kinds):
+        mixers, ffns = set(self.mixer_layers), set(self.ffn_layers)
+        classic = mixers == {"mha"} and ffns == {"mlp"} and norm_type == "layer_norm"
+        kinds = norm_type == "rms_norm" and "mlp" not in ffns
+        if ("latent" in mixers) != (self.qk_nope_head_dim is not None) or not (classic or kinds):
             raise ValueError(
-                "layer kinds: either every default (mha, mlp, layer_norm) or latent mixers with "
-                "swiglu/routed feed-forwards under rms_norm and the latent ranks and head dims; got "
-                f"{mixer_types}, {ffn_types}, {norm_type}, qk_nope_head_dim {self.qk_nope_head_dim}"
+                "layer kinds: either every default (mha, mlp, layer_norm) or, under rms_norm, mixers of "
+                "latent (with the latent ranks and head dims) / ssm / mha / none and feed-forwards of "
+                f"swiglu / routed / none; got {mixer_types}, {ffn_types}, {norm_type}, "
+                f"qk_nope_head_dim {self.qk_nope_head_dim}"
             )
-        if kinds and self.structured_event_processing_mode != StructuredEventProcessingMode.CONDITIONALLY_INDEPENDENT:
+        if not kinds:
+            if self.num_key_value_heads not in (None, self.num_attention_heads):
+                raise ValueError("the classic block has as many key/value heads as query heads")
+            return
+        if self.structured_event_processing_mode != StructuredEventProcessingMode.CONDITIONALLY_INDEPENDENT:
             raise ValueError("the kinds block serves the conditionally-independent model only")
+        bare = [i for i, parts in enumerate(zip(self.mixer_layers, self.ffn_layers)) if set(parts) == {"none"}]
+        if bare:
+            raise ValueError(f"layer kinds: layers {bare} have neither a mixer nor a feed-forward")
+        if "mha" in mixers:
+            kv = self.num_key_value_heads or self.num_attention_heads
+            if kv <= 0 or self.num_attention_heads % kv:
+                raise ValueError(
+                    f"num_attention_heads {self.num_attention_heads} is not a multiple of num_key_value_heads {kv}"
+                )
+        if "ssm" in mixers:
+            sizes = (self.mamba_num_heads, self.mamba_head_dim, self.mamba_n_groups, self.ssm_state_size)
+            if None in sizes:
+                raise ValueError(
+                    "mixer 'ssm' needs mamba_num_heads, mamba_head_dim, mamba_n_groups and ssm_state_size"
+                )
+            if self.mamba_num_heads % self.mamba_n_groups:
+                raise ValueError(
+                    f"mamba_num_heads {self.mamba_num_heads} is not a multiple of mamba_n_groups {self.mamba_n_groups}"
+                )
+            if self.mamba_conv_kernel < 1 or self.mamba_chunk_size < 1:
+                raise ValueError("mamba_conv_kernel and mamba_chunk_size are at least 1")
 
     @property
     def uses_layer_kinds(self) -> bool:

@@ -39,6 +39,8 @@ parameter tree (``q_a_proj``, ``q_a_layernorm``, ``q_b_proj``,
 
 from __future__ import annotations
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,16 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
         return (scale * y).astype(self.dtype)
+
+
+def bias_free_dense(cfg, features: int, name: str) -> nn.Dense:
+    """The kinds block's one kind of product: no bias, normal with the
+    configuration's ``init_std``, in its compute dtype. Called inside a
+    module's ``@nn.compact`` method, where the layer becomes its child."""
+    return nn.Dense(
+        features, use_bias=False, kernel_init=nn.initializers.normal(stddev=cfg.init_std),
+        dtype=cfg.compute_dtype, name=name,
+    )
 
 
 def segment_positions(segment_ids, batch_size: int, seq_len: int):
@@ -132,12 +144,7 @@ class LatentAttention(nn.Module):
         H, dn, dr, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         B, S = x.shape[:2]
 
-        def dense(features, name):
-            return nn.Dense(
-                features, use_bias=False, kernel_init=nn.initializers.normal(stddev=cfg.init_std),
-                dtype=dt, name=name,
-            )
-
+        dense = functools.partial(bias_free_dense, cfg)
         impl = self._assembly_impl()
         with scope("attn_latent"):
             c_q = RMSNorm(cfg.layer_norm_epsilon, dt, name="q_a_layernorm")(dense(cfg.q_lora_rank, "q_a_proj")(x))
@@ -203,39 +210,49 @@ class LatentAttention(nn.Module):
         return "xla"
 
     def _core(self, query, key, value, attention_mask, segment_ids):
-        """Causal, segment-masked softmax attention over (B, S, H, d)."""
-        cfg = self.config
-        B, S, H, d = query.shape
-        scale = d**-0.5
-        want_kernel = cfg.attention_implementation == "pallas_flash"
-        if want_kernel and jax.default_backend() == "tpu" and S % 128 == 0 and value.shape[-1] == d:
-            from ..ops.pallas_flash import flash_attention
-            from ..parallel.context import per_batch_shard
+        return causal_core(self.config, query, key, value, attention_mask, segment_ids, "latent attention")
 
-            # Padding rides as its own segment, as in the classic layers; the
-            # op reads (B, S, H, d) as the projections leave it.
-            seg = segment_ids if segment_ids is not None else jnp.zeros((B, S), jnp.int32)
-            if attention_mask is not None:
-                seg = jnp.where(attention_mask, seg.astype(jnp.int32), -1)
-            return per_batch_shard(
-                lambda q, k, v, s: flash_attention(q, k, v, s, sm_scale=scale), query, key, value, seg
-            ).astype(value.dtype)
-        if want_kernel:
-            import warnings
 
-            warnings.warn(
-                "attention_implementation='pallas_flash' is taking the einsum path in latent "
-                f"attention: backend={jax.default_backend()!r}, S={S}, head widths {d} and "
-                f"{value.shape[-1]} (the flash kernel needs a TPU, S % 128 == 0 and equal widths)",
-                stacklevel=2,
-            )
-        logits = jnp.einsum("bqhd,bkhd->bhqk", query, key, preferred_element_type=jnp.float32) * scale
-        pos = jnp.arange(S)
-        mask = (pos[None, :] <= pos[:, None])[None, None]
-        if segment_ids is not None:
-            mask = mask & (segment_ids[:, None, :, None] == segment_ids[:, None, None, :])
+def causal_core(cfg, query, key, value, attention_mask, segment_ids, who: str):
+    """Causal, segment-masked softmax attention over (B, S, H, d), scaled by
+    ``d ** -0.5``: the repo's flash op where the configuration asks for it and
+    a kernel is taken (a TPU, or the interpreter where
+    ``$ESGPT_PALLAS_IMPL=pallas_interpret``), the einsum elsewhere."""
+    from ..ops.impl_select import resolve_impl
+
+    B, S, H, d = query.shape
+    scale = d**-0.5
+    want_kernel = cfg.attention_implementation == "pallas_flash"
+    impl = resolve_impl(None, "flash attention") if want_kernel else "xla"
+    if impl != "xla" and S % 128 == 0 and d % 128 == 0 and value.shape[-1] == d:
+        from ..ops.pallas_flash import flash_attention
+        from ..parallel.context import per_batch_shard
+
+        # Padding rides as its own segment, as in the classic layers; the
+        # op reads (B, S, H, d) as the projections leave it.
+        seg = segment_ids if segment_ids is not None else jnp.zeros((B, S), jnp.int32)
         if attention_mask is not None:
-            mask = mask & attention_mask[:, None, None, :]
-        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(logits, axis=-1).astype(value.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, value)
+            seg = jnp.where(attention_mask, seg.astype(jnp.int32), -1)
+        return per_batch_shard(
+            lambda q, k, v, s: flash_attention(q, k, v, s, sm_scale=scale, interpret=impl == "pallas_interpret"),
+            query, key, value, seg,
+        ).astype(value.dtype)
+    if want_kernel:
+        import warnings
+
+        warnings.warn(
+            f"attention_implementation='pallas_flash' is taking the einsum path in {who}: "
+            f"backend={jax.default_backend()!r}, S={S}, head widths {d} and {value.shape[-1]} "
+            "(the flash kernel needs a TPU, S % 128 == 0 and equal widths of whole 128-lane tiles)",
+            stacklevel=3,
+        )
+    logits = jnp.einsum("bqhd,bkhd->bhqk", query, key, preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(S)
+    mask = (pos[None, :] <= pos[:, None])[None, None]
+    if segment_ids is not None:
+        mask = mask & (segment_ids[:, None, :, None] == segment_ids[:, None, None, :])
+    if attention_mask is not None:
+        mask = mask & attention_mask[:, None, None, :]
+    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(value.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, value)

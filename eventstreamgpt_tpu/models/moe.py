@@ -1,7 +1,9 @@
 """Gated and routed feed-forward layers of the kinds block (`models/blocks.py`).
 
-`SwiGLU` is ``(silu(x W_g) * x W_u) W_d`` with no bias. `RoutedFeedForward`
-is GLM-4.7-Flash's sparse layer (``glm4_moe_lite``, ``noaux_tc`` routing)::
+`SwiGLU` is ``(silu(x W_g) * x W_u) W_d`` and `Relu2` is ``relu(x W_u)^2 W_d``,
+both with no bias. `RoutedFeedForward` is the sparse layer of GLM-4.7-Flash
+(``glm4_moe_lite``) and of ``nemotron_h``, ``noaux_tc`` routing, with experts
+and a shared expert of either form (``config.moe_expert_form``)::
 
     s = sigmoid(x W_r)                        float32, over the router's whole width
     T = top_k(s + b)                          b: the selection bias; it chooses and does not weigh
@@ -43,6 +45,7 @@ from ..ops.impl_select import resolve_impl
 from ..parallel.context import per_batch_shard
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
+from .latent_attention import bias_free_dense
 
 ROUTING_COLLECTION = "routing"
 
@@ -53,15 +56,25 @@ class SwiGLU(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.config
+        dense = functools.partial(bias_free_dense, self.config)
+        return dense(self.config.hidden_size, "down_proj")(nn.silu(dense(self.inner, "gate_proj")(x)) * dense(self.inner, "up_proj")(x))
 
-        def dense(features, name):
-            return nn.Dense(
-                features, use_bias=False, kernel_init=nn.initializers.normal(stddev=cfg.init_std),
-                dtype=cfg.compute_dtype, name=name,
-            )
 
-        return dense(cfg.hidden_size, "down_proj")(nn.silu(dense(self.inner, "gate_proj")(x)) * dense(self.inner, "up_proj")(x))
+class Relu2(nn.Module):
+    """The ungated feed-forward ``relu(x W_u)^2 W_d``."""
+
+    config: StructuredTransformerConfig
+    inner: int
+
+    @nn.compact
+    def __call__(self, x):
+        dense = functools.partial(bias_free_dense, self.config)
+        return dense(self.config.hidden_size, "down_proj")(jnp.square(nn.relu(dense(self.inner, "up_proj")(x))))
+
+
+# An expert form's module, and the names of its stacks of matrices in the order
+# `_chunk_output` takes them: the last is the product back to the hidden size.
+EXPERT_FORMS = {"swiglu": (SwiGLU, ("gate_proj", "up_proj", "down_proj")), "relu2": (Relu2, ("up_proj", "down_proj"))}
 
 
 def route(scores, bias, top_k: int, scaling: float, normalize: bool):
@@ -74,12 +87,17 @@ def route(scores, bias, top_k: int, scaling: float, normalize: bool):
     return chosen, scaling * chosen_scores
 
 
-def _chunk_output(rows, chunk_rows, chunk_weights, group_sizes, w_gate, w_up, w_down, impl):
-    """What one chunk of ordered pairs adds to every row's output (float32)."""
+def _chunk_output(rows, chunk_rows, chunk_weights, group_sizes, stacks, impl):
+    """What one chunk of ordered pairs adds to every row's output (float32).
+    ``stacks``: the held experts' matrices, three stacks for the gated form
+    (gate, up, down: ``silu(a) * b``), two for the ungated (up, down:
+    ``relu(a)^2``)."""
     with scope("moe_dispatch"):
         x = rows[chunk_rows]
     with scope("moe_experts"):
-        hidden = nn.silu(grouped_matmul(x, w_gate, group_sizes, impl)) * grouped_matmul(x, w_up, group_sizes, impl)
+        *w_in, w_down = stacks
+        into = [grouped_matmul(x, w, group_sizes, impl) for w in w_in]
+        hidden = nn.silu(into[0]) * into[1] if len(into) == 2 else jnp.square(nn.relu(into[0]))
         y = grouped_matmul(hidden, w_down, group_sizes, impl)
     with scope("moe_dispatch"):
         # A pair past the held ones has a zero row of `y`, whatever its weight.
@@ -87,15 +105,15 @@ def _chunk_output(rows, chunk_rows, chunk_weights, group_sizes, w_gate, w_up, w_
         return jnp.zeros(rows.shape, jnp.float32).at[chunk_rows].add(y)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _walk_chunks(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w_up, w_down, impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _walk_chunks(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, stacks, impl):
     """The sum of `_chunk_output` over the first ``n_chunks`` chunks (a traced
     count: a loop whose trip count follows the pairs really held). The
     backward walks the same chunks and computes each one's forward again, so
     nothing the size of the buffer is kept or zero-filled."""
 
     def body(c, out):
-        chunk = _chunk_output(rows, pair_rows[c], pair_weights[c], chunk_sizes[c], w_gate, w_up, w_down, impl)
+        chunk = _chunk_output(rows, pair_rows[c], pair_weights[c], chunk_sizes[c], stacks, impl)
         with scope("moe_dispatch"):
             return out + chunk
 
@@ -103,44 +121,45 @@ def _walk_chunks(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w
         return jax.lax.fori_loop(0, n_chunks, body, jnp.zeros(rows.shape, jnp.float32))
 
 
-def _walk_chunks_fwd(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w_up, w_down, impl):
-    out = _walk_chunks(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w_up, w_down, impl)
-    return out, (rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w_up, w_down)
+def _walk_chunks_fwd(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, stacks, impl):
+    out = _walk_chunks(rows, pair_rows, pair_weights, chunk_sizes, n_chunks, stacks, impl)
+    return out, (rows, pair_rows, pair_weights, chunk_sizes, n_chunks, stacks)
 
 
 def _walk_chunks_bwd(impl, residuals, g):
-    rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w_up, w_down = residuals
+    rows, pair_rows, pair_weights, chunk_sizes, n_chunks, stacks = residuals
 
     def body(c, carry):
-        d_rows, d_pair_weights, d_gate, d_up, d_down = carry
+        d_rows, d_pair_weights, d_stacks = carry
         _, pull = jax.vjp(
-            lambda r, pw, a, b, d: _chunk_output(r, pair_rows[c], pw, chunk_sizes[c], a, b, d, impl),
-            rows, pair_weights[c], w_gate, w_up, w_down,
+            lambda r, pw, w: _chunk_output(r, pair_rows[c], pw, chunk_sizes[c], w, impl),
+            rows, pair_weights[c], stacks,
         )
-        r, pw, a, b, d = pull(g)
+        r, pw, w = pull(g)
         with scope("moe_dispatch"):
             d_rows, d_pair_weights = d_rows + r, d_pair_weights.at[c].set(pw)
         with scope("moe_experts"):
-            return d_rows, d_pair_weights, d_gate + a, d_up + b, d_down + d
+            return d_rows, d_pair_weights, tuple(acc + d for acc, d in zip(d_stacks, w))
 
     # (A custom VJP's rules are traced without the caller's name stack: the
     # scopes are set here again.)
     with scope("moe_dispatch"):
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, (rows, pair_weights, w_gate, w_up, w_down))
-        d_rows, d_pair_weights, d_gate, d_up, d_down = jax.lax.fori_loop(0, n_chunks, body, zeros)
-    return d_rows, None, d_pair_weights, None, None, d_gate, d_up, d_down
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, (rows, pair_weights, stacks))
+        d_rows, d_pair_weights, d_stacks = jax.lax.fori_loop(0, n_chunks, body, zeros)
+    return d_rows, None, d_pair_weights, None, None, d_stacks
 
 
 _walk_chunks.defvjp(_walk_chunks_fwd, _walk_chunks_bwd)
 
 
-def held_experts_output(rows, chosen, weights, w_gate, w_up, w_down, *, offset: int, impl=None):
+def held_experts_output(rows, chosen, weights, *stacks, offset: int, impl=None):
     """``sum_{e chosen and held} w_e E_e(row)`` for every row, in float32, and
-    the two routing counters as a ``(1, 2)`` row. ``w_*`` are ``(held, ...)``
-    stacks of the held experts' matrices, expert ``offset + i`` at index
+    the two routing counters as a ``(1, 2)`` row. ``stacks`` are ``(held,
+    ...)`` stacks of the held experts' matrices in `EXPERT_FORMS`' order (three
+    for the gated form, two for the ungated), expert ``offset + i`` at index
     ``i``; a row whose ``chosen`` is negative is routed nowhere."""
     n_rows, top_k = chosen.shape
-    held = w_gate.shape[0]
+    held = stacks[0].shape[0]
     with scope("moe_dispatch"):
         # Slot 0..held-1 are the experts held here; anything above lives on
         # another chip. (The router's width only bounds the ids.)
@@ -158,7 +177,7 @@ def held_experts_output(rows, chosen, weights, w_gate, w_up, w_down, *, offset: 
         chunk_sizes = jnp.concatenate([chunk_sizes, n_rows - chunk_sizes.sum(-1, keepdims=True)], axis=-1)
         n_chunks = (n_pairs + n_rows - 1) // n_rows
     out = _walk_chunks(
-        rows, pair_rows, pair_weights, chunk_sizes, n_chunks, w_gate, w_up, w_down, resolve_impl(impl, "grouped_matmul")
+        rows, pair_rows, pair_weights, chunk_sizes, n_chunks, tuple(stacks), resolve_impl(impl, "grouped_matmul")
     )
     return out, jnp.stack([n_pairs, jnp.max(sizes)])[None]
 
@@ -186,21 +205,21 @@ class RoutedFeedForward(nn.Module):
             if row_mask is not None:
                 chosen = jnp.where(row_mask.reshape(-1, 1), chosen, -1)
 
+        shared_cls, names = EXPERT_FORMS[cfg.moe_expert_form]
         with scope("moe_experts"):
-            experts = {
-                name: self.param(f"experts_{name}", init, shape, jnp.float32).astype(dt)
-                for name, shape in (
-                    ("gate_proj", (held, hidden, inner)),
-                    ("up_proj", (held, hidden, inner)),
-                    ("down_proj", (held, inner, hidden)),
-                )
-            }
+            stacks = tuple(
+                self.param(
+                    f"experts_{name}", init, (held, inner, hidden) if name == "down_proj" else (held, hidden, inner),
+                    jnp.float32,
+                ).astype(dt)
+                for name in names
+            )
         # Each batch shard of a data-parallel mesh routes its own rows to its
         # own copy of the held experts (parallel/context.py).
         routed, counters = per_batch_shard(
             functools.partial(held_experts_output, offset=cfg.moe_expert_offset),
             rows.astype(dt), chosen, weights,
-            replicated=(experts["gate_proj"], experts["up_proj"], experts["down_proj"]),
+            replicated=stacks,
         )
         if not self.is_initializing():  # `init` gives parameters alone
             counters = jnp.stack([counters[:, 0].sum(), counters[:, 1].max()])
@@ -209,7 +228,8 @@ class RoutedFeedForward(nn.Module):
             out = routed.astype(dt).reshape(x.shape)
         if cfg.n_shared_experts:
             with scope("moe_shared"):
-                out = out + SwiGLU(cfg, cfg.n_shared_experts * inner, name="shared_experts")(x)
+                width = cfg.moe_shared_expert_intermediate_size or cfg.n_shared_experts * inner
+                out = out + shared_cls(cfg, width, name="shared_experts")(x)
         return out
 
 

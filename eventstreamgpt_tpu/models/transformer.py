@@ -209,8 +209,10 @@ def paged_kv_bytes_per_block(
 
 
 NO_DECODE_STATE = (
-    "latent attention has no decode cache yet (a latent paged cache and the absorbed decode "
-    "path are not built: ROADMAP R3): generate() and the serving engine cannot run this backbone"
+    "the kinds block has no decode cache yet (latent attention: a latent paged cache and the absorbed "
+    "decode path; a state-space mixer: its recurrent state and the convolution's last taps in a "
+    "generation slot; grouped key/value heads in the caches: ROADMAP R3, R4): generate() and the "
+    "serving engine cannot run this backbone"
 )
 
 

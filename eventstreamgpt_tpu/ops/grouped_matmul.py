@@ -27,9 +27,16 @@ from ..utils.scopes import scope
 from .impl_select import resolve_impl
 
 # (rows, contraction, columns) of one grid step: the largest listed tile that
-# divides the dimension, else the dimension itself. They compile for the v5e
-# at the expert shapes 2,048 x 1,536 and 1,536 x 2,048 and were not swept
-# (PERF.md section 7, PR 28).
+# divides the dimension, else the dimension itself. Chosen for the expert
+# shapes 2,048 x 1,536 and 1,536 x 2,048 (`glm47flash_ep8`: 1,024 / 768 and
+# 512 / 512), where they compile for the v5e and were not swept (PERF.md
+# section 7, PR 28). At 2,688 x 1,856 and 1,856 x 2,688
+# (`nemotron_twotower_ep16`, PR 32) only the 128 tile divides 2,688 (= 21 x
+# 128) and no listed tile divides 1,856 (= 14.5 x 128), so that dimension is
+# taken whole; Mosaic compiles `gmm` and `tgmm` so, and the lists are as they
+# were: what `glm47flash_ep8`'s shapes pick is pinned in
+# tests/models/test_hybrid_kinds.py, and a sweep at the new shapes is open
+# (PERF.md section 7, PR 32).
 _TILES = {"m": (512, 256, 128), "k": (1024, 512, 256, 128), "n": (768, 512, 256, 128)}
 
 

@@ -28,6 +28,10 @@ SCOPES = (
     "attn_global",  # what lies between the projections in a global layer
     "attn_local",  # ... in a local layer
     "dep_graph",  # NA's dependency-graph attention and its plumbing
+    "ssm_proj",  # a state-space (Mamba-2) mixer's input and output projections
+    "ssm_conv",  # its causal convolution inside the segment, silu, the splits
+    "ssm_scan",  # softplus, the decays, the chunked scan, the D skip
+    "ssm_gate",  # the gate and the grouped norm
     "mlp",  # the feed-forward block (the classic MLP, the dense SwiGLU)
     "moe_router",  # a routed layer's logits, sigmoid, top-k and weights
     "moe_dispatch",  # ordering the (row, expert) pairs by expert, gathering rows, combining back

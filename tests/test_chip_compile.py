@@ -414,3 +414,76 @@ def test_latent_attention_warns_once_where_the_widths_are_not_lane_aligned(one_c
         jax.eval_shape(grad, *args)
     assembly = [w for w in caught if "assembling q, k and v with XLA" in str(w.message)]
     assert len(assembly) == 1, [str(w.message) for w in caught]
+
+
+# `nemotron_twotower_ep16.pretrain_packed`'s rows: 16 of 1,024 events at hidden 2,688
+HYBRID_BLOCKS = {
+    # letter: (layer of MEMEM*EME, parameters, Mosaic calls, temporaries in GB at most, largest temporary in MB at most)
+    "M": (0, 38_744_896, 0, 2.6, 420),
+    "E": (1, 100_125_440, 6, 1.4, 420),
+    "*": (5, 23_399_040, 3, 1.4, 420),
+}
+
+
+@pytest.mark.parametrize("letter", list(HYBRID_BLOCKS))
+def test_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, letter):
+    """One Mamba-2 (`M`), one routed relu^2 (`E`) and one grouped-query
+    attention (`*`) block of `benchmark/configs/nemotron_twotower_ep16.json`
+    under its ``block`` remat, forward and gradient on ``[16, 1024, 2688]``
+    bf16, as the chip's backend traces them. `M` is XLA products alone (no
+    Mosaic call) and its scan's ``L`` (537 MB whole in float32) is never held
+    whole: the largest temporary is the float32 ``[16, 1024, 6144]`` plane of
+    the convolution's backward (403 MB; then ``in_proj``'s bf16 output, 338
+    MB), 2.25 GB of temporaries in all. `E` holds megablox' ``gmm`` twice
+    forward, ``gmm`` and ``tgmm`` twice backward at 2,688 x 1,856 (the
+    contraction 2,688 takes the 128 tile, the width 1,856 no listed tile and
+    so the whole dimension), 1.16 GB; `*` the three flash kernels at 32 heads
+    (the two key/value heads repeated before them), 1.16 GB."""
+    import json
+    import re
+    from pathlib import Path
+
+    import numpy as np
+
+    from eventstreamgpt_tpu.models.blocks import KindsBlock
+    from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
+    from eventstreamgpt_tpu.models.transformer import remat_block_cls
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ESGPT_PALLAS_IMPL", raising=False)
+    layer, n_params, n_calls, temp_gb, largest_mb = HYBRID_BLOCKS[letter]
+    model = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / "nemotron_twotower_ep16.json").read_text())
+    cfg = StructuredTransformerConfig(**model["config"])
+    block = remat_block_cls(cfg, False, KindsBlock)(cfg, layer_id=layer)
+    B, S = 16, 1024
+    x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((B, S), jnp.bool_, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: block.init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), jnp.ones(mask.shape, bool), None, False, False, False,
+            jnp.zeros(seg.shape, seg.dtype),
+        )
+    )
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == n_params
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+
+    def grad(p, x_, mask_, seg_):
+        def loss(p, x_):
+            out, _ = block.apply(p, x_, mask_, None, False, False, False, seg_, mutable=["routing"])[0]
+            return out.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1))(p, x_)
+
+    compiled = jax.jit(grad).lower(params, x, mask, seg).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    entry = text[text.index("ENTRY") :]
+    itemsize = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}
+    sizes = [
+        np.prod([int(n) for n in dims.split(",")]) * itemsize.get(dtype, 4)
+        for dtype, dims, op in re.findall(r"%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(", entry)
+        if op not in ("parameter", "get-tuple-element", "bitcast")
+    ]
+    assert max(sizes) < largest_mb * 1e6

@@ -29,6 +29,21 @@ KINDS = [
 ]
 
 
+# The nemotron_h stack: one part a layer, MEMEM*EME's first five letters (Mamba-2, routed relu^2, grouped-query attention).
+HYBRID = [
+    "config.hidden_size=32", "config.num_attention_heads=4", "config.num_key_value_heads=2", "config.head_dim=16",
+    "config.num_hidden_layers=6", "config.intermediate_size=48", 'config.seq_attention_types=["global"]',
+    'config.mixer_types=["ssm","none","ssm","none","ssm","mha"]', 'config.ffn_types=["none","routed","none","routed","none","none"]',
+    "config.norm_type=rms_norm", "config.layer_norm_epsilon=1e-05",
+    "config.mamba_num_heads=4", "config.mamba_head_dim=8", "config.mamba_n_groups=2", "config.ssm_state_size=16",
+    "config.mamba_chunk_size=8", "config.moe_expert_form=relu2", "config.moe_intermediate_size=24",
+    "config.moe_shared_expert_intermediate_size=40", "config.moe_router_width=16", "config.n_routed_experts=4",
+    "config.moe_expert_offset=4", "config.n_shared_experts=1", "config.num_experts_per_tok=6",
+    "config.routed_scaling_factor=2.5", "config.resid_dropout=0.0", "config.input_dropout=0.0",
+    "config.attention_dropout=0.0", "config.gradient_checkpointing=block",
+]
+
+
 @pytest.fixture(scope="module")
 def sample_dir(tmp_path_factory):
     dst = tmp_path_factory.mktemp("sample_ds_kinds")
@@ -38,8 +53,8 @@ def sample_dir(tmp_path_factory):
     return dst
 
 
-def _overrides(sample_dir, save_dir, steps):
-    return KINDS + [
+def _overrides(sample_dir, save_dir, steps, kinds=KINDS):
+    return kinds + [
         f"data_config.save_dir={sample_dir}", "data_config.max_seq_len=8", "data_config.min_seq_len=2",
         "optimization_config.init_lr=1e-3", "optimization_config.max_epochs=20",
         f"optimization_config.max_training_steps={steps}", "optimization_config.lr_num_warmup_steps=1",
@@ -66,6 +81,25 @@ def test_scripts_pretrain_trains_checkpoints_and_resumes(sample_dir, tmp_path):
     assert all(0 < rec["moe_load_max"] <= 4 * 16 for rec in train)
     # the same directory again, two steps further: the run restores step 4's checkpoint and goes on
     pretrain_main(_overrides(sample_dir, save_dir, 6))
+    steps = sorted(int(p.name) for p in (save_dir / "model_checkpoints").iterdir() if p.name.isdigit())
+    assert steps[-1] == 6
+
+
+def test_scripts_pretrain_trains_the_hybrid_stack_with_its_counters(sample_dir, tmp_path):
+    """Mamba-2, routed relu^2 and grouped-query attention layers through
+    `scripts.pretrain`, the device-resident packed feed (rows of 16 events at
+    a chunk of 8: segments start inside chunks and on their edges) and
+    `make_chunked_train_step` with the health sentinel and the routing
+    counters; a checkpoint is written and restored."""
+    save_dir = tmp_path / "pretrain"
+    pretrain_main(_overrides(sample_dir, save_dir, 4, HYBRID) + ["do_overwrite=true"])
+    log = [json.loads(line) for line in (save_dir / "train_log.jsonl").read_text().splitlines()]
+    train = [rec for rec in log if rec.get("split") == "train" and "train_loss" in rec]
+    assert train and all(np.isfinite(rec["train_loss"]) for rec in train)
+    # two routed layers, six choices of 16 a row, experts 4-7 held: a pair and a half a row and layer at even routing
+    assert all(0 < rec["moe_pairs_per_step"] <= 2 * 4 * 4 * 16 for rec in train)
+    assert all(0 < rec["moe_load_max"] <= 4 * 16 for rec in train)
+    pretrain_main(_overrides(sample_dir, save_dir, 6, HYBRID))
     steps = sorted(int(p.name) for p in (save_dir / "model_checkpoints").iterdir() if p.name.isdigit())
     assert steps[-1] == 6
 
@@ -112,15 +146,19 @@ def test_padded_plans_give_one_segment_a_row_with_its_padding_tail():
     )), 128) == 12 / 16
 
 
-def test_generate_and_the_engine_refuse_the_backbone():
+@pytest.mark.parametrize("stack", ["latent", "hybrid"])
+def test_generate_and_the_engine_refuse_the_backbone(stack):
     from eventstreamgpt_tpu.generation.generation_utils import generate
     from eventstreamgpt_tpu.models.ci_model import CIPPTForGenerativeSequenceModeling
     from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
     from eventstreamgpt_tpu.serving import GenerationEngine
-    from tests.models.test_layer_kinds import KINDS as KINDS_KWARGS
+    if stack == "latent":
+        from tests.models.test_layer_kinds import KINDS as KINDS_KWARGS
+    else:  # Mamba-2, routed relu^2 and grouped-query attention layers: no decode state for any of the three
+        from tests.models.test_hybrid_kinds import HYBRID as KINDS_KWARGS
     from tests.test_generation import BASE_KWARGS, MEASUREMENT_CONFIGS, make_prompt
 
-    classic = {k: v for k, v in BASE_KWARGS.items() if k not in ("hidden_size", "head_dim", "num_attention_heads", "num_hidden_layers", "intermediate_size", "seq_attention_types")}
+    classic = {k: v for k, v in BASE_KWARGS.items() if k not in KINDS_KWARGS and k != "head_dim"}
     config = StructuredTransformerConfig(measurement_configs=dict(MEASUREMENT_CONFIGS), **classic, **KINDS_KWARGS)
     model = CIPPTForGenerativeSequenceModeling(config)
     prompt = make_prompt()
@@ -132,3 +170,8 @@ def test_generate_and_the_engine_refuse_the_backbone():
         GenerationEngine(model, params, config, template=prompt, n_slots=2, max_len=8)
     with pytest.raises(NotImplementedError, match="no decode cache yet"):
         model.apply(params, prompt, use_cache=True, is_generation=True)
+    from eventstreamgpt_tpu.models.transformer import NO_DECODE_STATE
+
+    # the message names what is missing, by mechanism
+    for missing in ("latent paged cache", "recurrent state", "grouped key/value heads"):
+        assert missing in NO_DECODE_STATE

@@ -29,15 +29,19 @@ PARENT_LOSSES = {
     "na_w1024.pretrain": ["0x1.5c6b2a0000000p+3", "0x1.70c5700000000p+3", "0x1.59d9620000000p+3", "0x1.602e640000000p+3"],
 }
 KINDS_CELL = "glm47flash_ep8.pretrain_packed"  # added by PR 28: it has no parent to be equal to
+HYBRID_CELL = "nemotron_twotower_ep16.pretrain_packed"  # added by PR 32; compiled here at six layers, MEMEM*
 CELLS = sorted(PARENT_LOSSES) + [KINDS_CELL]
 # Scopes a model does not have: CI has no dependency graph, the classic block
-# none of the kinds block's (docs/layer_kinds.md), the kinds block no local layer.
-KINDS = {"attn_latent", "moe_router", "moe_dispatch", "moe_experts", "moe_shared"}
+# none of the kinds block's (docs/layer_kinds.md), the kinds block no local layer;
+# GLM's stack has no state-space layer, nemotron_h's no latent attention and no dense feed-forward.
+SSM = {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate"}
+KINDS = {"attn_latent", "moe_router", "moe_dispatch", "moe_experts", "moe_shared"} | SSM
 ABSENT = {
     "ci_w1024.pretrain_packed": {"dep_graph"} | KINDS,
     "ci_w1024.pretrain_padded": {"dep_graph"} | KINDS,
     "na_w1024.pretrain": KINDS,
-    KINDS_CELL: {"dep_graph", "attn_local"},
+    KINDS_CELL: {"dep_graph", "attn_local"} | SSM,
+    HYBRID_CELL: {"dep_graph", "attn_local", "attn_latent", "mlp"},
 }
 # Instructions of the scan body with an op_name and no es. scope, at most:
 # constants and broadcasts the compiler hoists, the scan's own slicing, the
@@ -61,6 +65,8 @@ def compiled():
             with pytest.MonkeyPatch.context() as patch:
                 patch.setenv("ESGPT_PALLAS_IMPL", "pallas_interpret")
                 cell = tiny_cell(name)
+                if name == HYBRID_CELL:  # the rehearsal's two layers are ME: take in the attention layer
+                    cell["model"]["config"]["num_hidden_layers"] = 6
                 cohort = cohort_lib.make_cohort(cell["cohort"], SEED)
                 work = Path(tempfile.mkdtemp(prefix="scopes_"))
                 prog = loader.load_job(cell).Program(cell, cohort, loader.load_reference(cell), SEED, work)
@@ -82,7 +88,7 @@ def _scope(op_name: str):
     return scope_of(op_name)[0]
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS + [HYBRID_CELL])
 def test_every_scope_the_model_has_occurs(name, compiled):
     op_names, _ = compiled(name)
     seen = {_scope(n) for n in op_names} - {None}
@@ -102,7 +108,7 @@ def test_all_three_phases_occur_under_the_mlp(name, compiled):
     assert bool(recompute) == (name != "na_w1024.pretrain")
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS + [HYBRID_CELL])
 def test_most_of_the_scan_body_is_under_a_scope(name, compiled):
     op_names, _ = compiled(name)
     body = [n for n in op_names if "/while/body/" in n]
