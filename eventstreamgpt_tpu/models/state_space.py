@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import segment_starts
+from ..ops.impl_select import LANE
 from ..ops.ssd_scan import ssd_scan
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
@@ -83,9 +84,22 @@ def _conv_silu(xbc, kernel, bias, ordinal):
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
 def _gate_norm(y, z, scale, groups: int, eps: float):
     """``RMSNorm_grouped(y * silu(z))``: gate first, then RMS over each of the
-    ``groups`` groups of channels, times ``scale``."""
-    gated = (y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).reshape(y.shape[:-1] + (groups, -1))
-    gated = gated * jax.lax.rsqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps)
+    ``groups`` groups of channels, times ``scale``.
+
+    The scan's kernels hand ``y`` over row-major, and a group's channels are
+    whole lane tiles there; reshaped to ``[..., groups, width]`` the compiler
+    re-lays the float32 plane with a group down the sublanes (three copies of
+    268 MB a layer at 16 rows of 1,024 events, PERF.md section 6, PR 33). So
+    where the shapes allow, the mean runs over the plane as the chip tiles it,
+    ``[row tiles, 8 rows, groups, lane tiles, 128]``, and nothing is moved."""
+    gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+    width = y.shape[-1] // groups
+    if width % LANE == 0 and (gated.size // y.shape[-1]) % 8 == 0:
+        shape, over = (-1, 8, groups, width // LANE, LANE), (3, 4)
+    else:
+        shape, over = y.shape[:-1] + (groups, width), (-1,)
+    gated = gated.reshape(shape)
+    gated = gated * jax.lax.rsqrt(jnp.mean(jnp.square(gated), axis=over, keepdims=True) + eps)
     return (scale * gated.reshape(y.shape)).astype(y.dtype)
 
 
@@ -95,7 +109,6 @@ class Mamba2Mixer(nn.Module):
     @nn.compact
     def __call__(self, u, attention_mask=None, segment_ids=None):
         cfg = self.config
-        dt_ = cfg.compute_dtype
         heads, p, groups, n = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_n_groups, cfg.ssm_state_size
         inner, conv_dim = heads * p, heads * p + 2 * groups * n
         batch, seq_len = u.shape[:2]
@@ -123,8 +136,7 @@ class Mamba2Mixer(nn.Module):
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             if attention_mask is not None:
                 dt = jnp.where(attention_mask[..., None], dt, 0.0)
-            y = ssd_scan(x, dt, -jnp.exp(a_log), bmat, cmat, ordinal, chunk=cfg.mamba_chunk_size)
-            y = (y.astype(jnp.float32) + skip[:, None] * x.astype(jnp.float32)).astype(dt_)
+            y = ssd_scan(x, dt, -jnp.exp(a_log), bmat, cmat, ordinal, chunk=cfg.mamba_chunk_size, skip=skip)
         with scope("ssm_gate"):
             scale = self.param("norm_scale", nn.initializers.ones, (inner,), jnp.float32)
             y = _gate_norm(y.reshape(batch, seq_len, inner), z, scale, groups, cfg.layer_norm_epsilon)

@@ -16,7 +16,7 @@ from benchmark.reference import nemotron_twotower_ep16 as ref
 from eventstreamgpt_tpu.models.blocks import GroupedQueryAttention, KindsBlock
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 from eventstreamgpt_tpu.models.moe import RoutedFeedForward, held_experts_output
-from eventstreamgpt_tpu.models.state_space import Mamba2Mixer, causal_conv, segment_ordinal
+from eventstreamgpt_tpu.models.state_space import Mamba2Mixer, _gate_norm, causal_conv, segment_ordinal
 from eventstreamgpt_tpu.ops.ssd_scan import ssd_scan
 
 PATTERN = "MEMEM*EME"
@@ -154,6 +154,33 @@ def test_the_scan_pads_a_row_to_whole_chunks_and_walks_rows_in_blocks(monkeypatc
     monkeypatch.setattr(module, "_L_BYTES", 1)  # a block is one row
     np.testing.assert_allclose(ssd_scan(x, dt, a, bmat, cmat, ordinal, chunk=8), want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(ssd_scan(x, dt, a, bmat, cmat, ordinal, chunk=128), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "rows, width",
+    [(32, 128), (16, 256), (12, 128), (16, 24)],
+    ids=["tiled", "tiled_two_lane_tiles", "rows_not_whole_tiles", "narrow_group"],
+)
+def test_the_grouped_norm_over_the_plane_as_it_is_tiled_is_the_grouped_norm(rows, width):
+    """`_gate_norm` takes a group's mean over ``[row tiles, 8, groups, lane tiles,
+    128]`` where a group is whole lane tiles and the rows whole sublane tiles
+    (the first two cases), over ``[..., groups, width]`` elsewhere: the same
+    numbers and gradients as the plain formula."""
+    groups, eps = 3, 1e-5
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    y, z, w = (jax.random.normal(k, (2, rows // 2, groups * width)) for k in keys[:3])
+    scale = 1.0 + 0.3 * jax.random.normal(keys[3], (groups * width,))
+
+    def plain(y, z, scale):
+        gated = (y * jax.nn.silu(z)).reshape(y.shape[:-1] + (groups, width))
+        gated = gated / jnp.sqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps)
+        return scale * gated.reshape(y.shape)
+
+    np.testing.assert_allclose(_gate_norm(y, z, scale, groups, eps), plain(y, z, scale), rtol=2e-6, atol=2e-6)
+    got = jax.grad(lambda *v: jnp.sum(_gate_norm(*v, groups, eps) * w), argnums=(0, 1, 2))(y, z, scale)
+    want = jax.grad(lambda *v: jnp.sum(plain(*v) * w), argnums=(0, 1, 2))(y, z, scale)
+    for g, v in zip(got, want):
+        np.testing.assert_allclose(g, v, rtol=2e-5, atol=2e-5)
 
 
 def routed_reference(x, p, cfg):

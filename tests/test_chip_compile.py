@@ -416,34 +416,48 @@ def test_latent_attention_warns_once_where_the_widths_are_not_lane_aligned(one_c
     assert len(assembly) == 1, [str(w.message) for w in caught]
 
 
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_ssd_scan_kernels_at_the_cells_shapes(one_chip, direction):
+    """`ops/pallas_ssd_scan.py` as `nemotron_twotower_ep16.pretrain_packed`
+    calls it: 16 rows of 1,024 events, 64 heads of 64 in 8 groups, a state of
+    128, chunks of 128, bf16. Forward is one Mosaic call; the gradient holds
+    the forward that saves the chunks' entering states and the backward."""
+    from eventstreamgpt_tpu.ops.pallas_ssd_scan import ssd_scan_kernels
+
+    B, S, H, P, G, N = 16, 1024, 64, 64, 8, 128
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    x, dt, a = shaped((B, S, H, P), jnp.bfloat16), shaped((B, S, H), jnp.float32), shaped((H,), jnp.float32)
+    bmat = cmat = shaped((B, S, G, N), jnp.bfloat16)
+    ordinal, skip = shaped((B, S), jnp.int32), shaped((H,), jnp.float32)
+
+    def scan(x, dt, a, bmat, cmat, skip, ordinal):
+        return ssd_scan_kernels(x, dt, a, bmat, cmat, ordinal, skip, chunk=128)
+
+    def grad(x, dt, a, bmat, cmat, skip, ordinal):
+        loss = lambda *v: scan(*v, ordinal).astype(jnp.float32).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))(x, dt, a, bmat, cmat, skip)
+
+    text = _compile(scan if direction == "forward" else grad, x, dt, a, bmat, cmat, skip, ordinal)
+    assert text.count('custom_call_target="tpu_custom_call"') == (1 if direction == "forward" else 2)
+
+
 # `nemotron_twotower_ep16.pretrain_packed`'s rows: 16 of 1,024 events at hidden 2,688
 HYBRID_BLOCKS = {
     # letter: (layer of MEMEM*EME, parameters, Mosaic calls, temporaries in GB at most, largest temporary in MB at most)
-    "M": (0, 38_744_896, 0, 2.6, 420),
+    "M": (0, 38_744_896, 2, 2.2, 420),
     "E": (1, 100_125_440, 6, 1.4, 420),
     "*": (5, 23_399_040, 3, 1.4, 420),
 }
+_ITEMSIZE = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}
 
 
-@pytest.mark.parametrize("letter", list(HYBRID_BLOCKS))
-def test_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, letter):
-    """One Mamba-2 (`M`), one routed relu^2 (`E`) and one grouped-query
-    attention (`*`) block of `benchmark/configs/nemotron_twotower_ep16.json`
-    under its ``block`` remat, forward and gradient on ``[16, 1024, 2688]``
-    bf16, as the chip's backend traces them. `M` is XLA products alone (no
-    Mosaic call) and its scan's ``L`` (537 MB whole in float32) is never held
-    whole: the largest temporary is the float32 ``[16, 1024, 6144]`` plane of
-    the convolution's backward (403 MB; then ``in_proj``'s bf16 output, 338
-    MB), 2.25 GB of temporaries in all. `E` holds megablox' ``gmm`` twice
-    forward, ``gmm`` and ``tgmm`` twice backward at 2,688 x 1,856 (the
-    contraction 2,688 takes the 128 tile, the width 1,856 no listed tile and
-    so the whole dimension), 1.16 GB; `*` the three flash kernels at 32 heads
-    (the two key/value heads repeated before them), 1.16 GB."""
+def _hybrid_block_gradient(one_chip, monkeypatch, layer):
+    """Layer ``layer`` of `benchmark/configs/nemotron_twotower_ep16.json` under
+    its ``block`` remat, the gradient in its parameters and its input on
+    ``[16, 1024, 2688]`` bf16 compiled as the chip's backend traces it:
+    ``(compiled, parameters)``."""
     import json
-    import re
     from pathlib import Path
-
-    import numpy as np
 
     from eventstreamgpt_tpu.models.blocks import KindsBlock
     from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
@@ -451,7 +465,6 @@ def test_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, letter):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.delenv("ESGPT_PALLAS_IMPL", raising=False)
-    layer, n_params, n_calls, temp_gb, largest_mb = HYBRID_BLOCKS[letter]
     model = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / "nemotron_twotower_ep16.json").read_text())
     cfg = StructuredTransformerConfig(**model["config"])
     block = remat_block_cls(cfg, False, KindsBlock)(cfg, layer_id=layer)
@@ -465,7 +478,7 @@ def test_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, letter):
             jnp.zeros(seg.shape, seg.dtype),
         )
     )
-    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == n_params
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
     params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
 
     def grad(p, x_, mask_, seg_):
@@ -475,15 +488,68 @@ def test_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, letter):
 
         return jax.grad(loss, argnums=(0, 1))(p, x_)
 
-    compiled = jax.jit(grad).lower(params, x, mask, seg).compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
-    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    return jax.jit(grad).lower(params, x, mask, seg).compile(), n_params
+
+
+def _temporaries(text):
+    """``(bytes, dtype, dims, operation)`` of what the compiled entry computation writes."""
+    import re
+
+    import numpy as np
+
     entry = text[text.index("ENTRY") :]
-    itemsize = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}
-    sizes = [
-        np.prod([int(n) for n in dims.split(",")]) * itemsize.get(dtype, 4)
+    return [
+        (int(np.prod([int(n) for n in dims.split(",")])) * _ITEMSIZE.get(dtype, 4), dtype, dims, op)
         for dtype, dims, op in re.findall(r"%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(", entry)
         if op not in ("parameter", "get-tuple-element", "bitcast")
     ]
-    assert max(sizes) < largest_mb * 1e6
+
+
+@pytest.mark.parametrize("letter", list(HYBRID_BLOCKS))
+def test_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, letter):
+    """One Mamba-2 (`M`), one routed relu^2 (`E`) and one grouped-query
+    attention (`*`) block of `benchmark/configs/nemotron_twotower_ep16.json`
+    under its ``block`` remat, forward and gradient on ``[16, 1024, 2688]``
+    bf16, as the chip's backend traces them. `M` holds the scan's two kernels
+    (`ops/pallas_ssd_scan.py`: the forward that the remat runs again, which
+    saves the chunks' entering states, and the backward; the block's first
+    forward is dead code in the gradient of a sum) and no decay plane: its
+    largest temporary is ``in_proj``'s bf16 output (338 MB), then the gate's
+    three float32 broadcasts of a group's rsqrt (268 MB each, the parent's
+    too), 2.11 GB of temporaries in all against 2.25 GB with XLA's scan (and
+    2.37 GB before the grouped norm took its mean over the plane as it is
+    tiled: the kernels' row-major ``y`` was re-laid for it three times). `E` holds megablox' ``gmm`` twice forward, ``gmm`` and ``tgmm`` twice
+    backward at 2,688 x 1,856 (the contraction 2,688 takes the 128 tile, the
+    width 1,856 no listed tile and so the whole dimension), 1.16 GB; `*` the
+    three flash kernels at 32 heads (the two key/value heads repeated before
+    them), 1.16 GB."""
+    layer, n_params, n_calls, temp_gb, largest_mb = HYBRID_BLOCKS[letter]
+    compiled, counted = _hybrid_block_gradient(one_chip, monkeypatch, layer)
+    assert counted == n_params
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    assert max(size for size, *_ in _temporaries(text)) < largest_mb * 1e6
+
+
+def test_scan_kernels_carry_the_scope_and_leave_no_decay_plane(one_chip, monkeypatch):
+    """What a device trace shows of the `M` block's scan: both Mosaic calls
+    are named ``ssd_scan_fwd`` / ``ssd_scan_bwd`` and lie under
+    ``es.ssm_scan`` (JAX traces a custom_vjp's rules without the caller's name
+    stack, so the rules name the scope), the one in the recomputed forward,
+    the other in the backward; nothing under that scope is an XLA product any
+    more (the chunked form's four einsums are inside the kernels), and no
+    float32 ``Q x Q`` plane over 100 MB is written (``L`` whole is 537 MB)."""
+    import re
+
+    from benchmark.harness.scopes import scope_of
+
+    compiled, _ = _hybrid_block_gradient(one_chip, monkeypatch, HYBRID_BLOCKS["M"][0])
+    text = compiled.as_text()
+    calls = dict(re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text))
+    assert sorted(name.split(".")[0] for name in calls) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+    assert {scope_of(path) for path in calls.values()} == {("ssm_scan", "recompute"), ("ssm_scan", "backward")}
+    under_the_scope = [n for n in re.findall(r'op_name="([^"]*)"', text) if scope_of(n)[0] == "ssm_scan"]
+    assert under_the_scope and not [n for n in under_the_scope if "dot_general" in n]
+    planes = [t for t in _temporaries(text) if t[1] == "f32" and t[2].endswith(",128,128") and t[0] > 100e6]
+    assert planes == []
