@@ -3,10 +3,12 @@
 ``h <- h + part(norm(h))`` once or twice a layer: the mixer of layer
 ``layer_id`` first, where it names one, then its feed-forward, where it names
 one, each behind a norm of its own (``mixer_layers``, ``ffn_layers``;
-docs/layer_kinds.md). Each kind is a small module: latent attention
-(`models/latent_attention.py`), the Mamba-2 mixer (`models/state_space.py`),
-grouped-query attention (here), the gated and the routed feed-forward
-(`models/moe.py`). The classic block (`InnerBlock`: LayerNorm, multi-head
+docs/layer_kinds.md). Under ``hc_mult`` > 1 the state is that many residual
+streams, a tuple of ``[B, S, C]`` planes, and each part reads and writes them
+through learned maps of its own (`models/hyper_connections.py`). Each kind is
+a small module: latent attention (`models/latent_attention.py`), the Mamba-2
+mixer (`models/state_space.py`), grouped-query attention (here), the gated and
+the routed feed-forward (`models/moe.py`). The classic block (`InnerBlock`: LayerNorm, multi-head
 attention, GELU MLP, every cache branch) is not migrated here; the encoder
 builds one stack or the other.
 
@@ -25,6 +27,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
+from .hyper_connections import hyper_connected
 from .latent_attention import LatentAttention, RMSNorm, bias_free_dense, causal_core
 from .moe import RoutedFeedForward, SwiGLU
 from .state_space import Mamba2Mixer
@@ -92,17 +95,30 @@ class KindsBlock(nn.Module):
             with scope("norm"):
                 return RMSNorm(cfg.layer_norm_epsilon, cfg.compute_dtype, name=name)(x)
 
+        def residual(name, x, sublayer):
+            """``x + sublayer(x)`` on one stream; on ``hc_mult`` streams the sublayer between
+            its learned mixes (`models/hyper_connections.py`), maps ``name``'s."""
+            if cfg.hc_mult == 1:
+                return x + sublayer(x)
+            return hyper_connected(cfg, name, x, sublayer)
+
         if mixer != "none":
             module, name = MIXERS[mixer]
-            mixed = module(cfg, name=name)(norm("input_layernorm", hidden_states), attention_mask, segment_ids)
-            hidden_states = hidden_states + mixed
+            hidden_states = residual(
+                "mixer_hc",
+                hidden_states,
+                lambda x: module(cfg, name=name)(norm("input_layernorm", x), attention_mask, segment_ids),
+            )
         if ffn != "none":
             # One norm a part: a layer that is a feed-forward alone has the layer's one norm.
-            normed = norm("post_attention_layernorm" if mixer != "none" else "input_layernorm", hidden_states)
-            if ffn == "routed":
-                fed = RoutedFeedForward(cfg, name="mlp")(normed, attention_mask)
-            else:
+            norm_name = "post_attention_layernorm" if mixer != "none" else "input_layernorm"
+
+            def feed_forward(x):
+                normed = norm(norm_name, x)
+                if ffn == "routed":
+                    return RoutedFeedForward(cfg, name="mlp")(normed, attention_mask)
                 with scope("mlp"):
-                    fed = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(normed)
-            hidden_states = hidden_states + fed
+                    return SwiGLU(cfg, cfg.intermediate_size, name="mlp")(normed)
+
+            hidden_states = residual("ffn_hc", hidden_states, feed_forward)
         return hidden_states, {}

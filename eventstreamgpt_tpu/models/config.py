@@ -230,6 +230,11 @@ class StructuredTransformerConfig(JSONableMixin):
         qk_rope_head_dim: int | None = None,
         v_head_dim: int | None = None,
         rope_theta: float = 10000.0,
+        rope_scaling: dict | None = None,
+        hc_mult: int = 1,
+        hc_sinkhorn_iters: int = 20,
+        hc_eps: float = 1e-6,
+        hc_res_clamp: float = 30.0,
         moe_intermediate_size: int | None = None,
         moe_router_width: int | None = None,
         n_routed_experts: int | None = None,
@@ -486,6 +491,10 @@ class StructuredTransformerConfig(JSONableMixin):
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
         self.rope_theta = rope_theta
+        self.rope_scaling = rope_scaling
+        self.hc_mult, self.hc_sinkhorn_iters, self.hc_eps, self.hc_res_clamp = (
+            hc_mult, hc_sinkhorn_iters, hc_eps, hc_res_clamp
+        )
         self.num_key_value_heads = num_key_value_heads
         self.mamba_num_heads = mamba_num_heads
         self.mamba_head_dim = mamba_head_dim
@@ -563,6 +572,8 @@ class StructuredTransformerConfig(JSONableMixin):
         # test_scan_layers.py); checkpoints migrate between the two layouts
         # with `models.transformer.stack_layer_params` / `unstack_layer_params`.
         self.scan_layers = bool(scan_layers)
+        if self.scan_layers and self.hc_mult > 1:
+            raise ValueError("hc_mult > 1 with scan_layers: the kinds block, which carries the streams, is not scanned")
         if precision not in ("fp32", "bf16"):
             raise ValueError(f"precision must be 'fp32' or 'bf16'; got {precision}")
         self.precision = precision
@@ -747,6 +758,14 @@ class StructuredTransformerConfig(JSONableMixin):
                 f"swiglu / routed / none; got {mixer_types}, {ffn_types}, {norm_type}, "
                 f"qk_nope_head_dim {self.qk_nope_head_dim}"
             )
+        if self.rope_scaling is not None and (
+            not isinstance(self.rope_scaling, dict) or self.rope_scaling.get("type") != "yarn" or "latent" not in mixers
+        ):
+            raise ValueError(f"rope_scaling is null or a 'yarn' group of latent attention's; got {self.rope_scaling}")
+        if type(self.hc_mult) is not int or self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
+            raise ValueError(f"hc_mult and hc_sinkhorn_iters are whole numbers of at least 1; got {self.hc_mult}, {self.hc_sinkhorn_iters}")
+        if self.hc_mult > 1 and not kinds:
+            raise ValueError("hc_mult > 1: only the kinds block (norm_type rms_norm) carries residual streams")
         if not kinds:
             if self.num_key_value_heads not in (None, self.num_attention_heads):
                 raise ValueError("the classic block has as many key/value heads as query heads")

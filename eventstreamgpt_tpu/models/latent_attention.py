@@ -11,8 +11,11 @@ writes it (GLM-4.7-Flash's ``glm4_moe_lite``), with no bias anywhere::
 
 A position is the event's index inside its subject: it restarts at every
 segment of a packed row. The core is the ``pallas_flash`` kernel the classic
-global layers use wherever the two head widths are equal (they are published
-equal: 192 + 64 and 256), and the einsum elsewhere. There is no decode cache
+global layers use wherever some group of heads is whole 128-lane tiles at
+either width (GLM-4.7-Flash's 192 + 64 and 256; Xing4.0's 128 + 64 beside 128, two
+heads of 192 being three tiles), and the einsum elsewhere. ``rope_scaling``
+of type ``yarn`` blends RoPE's frequencies and scales the logits
+(`ops/rope.py`, `softmax_scale`). There is no decode cache
 yet: a latent paged cache and the absorbed decode path are ROADMAP R2/R3.
 
 **How q, k and v are assembled.** Two formulations of the lines above, one
@@ -47,6 +50,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import segment_starts
+from ..ops.rope import rope_cos_sin, yarn_mscale
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
 from .transformer import ATTENTION_CHECKPOINT_NAME
@@ -86,14 +90,24 @@ def segment_positions(segment_ids, batch_size: int, seq_len: int):
     return idx - first
 
 
-def rotate(x, positions, theta: float):
+def softmax_scale(cfg) -> float:
+    """Latent attention's logit scale: ``(nope + rope)^-1/2``, times YaRN's
+    ``m(mscale_all_dim)^2`` under a ``rope_scaling`` that names one."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scaling = getattr(cfg, "rope_scaling", None)
+    if scaling and scaling.get("mscale_all_dim", 0):
+        scale *= yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2
+    return scale
+
+
+def rotate(x, positions, theta: float, scaling: dict | None = None):
     """RoPE over the last axis of ``x`` (B, S, ..., d), rotate-half pairing:
-    dimension ``i`` pairs with ``i + d/2``. Float32 inside."""
+    dimension ``i`` pairs with ``i + d/2``; angles `ops.rope.rope_cos_sin`'s.
+    Float32 inside."""
     d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # (B, S, d/2)
-    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    cos, sin = (
+        t.reshape(t.shape[:2] + (1,) * (x.ndim - 3) + t.shape[-1:]) for t in rope_cos_sin(positions, d, theta, scaling)
+    )  # (B, S, ..., d/2)
     x32 = x.astype(jnp.float32)
     a, b = x32[..., : d // 2], x32[..., d // 2 :]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
@@ -155,8 +169,8 @@ class LatentAttention(nn.Module):
             if impl == "xla":
                 q = q.reshape(B, S, H, dn + dr)
                 kv = dense(H * (dn + dv), "kv_b_proj")(c_kv).reshape(B, S, H, dn + dv)
-                q_r = rotate(q[..., dn:], positions, cfg.rope_theta)
-                k_r = rotate(kv_a[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
+                q_r = rotate(q[..., dn:], positions, cfg.rope_theta, cfg.rope_scaling)
+                k_r = rotate(kv_a[..., cfg.kv_lora_rank :], positions, cfg.rope_theta, cfg.rope_scaling)
                 query = jnp.concatenate([q[..., :dn], q_r], axis=-1)
                 key = jnp.concatenate(
                     [kv[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, dr))], axis=-1
@@ -173,7 +187,8 @@ class LatentAttention(nn.Module):
                 value = jnp.dot(c_kv, w_value)
                 query, key = per_batch_shard(
                     lambda q, k, r, p: rope_join(
-                        q, k, r, p, heads=H, rope=dr, theta=cfg.rope_theta, interpret=impl == "pallas_interpret"
+                        q, k, r, p, heads=H, rope=dr, theta=cfg.rope_theta, scaling=cfg.rope_scaling,
+                        interpret=impl == "pallas_interpret",
                     ),
                     q, jnp.dot(c_kv, w_key), kv_a[..., cfg.kv_lora_rank :], positions,
                 )
@@ -210,21 +225,30 @@ class LatentAttention(nn.Module):
         return "xla"
 
     def _core(self, query, key, value, attention_mask, segment_ids):
-        return causal_core(self.config, query, key, value, attention_mask, segment_ids, "latent attention")
+        return causal_core(
+            self.config, query, key, value, attention_mask, segment_ids, "latent attention", softmax_scale(self.config)
+        )
 
 
-def causal_core(cfg, query, key, value, attention_mask, segment_ids, who: str):
-    """Causal, segment-masked softmax attention over (B, S, H, d), scaled by
-    ``d ** -0.5``: the repo's flash op where the configuration asks for it and
-    a kernel is taken (a TPU, or the interpreter where
-    ``$ESGPT_PALLAS_IMPL=pallas_interpret``), the einsum elsewhere."""
+def causal_core(cfg, query, key, value, attention_mask, segment_ids, who: str, scale: float | None = None):
+    """Causal, segment-masked softmax attention of queries and keys (B, S, H,
+    d) over values (B, S, H, dv), scaled by ``scale`` (``d ** -0.5`` where not
+    given): the repo's flash op where the configuration asks for it and a
+    kernel is taken (a TPU, or the interpreter where
+    ``$ESGPT_PALLAS_IMPL=pallas_interpret``), the einsum elsewhere. The op
+    takes a key width beside a value width where some number of heads side by
+    side is whole 128-lane tiles at both (`ops.pallas_flash.lane_tile_groups`:
+    256 / 256 and 128 / 128 a head, 192 / 128 two heads, as it is: on the chip
+    the kernels at 192 are 4% faster than at keys padded to 256, PERF.md
+    section 6, PR 34)."""
     from ..ops.impl_select import resolve_impl
+    from ..ops.pallas_flash import lane_tile_groups
 
     B, S, H, d = query.shape
-    scale = d**-0.5
+    scale = d**-0.5 if scale is None else scale
     want_kernel = cfg.attention_implementation == "pallas_flash"
     impl = resolve_impl(None, "flash attention") if want_kernel else "xla"
-    if impl != "xla" and S % 128 == 0 and d % 128 == 0 and value.shape[-1] == d:
+    if impl != "xla" and S % 128 == 0 and lane_tile_groups(H, d, value.shape[-1]):
         from ..ops.pallas_flash import flash_attention
         from ..parallel.context import per_batch_shard
 
@@ -243,7 +267,7 @@ def causal_core(cfg, query, key, value, attention_mask, segment_ids, who: str):
         warnings.warn(
             f"attention_implementation='pallas_flash' is taking the einsum path in {who}: "
             f"backend={jax.default_backend()!r}, S={S}, head widths {d} and {value.shape[-1]} "
-            "(the flash kernel needs a TPU, S % 128 == 0 and equal widths of whole 128-lane tiles)",
+            "(the flash kernel needs a TPU, S % 128 == 0 and a group of heads that is whole 128-lane tiles at either width)",
             stacklevel=3,
         )
     logits = jnp.einsum("bqhd,bkhd->bhqk", query, key, preferred_element_type=jnp.float32) * scale

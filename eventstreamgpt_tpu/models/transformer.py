@@ -1424,6 +1424,14 @@ def unstack_layer_params(params, config: StructuredTransformerConfig):
     return walk(params)
 
 
+def _streams_sum(hidden_states):
+    """Hyper-connected residual streams (a tuple of planes) read out as one
+    state, summed in float32; one stream's state as it is."""
+    if not isinstance(hidden_states, tuple):
+        return hidden_states
+    return sum(h.astype(jnp.float32) for h in hidden_states).astype(hidden_states[0].dtype)
+
+
 class ConditionallyIndependentPointProcessTransformer(nn.Module):
     """Stack of `InnerBlock`s over whole-event embeddings.
 
@@ -1499,10 +1507,14 @@ class ConditionallyIndependentPointProcessTransformer(nn.Module):
             if kinds:
                 from .blocks import KindsBlock as kinds_block
             block_cls = remat_block_cls(cfg, self.use_gradient_checkpointing, kinds_block)
+            if cfg.hc_mult > 1:
+                # Hyper-connected residual streams (`models/hyper_connections.py`): the embedding
+                # replicated in, a tuple of n planes between the blocks, summed out before ln_f.
+                hidden_states = (hidden_states,) * cfg.hc_mult
 
             for i in range(cfg.num_hidden_layers):
                 if all_hidden is not None:
-                    all_hidden.append(hidden_states)
+                    all_hidden.append(_streams_sum(hidden_states))  # a layer's state as `ln_f` would read it
                 layer_past = past[i] if past is not None else None
                 block = block_cls(cfg, layer_id=i, is_seq=True, name=f"h{i}")
                 hidden_states, outputs = block(
@@ -1517,12 +1529,17 @@ class ConditionallyIndependentPointProcessTransformer(nn.Module):
                 # Reference parity: zero masked events' hidden states between
                 # layers (``transformer.py:820-825``).
                 if batch is not None and batch.event_mask is not None:
-                    hidden_states = jnp.where(batch.event_mask[..., None], hidden_states, 0.0)
+                    hidden_states = jax.tree_util.tree_map(
+                        lambda h: jnp.where(batch.event_mask[..., None], h, 0.0), hidden_states
+                    )
                 if presents is not None:
                     presents.append(outputs.get("present_key_value"))
                 if all_attentions is not None:
                     all_attentions.append(outputs.get("attn_weights"))
 
+        if isinstance(hidden_states, tuple):
+            with scope("hc_mix"):
+                hidden_states = _streams_sum(hidden_states)
         with scope("norm"):
             if kinds:
                 from .latent_attention import RMSNorm

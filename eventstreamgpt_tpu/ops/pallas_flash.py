@@ -47,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.scopes import scope
 
-__all__ = ["FlashSizes", "chunk_bounds", "flash_attention", "flash_block_sizes", "visited_share"]
+__all__ = ["FlashSizes", "chunk_bounds", "flash_attention", "flash_block_sizes", "lane_tile_groups", "visited_share"]
 
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 LANES = 128
@@ -62,8 +62,26 @@ class FlashSizes(NamedTuple):
     chunk_k: int  # keys one step of the walk takes
 
 
-def flash_block_sizes(batch: int, seq_len: int, num_heads: int, head_dim: int, itemsize: int = 2) -> FlashSizes:
-    """Rows and heads a grid step and the walk's chunk widths, from static shapes.
+def lane_tile_groups(num_heads: int, head_dim: int, value_dim: int | None = None) -> list[int]:
+    """The numbers of heads a grid step can take side by side as lane blocks
+    of whole 128-lane tiles, at the key width and at the value width: the
+    divisors ``g`` of the heads with ``g * head_dim`` and ``g * value_dim``
+    multiples of 128 (256 / 256 and 128 / 128 from one head on; 192 / 128 from
+    two). `flash_block_sizes` picks among them, and the kinds block's core
+    hands the op what has any (`latent_attention.causal_core`)."""
+    value_dim = value_dim or head_dim
+    return [
+        g for g in range(1, num_heads + 1)
+        if num_heads % g == 0 and g * head_dim % LANES == 0 and g * value_dim % LANES == 0
+    ]
+
+
+def flash_block_sizes(
+    batch: int, seq_len: int, num_heads: int, head_dim: int, itemsize: int = 2, value_dim: int | None = None
+) -> FlashSizes:
+    """Rows and heads a grid step and the walk's chunk widths, from static
+    shapes: ``head_dim`` the queries' and keys' width, ``value_dim`` the
+    values' and the output's where it differs (the wider of the two decides).
 
     Swept on a v5e with `scripts/probe_flash_blocks.py` at the benchmark
     cells' shapes (PERF.md section 6, PR 29):
@@ -82,12 +100,14 @@ def flash_block_sizes(batch: int, seq_len: int, num_heads: int, head_dim: int, i
       128 above it, where a 256-wide pair's accumulators no longer fit the
       registers (9% faster than 256 x 256 at width 256).
     """
-    chunk = next((c for c in ((256, 128) if head_dim <= 128 else (128,)) if seq_len % c == 0), seq_len)
+    value_dim = value_dim or head_dim
+    widest = max(head_dim, value_dim)
+    chunk = next((c for c in ((256, 128) if widest <= 128 else (128,)) if seq_len % c == 0), seq_len)
     want = max(1, 1024 // seq_len)
     rows = max(r for r in range(1, want + 1) if batch % r == 0)
-    # a grid step's lane block: whole heads, whole 128-lane tiles (or the array's whole width)
-    groups = [g for g in range(1, num_heads + 1) if num_heads % g == 0 and (g * head_dim % LANES == 0 or g == num_heads)]
-    fits = [g for g in groups if rows * seq_len * g * head_dim * itemsize <= 5 * 2**20]
+    # a grid step's lane blocks: whole heads, whole 128-lane tiles (or the array's whole width)
+    groups = sorted({*lane_tile_groups(num_heads, head_dim, value_dim), num_heads})
+    fits = [g for g in groups if rows * seq_len * g * widest * itemsize <= 5 * 2**20]
     return FlashSizes(rows, max(fits, default=groups[0]), chunk, chunk)
 
 
@@ -229,7 +249,7 @@ def _fwd_kernel(
     k_lo_ref, k_hi_ref, seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, vt_ref, seg_kd_ref, *, scale, sizes
 ):
     rows, group, cq, ck = sizes
-    d = q_ref.shape[-1] // group
+    d = v_ref.shape[-1] // group  # the values' and the output's width
     n_q = q_ref.shape[1] // cq
     row0 = pl.program_id(0) * rows
     rel = _rel(ck, cq)
@@ -311,7 +331,7 @@ def _dkv_kernel(
     *, scale, sizes,
 ):
     rows, group, cq, ck = sizes
-    d = k_ref.shape[-1] // group
+    d, dv = k_ref.shape[-1] // group, v_ref.shape[-1] // group
     n_k = k_ref.shape[1] // ck
     row0 = pl.program_id(0) * rows
     rel = _rel(ck, cq)
@@ -328,18 +348,18 @@ def _dkv_kernel(
             mask = (seg_k == seg_q_ref[r, pl.ds(i, 1), :]) & (rel >= j * ck - i * cq)
             out = []
             for g, (q, do) in enumerate(zip(_heads(q_ref, r, at_q, group), _heads(do_ref, r, at_q, group))):
-                dk, dv = grads[g]
+                dk, dvalue = grads[g]
                 lse, di = lse_ref[r, g, pl.ds(i, 1), :], di_ref[r, g, pl.ds(i, 1), :]
                 p, ds = _probabilities(scale, k[g], q, v[g], do, lse, di, mask)
-                out.append((dk + _dot(ds.astype(q.dtype), q), dv + _dot(p.astype(do.dtype), do)))
+                out.append((dk + _dot(ds.astype(q.dtype), q), dvalue + _dot(p.astype(do.dtype), do)))
             return tuple(out)
 
         b = (row0 + r) * n_k + j
-        zeros = jnp.zeros((ck, d), jnp.float32)
-        grads = jax.lax.fori_loop(q_lo_ref[b], q_hi_ref[b] + 1, q_chunk, ((zeros, zeros),) * group)
-        for g, (dk, dv) in enumerate(grads):
+        zeros = (jnp.zeros((ck, d), jnp.float32), jnp.zeros((ck, dv), jnp.float32))
+        grads = jax.lax.fori_loop(q_lo_ref[b], q_hi_ref[b] + 1, q_chunk, (zeros,) * group)
+        for g, (dk, dvalue) in enumerate(grads):
             dk_ref[r, at_k, g * d : (g + 1) * d] = dk.astype(dk_ref.dtype)
-            dv_ref[r, at_k, g * d : (g + 1) * d] = dv.astype(dv_ref.dtype)
+            dv_ref[r, at_k, g * dv : (g + 1) * dv] = dvalue.astype(dv_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, rows * n_k, k_chunk, 0)
@@ -348,8 +368,8 @@ def _dkv_kernel(
 # ------------------------------------------------------------------ the calls
 def _specs(batch, seq_len, heads, head_dim, sizes):
     """Block specs of one grid step ``(row block, head group)``: whole rows of
-    ``group`` heads in the projections' layout, the statistics' rows, the
-    segment rows."""
+    ``group`` heads of width ``head_dim`` in the projections' layout, the
+    statistics' rows, the segment rows."""
     rows, group, cq, ck = sizes
     qkv = pl.BlockSpec((rows, seq_len, group * head_dim), lambda b, h, *_: (b, 0, h))
     stat = pl.BlockSpec((rows, group, seq_len // cq, cq), lambda b, h, *_: (b, h, 0, 0))
@@ -399,49 +419,51 @@ def _walk(seg, sizes):
 # they did the stock kernel's; the call sites keep their own names.
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _forward(q, k, v, walk, heads, scale, sizes, interpret):
-    batch, seq_len, width = q.shape
-    head_dim = width // heads
-    grid, qkv, stat, seg_q, seg_k = _specs(batch, seq_len, heads, head_dim, sizes)
+    batch, seq_len, _ = q.shape
+    key_dim, value_dim = q.shape[-1] // heads, v.shape[-1] // heads
+    grid, qk, stat, seg_q, seg_k = _specs(batch, seq_len, heads, key_dim, sizes)
+    vo = _specs(batch, seq_len, heads, value_dim, sizes)[1]
     seg_q_rows, seg_k_rows, k_lo, k_hi, _, _ = walk
     return _call(
         functools.partial(_fwd_kernel, scale=scale, sizes=sizes),
         "flash_attention",
         grid,
-        [seg_q, seg_k, qkv, qkv, qkv],
-        [qkv, stat],
+        [seg_q, seg_k, qk, qk, vo],
+        [vo, stat],
         [
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
             jax.ShapeDtypeStruct((batch, heads, seq_len // sizes.chunk_q, sizes.chunk_q), jnp.float32),
         ],
         interpret,
-        _scratch(seq_len, head_dim, q.dtype, sizes),
+        _scratch(seq_len, value_dim, q.dtype, sizes),
     )(k_lo, k_hi, seg_q_rows, seg_k_rows, q, k, v)
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
 def _backward(q, k, v, walk, o, lse, do, heads, scale, sizes, interpret):
-    batch, seq_len, width = q.shape
-    head_dim = width // heads
-    grid, qkv, stat, seg_q, seg_k = _specs(batch, seq_len, heads, head_dim, sizes)
+    batch, seq_len, _ = q.shape
+    key_dim, value_dim = q.shape[-1] // heads, v.shape[-1] // heads
+    grid, qk, stat, seg_q, seg_k = _specs(batch, seq_len, heads, key_dim, sizes)
+    vo = _specs(batch, seq_len, heads, value_dim, sizes)[1]
     seg_q_rows, seg_k_rows, k_lo, k_hi, q_lo, q_hi = walk
     # di = sum(o * do) over a head's width, in the statistics' compact shape.
     di = jnp.sum(
-        o.astype(jnp.float32).reshape(batch, seq_len, heads, head_dim)
-        * do.astype(jnp.float32).reshape(batch, seq_len, heads, head_dim),
+        o.astype(jnp.float32).reshape(batch, seq_len, heads, value_dim)
+        * do.astype(jnp.float32).reshape(batch, seq_len, heads, value_dim),
         axis=-1,
     )
     di = di.transpose(0, 2, 1).reshape(lse.shape)
     operands = (seg_q_rows, seg_k_rows, q, k, v, do, lse, di)
-    in_specs = [seg_q, seg_k, qkv, qkv, qkv, qkv, stat, stat]
-    like = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    in_specs = [seg_q, seg_k, qk, qk, vo, vo, stat, stat]
+    like_k, like_v = jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(v.shape, q.dtype)
     dk, dv = _call(
         functools.partial(_dkv_kernel, scale=scale, sizes=sizes),
-        "flash_mha_bwd_dkv", grid, in_specs, [qkv, qkv], [like, like], interpret,
-        _scratch(seq_len, head_dim, q.dtype, sizes)[1:],
+        "flash_mha_bwd_dkv", grid, in_specs, [qk, vo], [like_k, like_v], interpret,
+        _scratch(seq_len, key_dim, q.dtype, sizes)[1:],
     )(q_lo, q_hi, *operands)
     dq = _call(
         functools.partial(_dq_kernel, scale=scale, sizes=sizes),
-        "flash_mha_bwd_dq", grid, in_specs, qkv, like, interpret, _scratch(seq_len, head_dim, q.dtype, sizes),
+        "flash_mha_bwd_dq", grid, in_specs, qk, like_k, interpret, _scratch(seq_len, key_dim, q.dtype, sizes),
     )(k_lo, k_hi, *operands)
     return dq, dk, dv
 
@@ -475,19 +497,22 @@ def flash_attention(
     the keys with the query's segment id at or before it.
 
     Args:
-        query, key, value: ``[B, S, H, d]`` as the projections leave them
-            (``d`` a multiple of 128, ``S`` of the chunk widths), one dtype.
+        query, key: ``[B, S, H, d]`` as the projections leave them.
+        value: ``[B, S, H, dv]``; ``dv`` may differ from ``d`` (latent
+            attention at 192 / 128). A group of heads of either width is whole
+            128-lane tiles (`flash_block_sizes`); ``S`` a multiple of the
+            chunk widths; one dtype.
         segment_ids: ``[B, S]`` int32, padding as ``-1``.
         sm_scale: the logits' scale.
         sizes: `flash_block_sizes` where not given (the probe sweeps them).
         interpret: run the kernels in Pallas' interpreter (any backend).
 
-    Returns ``[B, S, H, d]``. Differentiable in query, key and value.
+    Returns ``[B, S, H, dv]``. Differentiable in query, key and value.
     """
     batch, seq_len, heads, head_dim = query.shape
-    sizes = sizes or flash_block_sizes(batch, seq_len, heads, head_dim, query.dtype.itemsize)
-    flat = (batch, seq_len, heads * head_dim)
+    sizes = sizes or flash_block_sizes(batch, seq_len, heads, head_dim, query.dtype.itemsize, value.shape[-1])
+    flat = (batch, seq_len, -1)
     with scope("attn_global"):
         walk = _walk(segment_ids.astype(jnp.int32), sizes)
     out = _flash(query.reshape(flat), key.reshape(flat), value.reshape(flat), walk, heads, float(sm_scale), sizes, interpret)
-    return out.reshape(query.shape)
+    return out.reshape(value.shape)

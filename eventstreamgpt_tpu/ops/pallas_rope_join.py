@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.scopes import scope
 from .impl_select import LANE as LANES
+from .rope import rope_cos_sin
 
 __all__ = ["rope_join", "rope_join_applies", "rope_tables"]
 
@@ -53,14 +54,12 @@ def rope_join_applies(nope: int, rope: int, v_head_dim: int) -> bool:
     return (nope + rope) % LANES == 0 and rope % 2 == 0 and 0 < rope <= LANES and v_head_dim == nope + rope
 
 
-def rope_tables(positions, rope: int, theta: float):
+def rope_tables(positions, rope: int, theta: float, scaling: dict | None = None):
     """``(cos, sin)`` float32 ``[B, S, 128]`` for a head's last lane tile:
-    `models.latent_attention.rotate`'s angles on the last ``rope`` lanes, the
-    sine negated on their first half; 1 and 0 on the lanes before them."""
-    half = rope // 2
-    inv_freq = theta ** (-jnp.arange(0, rope, 2, dtype=jnp.float32) / rope)
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # (B, S, rope / 2)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    `models.latent_attention.rotate`'s angles (its frequencies and, under a
+    ``yarn`` ``scaling``, its table scale) on the last ``rope`` lanes, the sine
+    negated on their first half; 1 and 0 on the lanes before them."""
+    cos, sin = rope_cos_sin(positions, rope, theta, scaling)  # (B, S, rope / 2)
     lead = positions.shape + (LANES - rope,)
     return (
         jnp.concatenate([jnp.ones(lead, jnp.float32), cos, cos], axis=-1),
@@ -189,7 +188,9 @@ def _rope_join_bwd(heads, rope, interpret, tables, cotangents):
 _rope_join.defvjp(_rope_join_fwd, _rope_join_bwd)
 
 
-def rope_join(q, k_nope, k_r, positions, *, heads: int, rope: int, theta: float, interpret: bool = False):
+def rope_join(
+    q, k_nope, k_r, positions, *, heads: int, rope: int, theta: float, scaling: dict | None = None, interpret: bool = False
+):
     """Latent attention's ``(query, key)``, both ``[B, S, heads * d]``.
 
     Args:
@@ -206,5 +207,5 @@ def rope_join(q, k_nope, k_r, positions, *, heads: int, rope: int, theta: float,
     ``k_nope`` and ``k_r``; the two big operands are consumed (aliased).
     """
     with scope("attn_latent"):
-        cos, sin = rope_tables(positions, rope, theta)
+        cos, sin = rope_tables(positions, rope, theta, scaling)
     return _rope_join(q, k_nope, k_r, cos, sin, heads, rope, interpret)

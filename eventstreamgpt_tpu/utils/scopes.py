@@ -32,6 +32,8 @@ SCOPES = (
     "ssm_conv",  # its causal convolution inside the segment, silu, the splits
     "ssm_scan",  # softplus, the decays, the chunked scan, the D skip
     "ssm_gate",  # the gate and the grouped norm
+    "hc_maps",  # a hyper-connected sublayer's maps: the streams' norm, Phi's product, the sigmoids, Sinkhorn
+    "hc_mix",  # its pre-mix of the streams, its post/res mix back into them, the sum before ln_f
     "mlp",  # the feed-forward block (the classic MLP, the dense SwiGLU)
     "moe_router",  # a routed layer's logits, sigmoid, top-k and weights
     "moe_dispatch",  # ordering the (row, expert) pairs by expert, gathering rows, combining back

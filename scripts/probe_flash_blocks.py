@@ -8,11 +8,15 @@ its chunk widths; a timing is a window of back-to-back dispatches ended by
 ``jax.block_until_ready``, which waits on the chip. The winner
 feeds `ops.pallas_flash.flash_block_sizes`; the table is in PERF.md section 6
 (PR 29). ``--stock`` also times jax's stock kernel at the blocks the parent
-commit gave it, for the same inputs.
+commit gave it, for the same inputs (equal widths only). ``--only <text>``
+keeps the shapes whose name holds the text: ``xing40`` is latent attention at
+a key width of 192 beside a value width of 128 (the sweep against keys padded
+to 256 with zero columns is in PERF.md section 6, PR 34; the padded shape is
+gone with the padded path).
 
 Run on the real chip:
 
-    python scripts/probe_flash_blocks.py [--stock]
+    python scripts/probe_flash_blocks.py [--stock] [--only <text>]
 """
 
 from __future__ import annotations
@@ -34,11 +38,12 @@ from eventstreamgpt_tpu.ops.pallas_flash import (  # noqa: E402
     visited_share,
 )
 
-# name, B, H, S, d, sm_scale, packed, longest history
+# name, B, H, S, d (queries and keys), dv (values), sm_scale, packed, longest history
 SHAPES = [
-    ("ci_w1024.pretrain_packed", 16, 8, 1024, 128, 1.0, True, 512),
-    ("ci_w1024.pretrain_padded", 64, 8, 256, 128, 1.0, False, 256),
-    ("glm47flash_ep8.pretrain_packed", 16, 20, 1024, 256, 256**-0.5, True, 512),
+    ("ci_w1024.pretrain_packed", 16, 8, 1024, 128, 128, 1.0, True, 512),
+    ("ci_w1024.pretrain_padded", 64, 8, 256, 128, 128, 1.0, False, 256),
+    ("glm47flash_ep8.pretrain_packed", 16, 20, 1024, 256, 256, 256**-0.5, True, 512),
+    ("xing40_a4b_ep8.pretrain_packed", 8, 32, 1024, 192, 128, 192**-0.5, True, 512),
 ]
 CHUNKS = [(128, 128), (256, 128), (256, 256), (512, 256)]
 
@@ -90,7 +95,7 @@ def with_gradient(fwd):
 def ours(seg, scale, sizes, H):
     def fwd(q, k, v):  # [B, S, H * d] as a projection leaves them
         heads = lambda x: x.reshape(*x.shape[:2], H, -1)  # noqa: E731
-        return flash_attention(heads(q), heads(k), heads(v), seg, sm_scale=scale, sizes=sizes).reshape(q.shape)
+        return flash_attention(heads(q), heads(k), heads(v), seg, sm_scale=scale, sizes=sizes).reshape(v.shape)
 
     return with_gradient(fwd)
 
@@ -119,22 +124,26 @@ def stock(seg, scale, d, H):
 
 
 def main():
-    with_stock = "--stock" in sys.argv[1:]
-    for name, B, H, S, d, scale, packed, cap in SHAPES:
+    args = sys.argv[1:]
+    with_stock = "--stock" in args
+    only = args[args.index("--only") + 1] if "--only" in args else ""
+    for name, B, H, S, d, dv, scale, packed, cap in SHAPES:
+        if only not in name:
+            continue
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (jax.random.normal(kk, (B, S, H * d), jnp.bfloat16) for kk in ks)
+        q, k, v = (jax.random.normal(kk, (B, S, H * w), jnp.bfloat16) for kk, w in zip(ks, (d, d, dv)))
         seg_np = cell_segment_ids(B, S, packed, cap)
         seg = jnp.asarray(seg_np)
-        chosen = flash_block_sizes(B, S, H, d)
-        print(f"== {name} B={B} H={H} S={S} d={d} real={float((seg_np >= 0).mean()):.3f} "
+        chosen = flash_block_sizes(B, S, H, d, 2, dv)
+        print(f"== {name} B={B} H={H} S={S} d={d} dv={dv} real={float((seg_np >= 0).mean()):.3f} "
               f"chosen={tuple(chosen)}", flush=True)
-        if with_stock:
+        if with_stock and d == dv:
             fwd, both = stock(seg, scale, d, H)
             print(f"  {'stock':>20}: fwd {cost_ms(fwd, q, k, v):7.3f}  fwd+bwd {cost_ms(both, q, k, v):7.3f} ms/layer", flush=True)
         for cq, ck in CHUNKS:
             if cq > S or ck > S:
                 continue
-            groups = sorted({g for g in (1, 2, 4, 5, 8, 10, chosen.heads) if H % g == 0})
+            groups = sorted({g for g in (1, 2, 4, 5, 8, 10, chosen.heads) if H % g == 0 and g * d % 128 == 0})
             for rows, group in [(1, 1)] * (chosen.rows > 1) + [(chosen.rows, g) for g in groups]:
                 sizes = FlashSizes(rows, group, cq, ck)
                 share = visited_share(seg_np, cq, ck)

@@ -146,11 +146,17 @@ def test_routed_feed_forward_agrees_with_the_plain_reference(impl, monkeypatch):
         np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * float(jnp.abs(w).max()), err_msg=str(path))
 
 
-def test_the_eight_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize(
+    "router",
+    [dict(moe_router_width=16), dict(moe_router_width=64, routed_scaling_factor=2.0)],
+    ids=["glm47flash_ep8: 2 of 16", "xing40_a4b_ep8: 8 of 64, scale 2"],
+)
+def test_the_eight_shares_add_up_to_the_uncut_layer(router):
     """One chip's share is a range of the router's experts. The routed parts
     of all the shares, with the shared expert counted once, are the layer that
     holds every expert."""
-    whole = kinds_config(n_routed_experts=16)
+    width = router["moe_router_width"]
+    whole = kinds_config(n_routed_experts=width, **router)
     x, _, _ = inputs(B=2, L=32, seed=3)
     module = RoutedFeedForward(whole)
     params = module.init(jax.random.PRNGKey(4), x)
@@ -158,11 +164,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     p = params["params"]
     shared = ref.swiglu(x, p["shared_experts"])
     total = shared
-    for first in range(0, 16, 2):
-        share = kinds_config(n_routed_experts=2, moe_expert_offset=first)
+    for first in range(0, width, width // 8):
+        share = kinds_config(n_routed_experts=width // 8, moe_expert_offset=first, **router)
         held = {
             **{k: v for k, v in p.items() if not k.startswith("experts_")},
-            **{k: v[first : first + 2] for k, v in p.items() if k.startswith("experts_")},
+            **{k: v[first : first + width // 8] for k, v in p.items() if k.startswith("experts_")},
         }
         total = total + RoutedFeedForward(share).apply({"params": held}, x) - shared
     np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
@@ -272,3 +278,105 @@ def test_what_the_configuration_refuses():
     assert cfg.head_dim == 12 and cfg.uses_layer_kinds and cfg.ffn_layers == ["swiglu", "routed", "routed"]
     assert StructuredTransformerConfig.from_dict(cfg.to_dict()) == cfg
     assert not StructuredTransformerConfig(hidden_size=16, head_dim=4).uses_layer_kinds
+
+
+# ------------------------------------------------------------------- YaRN, 192 / 128
+YARN = {"type": "yarn", "factor": 64, "original_max_position_embeddings": 4096, "beta_fast": 32, "beta_slow": 1,
+        "mscale": 1, "mscale_all_dim": 1}
+
+
+def _yarn_by_the_letter(d, theta, s):
+    """DeepSeek-V3's public modelling code, line for line, in float64."""
+    import math
+
+    def correction_dim(rotations):
+        return (d * math.log(s["original_max_position_embeddings"] / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(s["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(s["beta_slow"])), d - 1)
+    freq_extra = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    freq_inter = 1.0 / (s["factor"] * theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    inv_freq_mask = 1.0 - ramp
+    return freq_inter * (1 - inv_freq_mask) + freq_extra * inv_freq_mask, (low, high)
+
+
+def test_yarns_frequencies_are_the_published_codes():
+    from eventstreamgpt_tpu.ops.rope import rope_inv_freq, rope_table_scale, yarn_mscale
+
+    want, (low, high) = _yarn_by_the_letter(64, 10000.0, YARN)
+    assert (low, high) == (10, 23)  # of the 32 pairs: ten turn fast enough to stay, nine are interpolated whole
+    got = np.asarray(rope_inv_freq(64, 10000.0, YARN), np.float64)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    plain = np.asarray(rope_inv_freq(64, 10000.0), np.float64)
+    np.testing.assert_array_equal(got[:10], plain[:10])
+    np.testing.assert_allclose(got[23:], plain[23:] / 64, rtol=1e-6)
+    # no stretch: the unscaled frequencies, the unscaled tables, the unscaled softmax
+    flat = dict(YARN, factor=1)
+    np.testing.assert_allclose(np.asarray(rope_inv_freq(64, 10000.0, flat)), plain, rtol=1e-7)
+    assert rope_table_scale(flat) == 1.0 and rope_table_scale(None) == 1.0 and rope_table_scale(YARN) == 1.0
+    assert rope_table_scale(dict(YARN, mscale_all_dim=0)) == yarn_mscale(64, 1) == 0.1 * np.log(64) + 1
+
+
+def test_rope_scaling_null_computes_what_it_did_and_yarn_scales_the_softmax():
+    from eventstreamgpt_tpu.models.latent_attention import rotate, softmax_scale
+    from eventstreamgpt_tpu.ops.pallas_rope_join import rope_tables
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 3, 64))
+    positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
+    np.testing.assert_array_equal(rotate(x, positions, 1e4), rotate(x, positions, 1e4, None))
+    assert np.abs(np.asarray(rotate(x, positions, 1e4, YARN) - rotate(x, positions, 1e4))).max() > 0.1
+    # the in-place pass's tables turn by the same angles as `rotate`
+    cos, sin = rope_tables(positions, 64, 1e4, YARN)
+    turned = rotate(jnp.ones((2, 12, 64)), positions, 1e4, YARN)
+    np.testing.assert_allclose(cos[..., 64:96] - sin[..., 96:], turned[..., :32], rtol=1e-6, atol=1e-6)
+    assert softmax_scale(kinds_config()) == 12**-0.5
+    cfg = kinds_config(qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, rope_scaling=YARN)
+    assert softmax_scale(cfg) == pytest.approx(192**-0.5 * (0.1 * np.log(64) + 1) ** 2, rel=1e-12)
+
+
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_latent_attention_at_192_and_128_runs_the_flash_op_and_agrees_with_the_einsum(monkeypatch, precision):
+    """Xing4.0's widths, nope 128 + rope 64 beside a value of 128, on packed
+    rows of 128 events with YaRN: under ``pallas_flash`` the core is the flash
+    op (interpreted here; two heads of 192 are three lane tiles) and under ``einsum``
+    the plain softmax; the same parameters give the same output and gradients."""
+    from eventstreamgpt_tpu.ops import pallas_flash
+
+    widths = dict(num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, init_std=0.2,
+                  rope_scaling=YARN, precision=precision)
+    rng = np.random.default_rng(0)
+    B, S = 2, 128
+    x = jnp.asarray(rng.normal(size=(B, S, KINDS["hidden_size"])), jnp.float32)
+    segment_ids = jnp.asarray(np.sort(rng.integers(0, 4, (B, S)), axis=1), jnp.int32)
+    mask = jnp.ones((B, S), bool).at[1, S - 9 :].set(False)
+    weigh = jnp.asarray(rng.normal(size=(B, S, KINDS["hidden_size"])), jnp.float32) * mask[..., None]  # what a padded query reads out differs
+    seen = []
+    real = pallas_flash.flash_attention
+    monkeypatch.setattr(pallas_flash, "flash_attention", lambda q, k, v, *a, **kw: seen.append((q.shape, v.shape)) or real(q, k, v, *a, **kw))
+
+    def run(implementation, impl):
+        cfg = kinds_config(**widths, attention_implementation=implementation)
+        module = LatentAttention(cfg)
+        xc = x.astype(cfg.compute_dtype)
+        with monkeypatch.context() as patch:
+            patch.setenv("ESGPT_PALLAS_IMPL", impl)
+            params = module.init(jax.random.PRNGKey(1), xc, mask, segment_ids)
+            loss = lambda p, x_: jnp.sum(module.apply(p, x_, mask, segment_ids).astype(jnp.float32) * weigh)  # noqa: E731
+            return module.apply(params, xc, mask, segment_ids), jax.grad(loss, argnums=(0, 1))(params, xc)
+
+    with pytest.warns(UserWarning, match="assembling q, k and v with XLA"):
+        out_flash, grads_flash = run("pallas_flash", "pallas_interpret")
+    assert seen and set(seen) == {((B, S, 2, 192), (B, S, 2, 128))}
+    seen.clear()
+    out_plain, grads_plain = run("einsum", "xla")
+    assert not seen
+    tol = 2e-5 if precision == "fp32" else 3e-2
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    real_rows = np.asarray(mask)
+    np.testing.assert_allclose(f32(out_flash)[real_rows], f32(out_plain)[real_rows], rtol=tol, atol=tol * float(np.abs(f32(out_plain)).max()))
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads_flash)[0], jax.tree_util.tree_leaves(grads_plain)):
+        scale = float(np.abs(f32(w)).max())
+        np.testing.assert_allclose(f32(g), f32(w), rtol=tol, atol=tol * scale, err_msg=str(path))

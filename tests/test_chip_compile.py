@@ -451,11 +451,12 @@ HYBRID_BLOCKS = {
 _ITEMSIZE = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}
 
 
-def _hybrid_block_gradient(one_chip, monkeypatch, layer):
-    """Layer ``layer`` of `benchmark/configs/nemotron_twotower_ep16.json` under
-    its ``block`` remat, the gradient in its parameters and its input on
-    ``[16, 1024, 2688]`` bf16 compiled as the chip's backend traces it:
-    ``(compiled, parameters)``."""
+def _hybrid_block_gradient(one_chip, monkeypatch, layer, name="nemotron_twotower_ep16", B=16):
+    """Layer ``layer`` of `benchmark/configs/<name>.json` under its ``block``
+    remat, the gradient in its parameters and its input on ``[B, 1024,
+    hidden]`` bf16 (``hc_mult`` such planes where the configuration carries
+    residual streams) compiled as the chip's backend traces it: ``(compiled,
+    parameters)``."""
     import json
     from pathlib import Path
 
@@ -465,16 +466,19 @@ def _hybrid_block_gradient(one_chip, monkeypatch, layer):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.delenv("ESGPT_PALLAS_IMPL", raising=False)
-    model = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / "nemotron_twotower_ep16.json").read_text())
+    model = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / f"{name}.json").read_text())
     cfg = StructuredTransformerConfig(**model["config"])
     block = remat_block_cls(cfg, False, KindsBlock)(cfg, layer_id=layer)
-    B, S = 16, 1024
+    S = 1024
     x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    if cfg.hc_mult > 1:
+        x = (x,) * cfg.hc_mult
     mask = jax.ShapeDtypeStruct((B, S), jnp.bool_, sharding=one_chip)
     seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
     params = jax.eval_shape(
         lambda: block.init(
-            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), jnp.ones(mask.shape, bool), None, False, False, False,
+            jax.random.PRNGKey(0), jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), x),
+            jnp.ones(mask.shape, bool), None, False, False, False,
             jnp.zeros(seg.shape, seg.dtype),
         )
     )
@@ -484,7 +488,7 @@ def _hybrid_block_gradient(one_chip, monkeypatch, layer):
     def grad(p, x_, mask_, seg_):
         def loss(p, x_):
             out, _ = block.apply(p, x_, mask_, None, False, False, False, seg_, mutable=["routing"])[0]
-            return out.astype(jnp.float32).sum()
+            return sum(o.astype(jnp.float32).sum() for o in jax.tree_util.tree_leaves(out))
 
         return jax.grad(loss, argnums=(0, 1))(p, x_)
 
@@ -553,3 +557,36 @@ def test_scan_kernels_carry_the_scope_and_leave_no_decay_plane(one_chip, monkeyp
     assert under_the_scope and not [n for n in under_the_scope if "dot_general" in n]
     planes = [t for t in _temporaries(text) if t[1] == "f32" and t[2].endswith(",128,128") and t[0] > 100e6]
     assert planes == []
+
+
+# `xing40_a4b_ep8.pretrain_packed`'s rows: 8 of 1,024 events, four streams of 3,584
+STREAMED_BLOCKS = {
+    # kind: (layer, parameters, Mosaic calls, temporaries in GB at most)
+    "latent + swiglu": (0, 128_196_918, 3, 1.6),
+    "latent + routed": (1, 128_426_358, 15, 1.7),
+}
+
+
+@pytest.mark.parametrize("kind", list(STREAMED_BLOCKS))
+def test_streamed_blocks_at_the_cells_shapes(one_chip, monkeypatch, kind):
+    """The dense and a routed block of `benchmark/configs/xing40_a4b_ep8.json`
+    under its ``block`` remat on four streams ``[8, 1024, 3584]`` bf16, forward
+    and gradient, as the chip's backend traces them. The core at a key width
+    of 192 (as it is: two heads are three lane tiles) beside a value width of 128 is the three flash
+    kernels and nothing of ``[B, H, S, S]`` is written (33.5M elements a head
+    plane); the four streams are never one float32 array (470 MB: a first
+    version that stacked them held four, and the block compiled to 4.4 GB of
+    temporaries where this one holds 1.4-1.5); the routed block adds
+    megablox' twelve calls."""
+    layer, n_params, n_calls, temp_gb = STREAMED_BLOCKS[kind]
+    compiled, counted = _hybrid_block_gradient(one_chip, monkeypatch, layer, name="xing40_a4b_ep8", B=8)
+    assert counted == n_params
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    for kernel in ("flash_attention", "flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+        assert kernel in text
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    written = _temporaries(text)
+    assert not [t for t in written if t[2] in ("8,32,1024,1024", "8,1024,32,1024")]
+    assert max(size for size, *_ in written) < 160e6
+    assert not [t for t in written if t[1] == "f32" and t[2].endswith("1024,3584") and t[0] > 120e6], "a float32 plane of the streams"

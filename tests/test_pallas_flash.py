@@ -19,6 +19,7 @@ from eventstreamgpt_tpu.ops.pallas_flash import (
     chunk_bounds,
     flash_attention,
     flash_block_sizes,
+    lane_tile_groups,
     visited_share,
 )
 
@@ -111,6 +112,48 @@ def test_output_and_gradients_match_a_plain_softmax(B, S, H, d, scaled, sizes, l
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         assert np.isfinite(a).all(), name
         assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), (name, np.abs(a - b).max(), np.abs(b).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("d,dv,H", [(192, 128, 2), (256, 128, 1), (128, 256, 2)], ids=["192-128", "256-128", "128-256"])
+def test_a_key_width_beside_a_value_width(d, dv, H, dtype):
+    """Latent attention at 128 + 64 / 128: q and k of one width, v and the
+    output of another, on packed rows with a padding tail; two heads of 192
+    are three lane tiles. The softmax's scale is an argument of its own
+    (YaRN's ``192^-1/2 m^2``). Output and the three gradients against the
+    plain softmax; dq and dk have the key width, dv the value width."""
+    B, S = 2, 256
+    q, k, _, _, seg = case(B, S, H, d, dtype, "packed_padded", seed=d + dv)
+    _, _, v, w, _ = case(B, S, H, dv, dtype, "packed_padded", seed=d + dv + 1)
+    scale = 192**-0.5 * (0.1 * np.log(64) + 1) ** 2
+    q = (q.astype(jnp.float32) * d**0.5).astype(dtype)
+    assert flash_block_sizes(B, S, H, d, 2, dv).heads * d % 128 == 0
+
+    def ours(q, k, v):
+        return flash_attention(q, k, v, seg, sm_scale=scale, interpret=True)
+
+    out, ref = ours(q, k, v), reference(q, k, v, seg, scale)
+    assert out.dtype == dtype and out.shape == v.shape
+    grads = jax.grad(lambda *a: (ours(*a).astype(jnp.float32) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    ref_grads = jax.grad(lambda *a: (reference(*a, seg, scale) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv"), (out, *grads), (ref, *ref_grads)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all(), name
+        assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), (name, np.abs(a - b).max(), np.abs(b).max())
+
+
+def test_equal_widths_take_the_sizes_they_took():
+    """A value width given equal to the key width, or not given, changes nothing."""
+    for shape in [(16, 1024, 8, 128), (64, 256, 8, 128), (16, 1024, 20, 256), (4, 256, 4, 64)]:
+        assert flash_block_sizes(*shape, 2, shape[-1]) == flash_block_sizes(*shape)
+    assert flash_block_sizes(8, 1024, 32, 192, 2, 128) == (1, 8, 128, 128)  # xing40_a4b_ep8's core
+    # one rule: the groups `flash_block_sizes` picks from are the ones the kinds block's core asks for
+    assert lane_tile_groups(32, 192, 128) == [2, 4, 8, 16, 32] and lane_tile_groups(20, 256) == [1, 2, 4, 5, 10, 20]
+    assert lane_tile_groups(2, 64, 64) == [2] and lane_tile_groups(8, 32, 32) == [4, 8]
+    assert not lane_tile_groups(3, 192, 128) and not lane_tile_groups(4, 12, 12) and not lane_tile_groups(2, 96, 64)
+    assert flash_block_sizes(8, 1024, 8, 32).heads in lane_tile_groups(8, 32)
 
 
 @pytest.mark.parametrize("chunk_q,chunk_k", [(128, 128), (256, 128), (128, 256), (512, 512)])
