@@ -25,19 +25,28 @@ parameter tree (``q_a_proj``, ``q_a_layernorm``, ``q_b_proj``,
 * *In the flash op's layout* (`ops/pallas_rope_join.py`), where a kernel is
   taken (`ops.impl_select.resolve_impl`: a TPU backend, or
   ``$ESGPT_PALLAS_IMPL=pallas_interpret`` anywhere) and the head widths allow
-  it (``nope + rope`` whole 128-lane tiles, an even ``rope`` of at most 128,
-  ``v_head_dim == nope + rope``): ``kv_b_proj``'s kernel is sliced per head
-  into a value kernel and a key kernel padded with ``rope`` zero columns a
-  head (`split_kv_kernel`: 4.6M elements at the published widths), so
-  ``value`` and the key's nope part leave their products as ``[B, S, H * d]``
-  row-major, as ``q_b_proj`` leaves the query; one in-place Pallas pass then
-  rotates the query's rope lanes and writes the rotated shared key part into
-  every head's. Nothing between the products and the flash kernels, forward
-  or backward, is sliced, concatenated or re-laid by XLA (which otherwise
-  lays all of it out events-minor: `PERF.md` section 6, PR 31).
+  it (`ops.pallas_rope_join.rope_join_applies`: some group of heads is whole
+  128-lane tiles at either width, as the flash op asks; an even ``rope``; every
+  head's rope span, lanes ``(h (nope + rope) + nope) mod 128`` onward, inside
+  one tile, at any offset of it, and that tile no other head's; the value's
+  width is free). Both published
+  splits are such: GLM-4.7-Flash's 192 + 64 beside 256 (the span is the second
+  half of every head's second tile) and Xing4.0's 128 + 64 beside 128 (the
+  first half of a tile for the even heads, the second half of the next for
+  the odd). ``kv_b_proj``'s kernel is sliced per head into a value kernel
+  and a key kernel padded with ``rope`` zero columns a head
+  (`split_kv_kernel`: 4.6M and 2.1M elements at the published widths), so
+  ``value`` leaves its product as ``[B, S, H * v]`` and the key's nope part
+  as ``[B, S, H * d]`` row-major, as ``q_b_proj`` leaves the query; one
+  in-place Pallas pass then rotates the query's rope lanes and writes the
+  rotated shared key part into every head's. Nothing between the products and
+  the flash kernels, forward or backward, is sliced, concatenated or re-laid
+  by XLA (which otherwise lays all of it out events-minor: `PERF.md` section
+  6, PR 31 and PR 35).
 * *With XLA's slices and concatenations* (`rotate`) everywhere else: the CPU,
-  head widths such as the tests' 8 + 4. Asked for ``pallas_flash`` where a
-  kernel is taken and the widths refuse, the layer warns, as `_core` does.
+  head widths such as the tests' 8 + 4 or a rope span that crosses a tile
+  boundary (96 + 64). Asked for ``pallas_flash`` where a kernel is taken and
+  the widths refuse, the layer warns, as `_core` does.
 """
 
 from __future__ import annotations
@@ -192,7 +201,8 @@ class LatentAttention(nn.Module):
                     ),
                     q, jnp.dot(c_kv, w_key), kv_a[..., cfg.kv_lora_rank :], positions,
                 )
-                query, key, value = (a.reshape(B, S, H, dv) for a in (query, key, value))
+                query, key = query.reshape(B, S, H, dn + dr), key.reshape(B, S, H, dn + dr)
+                value = value.reshape(B, S, H, dv)
 
         with scope("attn_global"):
             out = self._core(query, key, value, attention_mask, segment_ids)
@@ -211,15 +221,18 @@ class LatentAttention(nn.Module):
 
         cfg = self.config
         impl = resolve_impl(None, "latent attention's assembly")
-        if impl == "xla" or rope_join_applies(cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim):
+        if impl == "xla" or rope_join_applies(
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.num_attention_heads
+        ):
             return impl
         if cfg.attention_implementation == "pallas_flash":
             import warnings
 
             warnings.warn(
                 "latent attention is assembling q, k and v with XLA's slices and concatenations: head widths "
-                f"nope {cfg.qk_nope_head_dim}, rope {cfg.qk_rope_head_dim}, value {cfg.v_head_dim} (the in-place "
-                "pass needs nope + rope a multiple of 128, an even rope of at most 128 and value == nope + rope)",
+                f"nope {cfg.qk_nope_head_dim}, rope {cfg.qk_rope_head_dim}, value {cfg.v_head_dim} on "
+                f"{cfg.num_attention_heads} heads (the in-place pass needs a group of heads that is whole 128-lane "
+                "tiles at either width, an even rope, and every head's rope lanes inside one tile of their own)",
                 stacklevel=2,
             )
         return "xla"

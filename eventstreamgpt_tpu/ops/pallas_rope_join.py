@@ -1,30 +1,42 @@
 """Pallas TPU pass that finishes latent attention's query and key in the flash op's layout.
 
-`models/latent_attention.py` hands `ops/pallas_flash.py` q, k and v as ``[B, S,
-H * d]`` row-major, ``d = nope + rope`` a whole number of 128-lane tiles. The
-projections leave all but a head's rope lanes (its last ``rope``) final: the
-query's nope part as ``q_b_proj`` writes it, the key's as the product of the
-key columns of ``kv_b_proj`` (zero-padded to ``d`` a head). What is left is
-RoPE on the query's rope lanes and the one rotated ``k_r`` written into every
-head's rope lanes of the key, and XLA does neither without re-laying the whole
-arrays events-minor (a head's halves are ``rope / 2`` lanes wide). So it is
-one Mosaic pass, which pins its operands row-major as the flash op does:
+`models/latent_attention.py` hands `ops/pallas_flash.py` q and k as ``[B, S,
+H * d]`` row-major, ``d = nope + rope``, and v as ``[B, S, H * v]``, whatever
+the value's width. The projections leave all but a head's rope lanes (its last
+``rope``) final: the query's nope part as ``q_b_proj`` writes it, the key's as
+the product of the key columns of ``kv_b_proj`` (zero-padded to ``d`` a head).
+What is left is RoPE on the query's rope lanes and the one rotated ``k_r``
+written into every head's rope lanes of the key, and XLA does neither without
+re-laying the whole arrays events-minor (a head's halves are ``rope / 2``
+lanes wide). So it is one Mosaic pass, which pins its operands row-major as
+the flash op does:
 
-* **In place.** A grid step takes one head's last lane tile of a block of rows,
-  ``[rows, 128]``, of the query and of the key; the outputs alias the inputs
+* **Where a head's rope lanes are.** Head ``h`` has them at lanes ``[(h d +
+  nope) mod 128, ... + rope)`` of tile ``(h d + nope) div 128``: a span inside
+  one 128-lane tile, at any offset of it (`rope_join_applies`). GLM-4.7-Flash's
+  192 + 64 puts it at lanes 64-127 of every head's second tile; Xing4.0's
+  128 + 64 at lanes 0-63 of tile ``3 j + 1`` for head ``2 j`` and at lanes
+  64-127 of tile ``3 j + 2`` for head ``2 j + 1`` (two heads are three tiles).
+* **In place.** A grid step takes that one tile of a block of rows, ``[rows,
+  128]``, of the query and of the key; the outputs alias the inputs
   (``input_output_aliases``), so every other tile stays as the products wrote
-  it and the pass moves ``2 * rope / d`` of the two arrays.
-* **The rotation inside a tile.** Rotate-half pairs lane ``i`` of the rope
-  lanes with lane ``i + rope / 2``: two lane rotations of the tile and a
-  select. The arithmetic is `models.latent_attention.rotate`'s: float32,
-  ``a cos - b sin`` and ``b cos + a sin``, one rounding to the operands' dtype.
-  Cosine and sine come as tile-wide tables (1 and 0 on the lanes that are not
-  rotated, the sine signed by half), made once a call by XLA's own ``cos`` and
-  ``sin`` from the positions and fetched once a block of rows for all heads.
+  it and the pass moves ``2 * 128 / d`` of the two arrays at most.
+* **The rotation inside a tile.** Rotate-half pairs lane ``i`` of a span's
+  first half with lane ``i + rope / 2``: two lane rotations of the tile and a
+  select, both inside the tile wherever the span starts. The arithmetic is
+  `models.latent_attention.rotate`'s: float32, ``a cos - b sin`` and ``b cos +
+  a sin``, one rounding to the operands' dtype. What all heads of a block of
+  rows share comes as one tile that holds it at every span the heads use
+  (spans at different offsets do not overlap), fetched once a block of rows:
+  the cosine and the sine signed by half (1 and 0 between the spans), made
+  once a call by XLA's own ``cos`` and ``sin`` from the positions, and
+  ``k_r``, rotated once a block of rows. Only the mask that says which lanes
+  are this head's follows the grid.
 * **The transpose** is the same pass with the sine negated on ``dquery``;
   ``dk_r`` is the rotated sum of ``dkey``'s rope lanes over the heads, summed
-  in float32, and those lanes of ``dkey`` are then zeroed (the forward did not
-  read them), again in place.
+  in float32 where they lie and brought to one offset before the rotation, and
+  those lanes of ``dkey`` are then zeroed (the forward did not read them),
+  again in place.
 """
 
 from __future__ import annotations
@@ -38,95 +50,129 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.scopes import scope
 from .impl_select import LANE as LANES
+from .pallas_flash import lane_tile_groups
 from .rope import rope_cos_sin
 
 __all__ = ["rope_join", "rope_join_applies", "rope_tables"]
 
-# Rows a grid step takes. On a v5e at [16,384, 20 * 256] bf16, forward / transpose in ms: 256 rows 0.97 / 1.02,
-# 512 0.75 / 0.77, 1,024 0.64 / 0.65, 2,048 0.60 / 0.60, 4,096 0.59 / 0.59 (PERF.md section 6, PR 31).
+# Rows a grid step takes. On a v5e, forward / transpose in ms (back-to-back donated calls), at `glm47flash_ep8`'s
+# [16,384, 20 * 256] bf16 (one offset, lanes 64-127): 512 rows 0.73 / 0.75, 1,024 0.62 / 0.62, 2,048 0.58 / 0.58,
+# 4,096 0.57 / 0.57, 8,192 0.56 / 0.57, the parent's kernel with its static mask the same to 0.3% at every size; at
+# `xing40_a4b_ep8`'s [8,192, 32 * 192] (offsets 0 and 64 by head parity, the mask from `program_id`): 512 rows
+# 0.60 / 0.61, 1,024 0.49 / 0.50, 2,048 0.45 / 0.45, 4,096 0.44 / 0.45, 8,192 0.45 / 0.45: 268 MB a call at 600 GB/s,
+# 0.73 of the HBM peak, so the mask that follows the grid costs nothing and no static variant by head parity was
+# built (PERF.md section 6, PR 35; PR 31 read 0.60 / 0.60 at 2,048).
 ROWS = 2048
 
 
-def rope_join_applies(nope: int, rope: int, v_head_dim: int) -> bool:
-    """Whether the pass can finish heads of these widths: a head is whole lane
-    tiles, its rope lanes lie in the last one and pair up, and the value is as
-    wide as the key (the flash op's condition)."""
-    return (nope + rope) % LANES == 0 and rope % 2 == 0 and 0 < rope <= LANES and v_head_dim == nope + rope
+def _span_offsets(heads: int, nope: int, rope: int) -> list[int]:
+    """The lane offsets inside a tile at which the heads' rope spans start."""
+    return sorted({(h * (nope + rope) + nope) % LANES for h in range(heads)})
 
 
-def rope_tables(positions, rope: int, theta: float, scaling: dict | None = None):
-    """``(cos, sin)`` float32 ``[B, S, 128]`` for a head's last lane tile:
-    `models.latent_attention.rotate`'s angles (its frequencies and, under a
-    ``yarn`` ``scaling``, its table scale) on the last ``rope`` lanes, the sine
-    negated on their first half; 1 and 0 on the lanes before them."""
+def rope_join_applies(nope: int, rope: int, v_head_dim: int, heads: int) -> bool:
+    """Whether the pass can finish ``heads`` heads of these widths: some group
+    of heads is whole lane tiles (the flash op's rule, `lane_tile_groups`),
+    the rope lanes pair up, and every head's rope span lies inside one tile
+    (spans at different offsets then never overlap: a group's offsets lie
+    ``gcd(d, 128)`` apart up to the tile's end, so one table serves all), a
+    tile of its own (a grid step rewrites one head's)."""
+    if rope <= 0 or rope % 2 or not lane_tile_groups(heads, nope + rope, v_head_dim):
+        return False
+    starts = [h * (nope + rope) + nope for h in range(heads)]
+    return all(s % LANES + rope <= LANES for s in starts) and len({s // LANES for s in starts}) == heads
+
+
+def _at_the_spans(pieces, offsets, fill):
+    """A ``[..., 128]`` tile that holds ``pieces`` (arrays ``[..., n]``, side
+    by side one span wide) from each lane of ``offsets`` on and ``fill``
+    between the spans."""
+    span = sum(p.shape[-1] for p in pieces)
+    gaps = [b - a for a, b in zip([0, *(o + span for o in offsets)], [*offsets, LANES])]  # before each span, after the last
+    out = []
+    for gap, held in zip(gaps, [pieces] * len(offsets) + [[]]):
+        out += [jnp.full(pieces[0].shape[:-1] + (gap,), fill, pieces[0].dtype)] * (gap > 0) + held
+    return jnp.concatenate(out, axis=-1)
+
+
+def rope_tables(positions, rope: int, theta: float, scaling: dict | None = None, offsets=None):
+    """``(cos, sin)`` float32 ``[B, S, 128]`` for the tiles that hold the
+    heads' rope lanes: `models.latent_attention.rotate`'s angles (its
+    frequencies and, under a ``yarn`` ``scaling``, its table scale) on the
+    ``rope`` lanes from each of ``offsets`` on (a tile's last ``rope`` where
+    not given), the sine negated on a span's first half; 1 and 0 on the lanes
+    between the spans."""
     cos, sin = rope_cos_sin(positions, rope, theta, scaling)  # (B, S, rope / 2)
-    lead = positions.shape + (LANES - rope,)
-    return (
-        jnp.concatenate([jnp.ones(lead, jnp.float32), cos, cos], axis=-1),
-        jnp.concatenate([jnp.zeros(lead, jnp.float32), -sin, sin], axis=-1),
-    )
+    offsets = (LANES - rope,) if offsets is None else offsets
+    return _at_the_spans([cos, cos], offsets, 1.0), _at_the_spans([-sin, sin], offsets, 0.0)
 
 
-def _rope_lanes(shape, rope):
-    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) >= LANES - rope
+def _lanes(shape, width, nope, rope, offsets):
+    """Two masks over a ``[rows, 128]`` tile: the rope lanes of this grid
+    step's head, and the first halves of every span the tile can hold."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    start = jax.lax.rem(pl.program_id(1) * width + nope, LANES)
+    first_halves = functools.reduce(jnp.logical_or, [(lane >= o) & (lane < o + rope // 2) for o in offsets])
+    return (lane >= start) & (lane < start + rope), first_halves
 
 
-def _turn(x, cos, sin, rope):
-    """The tile ``x`` [rows, 128] float32 with each rope lane's ``x cos +
-    partner sin`` (``sin`` signed by half): lane ``i`` of the first half pairs
-    with ``i + rope / 2``, of the second with ``i - rope / 2``."""
+def _turn(x, cos, sin, first_halves, rope):
+    """The tile ``x`` [rows, 128] float32 with each span lane's ``x cos +
+    partner sin`` (``sin`` signed by half): lane ``i`` of a first half pairs
+    with ``i + rope / 2``, of a second with ``i - rope / 2``."""
     half = rope // 2
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    partner = jnp.where(lane < LANES - half, pltpu.roll(x, LANES - half, 1), pltpu.roll(x, half, 1))
-    return x * cos + partner * sin
+    return x * cos + jnp.where(first_halves, pltpu.roll(x, LANES - half, 1), pltpu.roll(x, half, 1)) * sin
 
 
-def _fwd_kernel(cos_ref, sin_ref, kr_ref, q_ref, k_ref, query_ref, key_ref, kr_turned_ref, *, rope):
-    rope_lanes = _rope_lanes(q_ref.shape, rope)
-    cos, sin = cos_ref[...], sin_ref[...]
+def _fwd_kernel(cos_ref, sin_ref, kr_ref, q_ref, k_ref, query_ref, key_ref, kr_turned_ref, *, width, nope, rope, offsets):
+    rope_lanes, first_halves = _lanes(q_ref.shape, width, nope, rope, offsets)
+    turn = functools.partial(_turn, cos=cos_ref[...], sin=sin_ref[...], first_halves=first_halves, rope=rope)
 
     @pl.when(pl.program_id(1) == 0)
     def _():  # the shared key part, rotated once a block of rows for all heads
-        kr_turned_ref[...] = _turn(kr_ref[...].astype(jnp.float32), cos, sin, rope).astype(kr_turned_ref.dtype)
+        kr_turned_ref[...] = turn(kr_ref[...].astype(jnp.float32)).astype(kr_turned_ref.dtype)
 
     q = q_ref[...]
-    query_ref[...] = jnp.where(rope_lanes, _turn(q.astype(jnp.float32), cos, sin, rope).astype(q.dtype), q)
+    query_ref[...] = jnp.where(rope_lanes, turn(q.astype(jnp.float32)).astype(q.dtype), q)
     key_ref[...] = jnp.where(rope_lanes, kr_turned_ref[...], k_ref[...])
 
 
-def _bwd_kernel(cos_ref, sin_ref, dquery_ref, dkey_ref, dq_ref, dk_ref, dkr_ref, sum_ref, *, rope):
+def _bwd_kernel(cos_ref, sin_ref, dquery_ref, dkey_ref, dq_ref, dk_ref, dkr_ref, sum_ref, *, width, nope, rope, offsets):
     head, heads = pl.program_id(1), pl.num_programs(1)
-    rope_lanes = _rope_lanes(dq_ref.shape, rope)
-    cos, sin = cos_ref[...], -sin_ref[...]
+    rope_lanes, first_halves = _lanes(dq_ref.shape, width, nope, rope, offsets)
+    turn = functools.partial(_turn, cos=cos_ref[...], sin=-sin_ref[...], first_halves=first_halves, rope=rope)
     dquery = dquery_ref[...]
-    dq_ref[...] = jnp.where(rope_lanes, _turn(dquery.astype(jnp.float32), cos, sin, rope).astype(dquery.dtype), dquery)
+    dq_ref[...] = jnp.where(rope_lanes, turn(dquery.astype(jnp.float32)).astype(dquery.dtype), dquery)
 
     @pl.when(head == 0)
     def _():
         sum_ref[...] = jnp.zeros_like(sum_ref)
 
     dkey = dkey_ref[...]
-    sum_ref[...] += dkey.astype(jnp.float32)
-    dk_ref[...] = jnp.where(rope_lanes, jnp.zeros_like(dkey), dkey)
+    nothing = jnp.zeros_like(dkey)
+    sum_ref[...] += jnp.where(rope_lanes, dkey, nothing).astype(jnp.float32)
+    dk_ref[...] = jnp.where(rope_lanes, nothing, dkey)
 
     @pl.when(head == heads - 1)
-    def _():  # only the rope lanes are read back
-        dkr_ref[...] = _turn(sum_ref[...], cos, sin, rope).astype(dkr_ref.dtype)
+    def _():  # each offset's sum brought to the first head's; only that span is read back
+        sums = sum_ref[...]
+        total = sum([pltpu.roll(sums, (nope - o) % LANES, 1) for o in offsets if o != nope % LANES], sums)
+        dkr_ref[...] = turn(total).astype(dkr_ref.dtype)
 
 
-def _call(kernel, name, like, heads, shared_ins, shared_outs, scratch_dtype, interpret):
-    """One pass in place over the heads' last tiles of two arrays ``like``
-    ``[n_rows, heads * d]``: grid ``(row block, head)``. Operands:
-    ``shared_ins`` ``[n_rows, 128]`` arrays whose block a row block's heads
-    share (fetched once), then the two; outputs: the two, aliased, then
-    ``shared_outs`` ``[n_rows, 128]`` arrays; one ``[rows, 128]`` scratch."""
-    n_rows, total = like.shape
+def _call(kernel, name, like, heads, nope, rope, shared_ins, shared_outs, scratch_dtype, interpret):
+    """One pass in place over the tiles that hold the heads' rope lanes, of
+    two arrays ``like`` ``[n_rows, heads * (nope + rope)]``: grid ``(row
+    block, head)``. Operands: ``shared_ins`` ``[n_rows, 128]`` arrays whose
+    block a row block's heads share (fetched once), then the two; outputs: the
+    two, aliased, then ``shared_outs`` ``[n_rows, 128]`` arrays; one ``[rows,
+    128]`` scratch."""
+    n_rows, width = like.shape[0], nope + rope
     rows = next(r for r in range(min(ROWS, n_rows), 0, -1) if n_rows % r == 0 and (r % 16 == 0 or r == n_rows))
-    tiles = total // heads // LANES
     shared = pl.BlockSpec((rows, LANES), lambda i, h: (i, 0))
-    of_head = pl.BlockSpec((rows, LANES), lambda i, h: (i, h * tiles + tiles - 1))
+    of_head = pl.BlockSpec((rows, LANES), lambda i, h: (i, (h * width + nope) // LANES))
     return pl.pallas_call(
-        kernel,
+        functools.partial(kernel, width=width, nope=nope, rope=rope, offsets=_span_offsets(heads, nope, rope)),
         grid=(n_rows // rows, heads),
         in_specs=[shared] * shared_ins + [of_head] * 2,
         out_specs=[of_head] * 2 + [shared] * shared_outs,
@@ -151,8 +197,9 @@ def _flat(x):
 @functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _forward(q, k, k_r, cos, sin, heads, rope, interpret):
     like = jax.ShapeDtypeStruct(_flat(q).shape, q.dtype)
-    k_r = jnp.pad(_flat(k_r), ((0, 0), (LANES - rope, 0)))  # at the lanes it takes in a head's last tile
-    call = _call(functools.partial(_fwd_kernel, rope=rope), "rope_join", like, heads, 3, 0, q.dtype, interpret)
+    nope = like.shape[1] // heads - rope
+    k_r = _at_the_spans([_flat(k_r)], _span_offsets(heads, nope, rope), 0)  # at the lanes it takes in every span
+    call = _call(_fwd_kernel, "rope_join", like, heads, nope, rope, 3, 0, q.dtype, interpret)
     query, key = call(_flat(cos), _flat(sin), k_r, _flat(q), _flat(k))
     return query.reshape(q.shape), key.reshape(q.shape)
 
@@ -160,11 +207,11 @@ def _forward(q, k, k_r, cos, sin, heads, rope, interpret):
 @functools.partial(jax.jit, static_argnums=(4, 5, 6))
 def _backward(cos, sin, dquery, dkey, heads, rope, interpret):
     like = jax.ShapeDtypeStruct(_flat(dquery).shape, dquery.dtype)
-    call = _call(
-        functools.partial(_bwd_kernel, rope=rope), "rope_join_transpose", like, heads, 2, 1, jnp.float32, interpret
-    )
+    nope = like.shape[1] // heads - rope
+    call = _call(_bwd_kernel, "rope_join_transpose", like, heads, nope, rope, 2, 1, jnp.float32, interpret)
     dq, dk, dk_r = call(_flat(cos), _flat(sin), _flat(dquery), _flat(dkey))
-    return dq.reshape(dquery.shape), dk.reshape(dquery.shape), dk_r[:, LANES - rope :].reshape(*dquery.shape[:2], rope)
+    first = nope % LANES  # the first head's span
+    return dq.reshape(dquery.shape), dk.reshape(dquery.shape), dk_r[:, first : first + rope].reshape(*dquery.shape[:2], rope)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -207,5 +254,5 @@ def rope_join(
     ``k_nope`` and ``k_r``; the two big operands are consumed (aliased).
     """
     with scope("attn_latent"):
-        cos, sin = rope_tables(positions, rope, theta, scaling)
+        cos, sin = rope_tables(positions, rope, theta, scaling, _span_offsets(heads, q.shape[-1] // heads - rope, rope))
     return _rope_join(q, k_nope, k_r, cos, sin, heads, rope, interpret)

@@ -49,15 +49,27 @@ def test_latent_attention_agrees_with_the_plain_reference(packed):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-# Head widths the in-place assembly takes (`ops/pallas_rope_join.py`): a head of two lane tiles, the published split.
-ALIGNED = dict(num_attention_heads=2, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256, init_std=0.2)
+# Head widths the in-place assembly takes (`ops/pallas_rope_join.py`), the two published splits: GLM-4.7-Flash's, a
+# head of two lane tiles whose rope lanes end it, and Xing4.0's, two heads of three tiles whose rope lanes start at lane
+# 0 of a tile (the even heads) and at lane 64 of the next (the odd), beside a narrower value, with and without YaRN;
+# and a split no model publishes, whose spans lie at lanes 16-63 and 80-127 by head parity, with lanes between them.
+ALIGNED = {
+    "192 + 64 / 256": dict(num_attention_heads=2, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256),
+    "128 + 64 / 128": dict(num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
+    "144 + 48 / 64": dict(num_attention_heads=2, qk_nope_head_dim=144, qk_rope_head_dim=48, v_head_dim=64),
+    "128 + 64 / 128, four heads, yarn": dict(
+        num_attention_heads=4, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling={"type": "yarn", "factor": 64, "original_max_position_embeddings": 4096, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1},
+    ),
+}
 
 
-def latent_layer(monkeypatch, impl, precision, packed):
+def latent_layer(monkeypatch, impl, precision, packed, widths):
     """One latent-attention layer at lane-aligned widths under ``impl``: its
     parameters, output, the ``(query, key, value)`` its core was handed, and
     the gradients of ``x`` and of the parameters."""
-    cfg = kinds_config(**ALIGNED, precision=precision)
+    cfg = kinds_config(**ALIGNED[widths], init_std=0.2, precision=precision)
     x, mask, segment_ids = inputs()
     x, segment_ids = x.astype(cfg.compute_dtype), segment_ids if packed else None
     seen = []
@@ -76,24 +88,32 @@ def latent_layer(monkeypatch, impl, precision, packed):
 
 @pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
 @pytest.mark.parametrize("precision", ["fp32", "bf16"])
-def test_assembled_latent_attention_agrees_with_the_present_formulation(monkeypatch, precision, packed):
+@pytest.mark.parametrize("widths", list(ALIGNED))
+def test_assembled_latent_attention_agrees_with_the_present_formulation(monkeypatch, widths, precision, packed):
     """q, k and v assembled in the flash op's layout (the key/value split on
     the weights, RoPE and the join as one Pallas pass, interpreted here)
     against the slices, `rotate` and concatenations: the same parameter tree
-    from the same key; ``query``, ``key`` and ``value`` to one ulp of the
-    compute dtype; the output and every gradient within this file's
-    tolerances in float32, within two ulps in bfloat16."""
-    p_new, out_new, qkv_new, grads_new = latent_layer(monkeypatch, "pallas_interpret", precision, packed)
-    p_old, out_old, qkv_old, grads_old = latent_layer(monkeypatch, "xla", precision, packed)
+    from the same key; ``query``, ``key`` and ``value`` bit for bit; the
+    output and every gradient (the shared ``k_r``'s through
+    ``kv_a_proj_with_mqa`` among them) within this file's tolerances in
+    float32, within two ulps in bfloat16."""
+    p_new, out_new, qkv_new, grads_new = latent_layer(monkeypatch, "pallas_interpret", precision, packed, widths)
+    p_old, out_old, qkv_old, grads_old = latent_layer(monkeypatch, "xla", precision, packed, widths)
     assert jax.tree_util.tree_structure(p_new) == jax.tree_util.tree_structure(p_old)
     assert len(jax.tree_util.tree_leaves(p_new)) == 7
     for a, b in zip(jax.tree_util.tree_leaves(p_new), jax.tree_util.tree_leaves(p_old)):
         assert a.dtype == b.dtype and np.array_equal(a, b)
-    ulp = float(jnp.finfo(qkv_new[0].dtype).eps)
     f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    ulp = float(jnp.finfo(qkv_new[0].dtype).eps)
+    w = ALIGNED[widths]
+    key_width = w["qk_nope_head_dim"] + w["qk_rope_head_dim"]
     for name, a, b in zip(("query", "key", "value"), qkv_new, qkv_old):
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        np.testing.assert_allclose(f32(a), f32(b), rtol=ulp, atol=1e-6, err_msg=name)
+        assert a.shape == b.shape == (2, 12, w["num_attention_heads"], w["v_head_dim"] if name == "value" else key_width), name
+        assert a.dtype == b.dtype, name
+        if precision == "bf16":  # one rounding of the same float32 arithmetic: the forward, and so the routing, is the parent's
+            np.testing.assert_array_equal(f32(a), f32(b), err_msg=name)
+        else:  # the CPU contracts `a cos - b sin` as written and `a cos + b (-sin)` into different fused multiply-adds
+            np.testing.assert_allclose(f32(a), f32(b), rtol=ulp, atol=1e-6, err_msg=name)
     # float32: this file's tolerances. bfloat16: the output to an ulp (it reads equal), a gradient to two
     # (the pass sums dkey's rope lanes over the heads in float32, the present formulation rounds the sum).
     out_tol, grad_tol = (2e-5, 1e-3) if precision == "fp32" else (ulp, 2 * ulp)
@@ -105,6 +125,26 @@ def test_assembled_latent_attention_agrees_with_the_present_formulation(monkeypa
         scale = float(np.abs(f32(w)).max())
         assert scale > 0, path
         np.testing.assert_allclose(f32(g), f32(w), rtol=grad_tol, atol=grad_tol * scale, err_msg=str(path))
+
+
+@pytest.mark.parametrize(
+    "nope, rope, value, heads, applies",
+    [
+        (192, 64, 256, 20, True),  # GLM-4.7-Flash: the span ends every head's second tile
+        (128, 64, 128, 32, True),  # Xing4.0: lanes 0-63 of a tile, lanes 64-127 of the next
+        (96, 32, 64, 20, True),  # one tile a head, a value of half a tile: two heads a group
+        (8, 4, 12, 4, False),  # this file's widths: no group of heads is whole tiles
+        (128, 64, 128, 3, False),  # three heads of 192 are not
+        (193, 63, 256, 20, False),  # an odd rope has no halves
+        (96, 64, 128, 20, False),  # four heads are five tiles, and the first head's span crosses lane 128
+        (144, 48, 64, 2, True),  # spans at lanes 16-63 and 80-127, by head parity
+        (16, 48, 64, 4, False),  # the same spans, but two heads' in one tile: a grid step rewrites one head's
+    ],
+)
+def test_which_head_widths_the_in_place_assembly_takes(nope, rope, value, heads, applies):
+    from eventstreamgpt_tpu.ops.pallas_rope_join import rope_join_applies
+
+    assert rope_join_applies(nope, rope, value, heads) is applies
 
 
 def test_the_split_kernels_gradient_lands_in_the_one_leaf():
@@ -343,6 +383,8 @@ def test_latent_attention_at_192_and_128_runs_the_flash_op_and_agrees_with_the_e
     rows of 128 events with YaRN: under ``pallas_flash`` the core is the flash
     op (interpreted here; two heads of 192 are three lane tiles) and under ``einsum``
     the plain softmax; the same parameters give the same output and gradients."""
+    import warnings
+
     from eventstreamgpt_tpu.ops import pallas_flash
 
     widths = dict(num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, init_std=0.2,
@@ -367,7 +409,8 @@ def test_latent_attention_at_192_and_128_runs_the_flash_op_and_agrees_with_the_e
             loss = lambda p, x_: jnp.sum(module.apply(p, x_, mask, segment_ids).astype(jnp.float32) * weigh)  # noqa: E731
             return module.apply(params, xc, mask, segment_ids), jax.grad(loss, argnums=(0, 1))(params, xc)
 
-    with pytest.warns(UserWarning, match="assembling q, k and v with XLA"):
+    with warnings.catch_warnings():  # the widths engage the in-place assembly: nothing falls back, nothing warns
+        warnings.simplefilter("error")
         out_flash, grads_flash = run("pallas_flash", "pallas_interpret")
     assert seen and set(seen) == {((B, S, 2, 192), (B, S, 2, 128))}
     seen.clear()

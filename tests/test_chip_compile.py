@@ -14,6 +14,8 @@ in a ``skipif`` or a ``parametrize``): only the xdist worker that is given
 this file loads libtpu. Keep every such test in THIS file.
 """
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -310,23 +312,29 @@ def test_held_experts_at_the_cells_shapes(one_chip, monkeypatch):
     assert " while(" in text  # the buffer is walked as far as the held pairs reach
 
 
-# `glm47flash_ep8.pretrain_packed`'s latent attention: 16 rows of 1,024 events, 20 heads of 192 + 64 (value 256)
-MLA_SHAPE = dict(B=16, S=1024, H=20, nope=192, rope=64)
+# The latent-attention cells' shapes: `glm47flash_ep8.pretrain_packed`, 16 rows of 1,024 events, 20 heads of 192 + 64
+# (value 256, a head's rope lanes the second half of its second tile); `xing40_a4b_ep8.pretrain_packed`, 8 rows, 32
+# heads of 128 + 64 (value 128, the rope lanes at lane 0 of a tile for the even heads and at lane 64 for the odd)
+MLA_SHAPES = {
+    "glm47flash_ep8": dict(B=16, S=1024, H=20, nope=192, rope=64),
+    "xing40_a4b_ep8": dict(B=8, S=1024, H=32, nope=128, rope=64),
+}
 
 
-def test_rope_join_and_its_transpose_at_the_cells_shapes(one_chip):
+@pytest.mark.parametrize("cell", list(MLA_SHAPES))
+def test_rope_join_and_its_transpose_at_the_cells_shapes(one_chip, cell):
     """`ops.pallas_rope_join.rope_join` (latent attention's RoPE and
-    nope/rope join, in place on ``[16, 1024, 20 * 256]`` bf16) and its
-    transpose compile for the chip, and both Mosaic calls carry
-    ``es.attn_latent``: JAX traces a custom_vjp's rules without the caller's
-    name stack, so a rule that did not name its scope would leave
+    nope/rope join, in place on ``[16, 1024, 20 * 256]`` and on ``[8, 1024,
+    32 * 192]`` bf16) and its transpose compile for the chip, and both Mosaic
+    calls carry ``es.attn_latent``: JAX traces a custom_vjp's rules without
+    the caller's name stack, so a rule that did not name its scope would leave
     ``scoped_pct.train`` short of the pass's time."""
     import re
 
     from benchmark.harness.scopes import scope_of
     from eventstreamgpt_tpu.ops.pallas_rope_join import rope_join
 
-    B, S, H, nope, rope = MLA_SHAPE.values()
+    B, S, H, nope, rope = MLA_SHAPES[cell].values()
     wide = jax.ShapeDtypeStruct((B, S, H * (nope + rope)), jnp.bfloat16, sharding=one_chip)
     k_r = jax.ShapeDtypeStruct((B, S, rope), jnp.bfloat16, sharding=one_chip)
     positions = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
@@ -347,10 +355,10 @@ def test_rope_join_and_its_transpose_at_the_cells_shapes(one_chip):
     assert text.count("output_to_operand_aliasing") == 2
 
 
-def _latent_layer(one_chip, monkeypatch, **widths):
-    """One `LatentAttention` layer of `benchmark/configs/glm47flash_ep8.json`
-    as the chip's backend traces it (the module asks `jax.default_backend`),
-    with shapes for its forward + gradient at the cell's rows."""
+def _latent_layer(one_chip, monkeypatch, name="glm47flash_ep8", **widths):
+    """One `LatentAttention` layer of `benchmark/configs/<name>.json` as the
+    chip's backend traces it (the module asks `jax.default_backend`), with
+    shapes for its forward + gradient at the cell's rows."""
     import json
     from pathlib import Path
 
@@ -359,9 +367,9 @@ def _latent_layer(one_chip, monkeypatch, **widths):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.delenv("ESGPT_PALLAS_IMPL", raising=False)
-    cell = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / "glm47flash_ep8.json").read_text())
+    cell = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / f"{name}.json").read_text())
     module = LatentAttention(StructuredTransformerConfig(**{**cell["config"], **widths}))
-    B, S = MLA_SHAPE["B"], MLA_SHAPE["S"]
+    B, S = MLA_SHAPES[name]["B"], MLA_SHAPES[name]["S"]
     x = jax.ShapeDtypeStruct((B, S, module.config.hidden_size), jnp.bfloat16, sharding=one_chip)
     seg = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
     params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), None, jnp.zeros(seg.shape, seg.dtype)))
@@ -373,42 +381,47 @@ def _latent_layer(one_chip, monkeypatch, **widths):
     return grad, (params, x, seg)
 
 
-def test_latent_attention_layer_holds_no_relayout_between_its_products_and_the_flash_kernels(one_chip, monkeypatch):
+@pytest.mark.parametrize("name, over", [("glm47flash_ep8", 100e6), ("xing40_a4b_ep8", 60e6)])
+def test_latent_attention_layer_holds_no_relayout_between_its_products_and_the_flash_kernels(one_chip, monkeypatch, name, over):
     """The cell's shapes engage the assembly, and then nothing of q, k, v or
-    their gradients (168 MB each) is sliced, concatenated or re-laid by XLA
-    between the four latent products and the three flash kernels: the entry
-    computation holds no ``copy``, ``slice``, ``pad_*`` or ``select_*`` over
-    100 MB (with XLA's own assembly it holds 7 copies and 4 slices). One is
-    left that is not latent attention's: the flash op's ``di`` (`ops/
+    their gradients (168 MB each in `glm47flash_ep8` at 16 rows; 101 and 67 MB
+    in `xing40_a4b_ep8` at 8) is sliced, concatenated, padded or re-laid by
+    XLA between the latent products and the three flash kernels: the entry
+    computation holds no ``copy``, ``slice``, ``split``, ``pad_*``,
+    ``select_*``, ``broadcast_*`` or ``concatenate`` of that size (with XLA's
+    own assembly `glm47flash_ep8`'s layer holds 7 copies and 4 slices, and
+    `xing40_a4b_ep8`'s sixteen such arrays over 30 MB, 1.64 GB). One is left
+    that is not latent attention's: the flash op's ``di`` (`ops/
     pallas_flash.py::_backward`), whose float32 ``o * do`` XLA re-lays
     events-minor before it reduces it."""
     import re
 
     import numpy as np
 
-    grad, args = _latent_layer(one_chip, monkeypatch)
-    text = _compile(grad, *args)
+    grad, args = _latent_layer(one_chip, monkeypatch, name)
+    with warnings.catch_warnings():  # and the layer says nothing of a fallback
+        warnings.simplefilter("error")
+        text = _compile(grad, *args)
     assert "rope_join" in text and "rope_join_transpose" in text
     entry = text[text.index("ENTRY") :]
     itemsize = {"bf16": 2, "f32": 4, "s32": 4}
     moved = []
-    for name, dtype, dims, op_name in re.findall(
-        r'%((?:copy|slice|split|pad_|select_)[\w.\-]*) = (\w+)\[([\d,]+)\][^\n]*?op_name="([^"]*)"', entry
+    for op, dtype, dims, op_name in re.findall(
+        r'%((?:copy|slice|split|pad_|select_|broadcast_|concatenate)[\w.\-]*) = (\w+)\[([\d,]+)\][^\n]*?op_name="([^"]*)"', entry
     ):
-        if np.prod([int(n) for n in dims.split(",")]) * itemsize.get(dtype, 4) > 100e6:
-            moved.append((name, f"{dtype}[{dims}]", op_name))
+        if np.prod([int(n) for n in dims.split(",")]) * itemsize.get(dtype, 4) > over:
+            moved.append((op, f"{dtype}[{dims}]", op_name))
     flash_di = [m for m in moved if "es.attn_global/jit(_backward)/mul" in m[2]]
     assert [m for m in moved if m not in flash_di] == [], moved
     assert len(flash_di) <= 1
 
 
 def test_latent_attention_warns_once_where_the_widths_are_not_lane_aligned(one_chip, monkeypatch):
-    """Heads of 96 + 32 are one lane tile, but a value of 64 is not a key's
-    width: asked for ``pallas_flash`` on a TPU, the layer assembles q, k and v
-    with XLA and says so, once a trace."""
-    import warnings
-
-    grad, args = _latent_layer(one_chip, monkeypatch, qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=64)
+    """Four heads of 96 + 64 are five lane tiles, but the first head's rope
+    lanes would lie across lanes 96-159, in two tiles: asked for
+    ``pallas_flash`` on a TPU, the layer assembles q, k and v with XLA and
+    says so, once a trace."""
+    grad, args = _latent_layer(one_chip, monkeypatch, qk_nope_head_dim=96, qk_rope_head_dim=64, v_head_dim=128)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         jax.eval_shape(grad, *args)
@@ -562,8 +575,8 @@ def test_scan_kernels_carry_the_scope_and_leave_no_decay_plane(one_chip, monkeyp
 # `xing40_a4b_ep8.pretrain_packed`'s rows: 8 of 1,024 events, four streams of 3,584
 STREAMED_BLOCKS = {
     # kind: (layer, parameters, Mosaic calls, temporaries in GB at most)
-    "latent + swiglu": (0, 128_196_918, 3, 1.6),
-    "latent + routed": (1, 128_426_358, 15, 1.7),
+    "latent + swiglu": (0, 128_196_918, 5, 1.5),
+    "latent + routed": (1, 128_426_358, 17, 1.7),
 }
 
 
@@ -576,17 +589,22 @@ def test_streamed_blocks_at_the_cells_shapes(one_chip, monkeypatch, kind):
     kernels and nothing of ``[B, H, S, S]`` is written (33.5M elements a head
     plane); the four streams are never one float32 array (470 MB: a first
     version that stacked them held four, and the block compiled to 4.4 GB of
-    temporaries where this one holds 1.4-1.5); the routed block adds
-    megablox' twelve calls."""
+    temporaries where this one holds 1.33 and 1.54); q, k and v reach the
+    kernels through `rope_join` in the recomputed forward and its transpose
+    in the backward, two Mosaic calls more than the three (the block's first
+    forward is dead code in the gradient of a sum), and no ``copy`` of a q, k
+    or v plane (101 MB at the key width) is left but the flash op's ``di``;
+    the routed block adds megablox' twelve calls."""
     layer, n_params, n_calls, temp_gb = STREAMED_BLOCKS[kind]
     compiled, counted = _hybrid_block_gradient(one_chip, monkeypatch, layer, name="xing40_a4b_ep8", B=8)
     assert counted == n_params
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == n_calls
-    for kernel in ("flash_attention", "flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+    for kernel in ("flash_attention", "flash_mha_bwd_dkv", "flash_mha_bwd_dq", "rope_join", "rope_join_transpose"):
         assert kernel in text
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
     written = _temporaries(text)
     assert not [t for t in written if t[2] in ("8,32,1024,1024", "8,1024,32,1024")]
     assert max(size for size, *_ in written) < 160e6
+    assert [t[1:3] for t in written if t[3].startswith("copy") and t[0] > 100e6] == [("f32", "1024,8,32,128")]
     assert not [t for t in written if t[1] == "f32" and t[2].endswith("1024,3584") and t[0] > 120e6], "a float32 plane of the streams"
