@@ -109,41 +109,32 @@ def check(ok, msg: str) -> None:
 
 
 # ------------------------------------------------------------ instrumentation
-class CompileMeter:
-    """Per-phase backend-compile seconds and persistent-cache hit/miss
-    counts, from JAX's own monitoring events."""
+def compile_state() -> tuple:
+    """The clock and the persistent cache's hits and misses so far, from the
+    program's host record (`eventstreamgpt_tpu/utils/scopes.py`, whose
+    listener on JAX's compile events is the process's one)."""
+    from eventstreamgpt_tpu.utils import scopes
 
-    def __init__(self):
-        import jax.monitoring as mon
+    totals = scopes.compile_totals()
+    return time.perf_counter(), totals["hits"], totals["misses"]
 
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
 
-    def _on_duration(self, event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
+def compile_since(before: tuple) -> str:
+    """Backend-compile seconds and cache hits and misses since `compile_state`."""
+    from eventstreamgpt_tpu.utils import scopes
 
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+    began, hits, misses = before
+    totals = scopes.compile_totals()
+    seconds = sum(s.end - s.start for s in scopes.since(began) if s.name == "compile/backend")
+    return f"compile {seconds:.1f}s, cache hits {totals['hits'] - hits} misses {totals['misses'] - misses}"
 
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        s0, h0, m0 = self.seconds, self.hits, self.misses
-        t0 = time.perf_counter()
-        say(f"[{name}] start")
-        yield
-        say(
-            f"[{name}] done: wall {time.perf_counter() - t0:.1f}s, "
-            f"compile {self.seconds - s0:.1f}s, "
-            f"cache hits {self.hits - h0} misses {self.misses - m0}, "
-            f"peak HBM {peak_hbm_gb()}"
-        )
+
+@contextlib.contextmanager
+def phase(name: str):
+    before, t0 = compile_state(), time.perf_counter()
+    say(f"[{name}] start")
+    yield
+    say(f"[{name}] done: wall {time.perf_counter() - t0:.1f}s, {compile_since(before)}, peak HBM {peak_hbm_gb()}")
 
 
 def peak_hbm_gb() -> str:
@@ -302,12 +293,10 @@ def lowered_train_step_text(save_dir: Path, train_ds, sz: Sizes, kind: str) -> s
     return make_train_step(model, tx).lower(state, batch, jax.random.PRNGKey(0)).as_text()
 
 
-def phase_train_ci(
-    data_dir: Path, train_ds, out: Path, sz: Sizes, expect_kernels: bool, meter=None
-) -> Path:
+def phase_train_ci(data_dir: Path, train_ds, out: Path, sz: Sizes, expect_kernels: bool) -> Path:
     """CI at full width through ``scripts.pretrain.main``: preempt, resume.
-    With a `CompileMeter`, prints each call's compile seconds, so the second
-    call's persistent-cache hits are visible."""
+    Prints each call's compile seconds, so the second call's persistent-cache
+    hits are visible."""
     from eventstreamgpt_tpu.reliability import Preempted
     from eventstreamgpt_tpu.reliability.faults import Fault, FaultPlan, fault_plan
     from scripts.pretrain import main as pretrain_main
@@ -315,17 +304,6 @@ def phase_train_ci(
     save_dir = out / "pretrain_ci"
     args = pretrain_args(data_dir, save_dir, sz, "ci")
     say(f"[train_ci] scripts.pretrain.main({' '.join(args)})")
-
-    def compile_state():
-        return (meter.seconds, meter.hits, meter.misses) if meter else (0.0, 0, 0)
-
-    def say_compile(label, before):
-        if meter:
-            s1, h1, m1 = compile_state()
-            say(
-                f"[train_ci] {label}: compile {s1 - before[0]:.1f}s, "
-                f"cache hits {h1 - before[1]} misses {m1 - before[2]}"
-            )
 
     preempted = False
     before = compile_state()
@@ -338,12 +316,12 @@ def phase_train_ci(
     check(preempted, "the scripted SIGTERM did not preempt the run")
     ckpts = sorted(p.name for p in (save_dir / "model_checkpoints").iterdir() if p.name.isdigit())
     check(ckpts, "preemption wrote no checkpoint")
-    say_compile("first call (to the preemption)", before)
+    say(f"[train_ci] first call (to the preemption): {compile_since(before)}")
     say(f"[train_ci] checkpoints on disk: {ckpts}; resuming with the same arguments")
 
     before = compile_state()
     pretrain_main(args)  # restores the checkpoint, finishes the step budget
-    say_compile("second call (resume; same programs)", before)
+    say(f"[train_ci] second call (resume; same programs): {compile_since(before)}")
     check_losses("train_ci", save_dir, sz.steps)
     resumed_from = [s for s, _ in read_train_losses(save_dir)]
     check(resumed_from == sorted(set(resumed_from)), "resume retrained logged steps")
@@ -803,27 +781,26 @@ def run_smoke(chips: int, sz: Sizes = FULL, out: Path = OUT) -> dict:
             "It does not fall back to another backend."
         )
     say(f"compile cache: {configure_compile_cache()}")
-    meter = CompileMeter()
     shutil.rmtree(out, ignore_errors=True)
     out.mkdir(parents=True)
     t0 = time.perf_counter()
 
-    with meter.phase("data"):
+    with phase("data"):
         data_dir, train_ds = phase_data(out, sz)
     if chips > 1:
-        with meter.phase("multichip"):
+        with phase("multichip"):
             phase_multichip(data_dir, out, sz, chips)
     else:
-        with meter.phase("timing"):
+        with phase("timing"):
             phase_timing()
-        with meter.phase("kernels"):
+        with phase("kernels"):
             phase_kernels(sz, "pallas")
             phase_attention_parity(sz.data_max_seq_len, sz.na_hidden, expect_kernels=True)
-        with meter.phase("train_ci"):
-            save_dir = phase_train_ci(data_dir, train_ds, out, sz, expect_kernels=True, meter=meter)
-        with meter.phase("serve"):
+        with phase("train_ci"):
+            save_dir = phase_train_ci(data_dir, train_ds, out, sz, expect_kernels=True)
+        with phase("serve"):
             phase_serve(save_dir, train_ds, sz)
-        with meter.phase("train_na"):
+        with phase("train_na"):
             phase_train_na(data_dir, train_ds, out, sz, expect_kernels=True)
     shutil.rmtree(out, ignore_errors=True)  # checkpoints of a 166.6M model
     say(f"all phases passed in {time.perf_counter() - t0:.1f}s")

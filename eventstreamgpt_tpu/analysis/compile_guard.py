@@ -10,10 +10,11 @@ a static event, so it can be *gated*, not profiled:
   `RecompileError` from :meth:`check` / ``__exit__`` if any watched function
   grew a new executable. Per-function and noise-free: eager helper ops
   compiling elsewhere don't trip it.
-* ``CompileGuard()`` (no watch) falls back to a process-global backend
-  compile counter fed by a ``jax.monitoring`` duration listener — coarser
-  (any compile in the window trips it) but works for "this region must
-  dispatch only cached programs" assertions in tests.
+* ``CompileGuard()`` (no watch) falls back to the process-global count of
+  backend compiles in the program's host record (``utils/scopes.py``, whose
+  listener on JAX's compile events is the process's one) — coarser (any
+  compile in the window trips it) but works for "this region must dispatch
+  only cached programs" assertions in tests.
 
 Used by ``training/pretrain.py`` (armed from the second epoch, checked after
 every full-shape dispatch; ``trainer_config.guard_recompiles=False`` opts
@@ -28,34 +29,14 @@ from typing import Callable, Sequence
 
 __all__ = ["CompileGuard", "RecompileError", "backend_compile_count"]
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-# Process-global backend-compile counter. jax.monitoring has no listener
-# de-registration, so register exactly one module-level listener lazily and
-# let guards snapshot/diff the counter.
-_compile_count = 0
-_listener_installed = False
-
-
-def _install_listener() -> None:
-    global _listener_installed
-    if _listener_installed:
-        return
-    import jax
-
-    def _on_event(event: str, duration: float, **kwargs) -> None:
-        global _compile_count
-        if event == _COMPILE_EVENT:
-            _compile_count += 1
-
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
-    _listener_installed = True
-
 
 def backend_compile_count() -> int:
-    """Backend compiles observed process-wide since the listener installed."""
-    _install_listener()
-    return _compile_count
+    """Backend compiles observed process-wide: the ``compile/backend`` spans
+    of the program's host record, fed by the process's one listener on JAX's
+    compile events (``utils/scopes.py``)."""
+    from ..utils import scopes
+
+    return scopes.compile_totals()["backend"]
 
 
 def _cache_size(fn) -> int | None:
@@ -104,8 +85,6 @@ class CompileGuard:
         self._use_global = not self.watch or any(
             _cache_size(fn) is None for fn in self.watch
         )
-        if self._use_global:
-            _install_listener()
 
     # ------------------------------------------------------------- lifecycle
     def arm(self) -> "CompileGuard":

@@ -1,3 +1,7 @@
+from ..utils.misc import ImportClock as _ImportClock
+
+_import = _ImportClock()  # `startup/import` of the host record, from here to the last line
+
 from .config import (
     DatasetConfig,
     DatasetSchema,
@@ -54,3 +58,5 @@ __all__ = [
     "VocabularyConfig",
     "de_pad",
 ]
+
+_import.done()

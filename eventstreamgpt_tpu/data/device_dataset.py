@@ -51,7 +51,7 @@ from __future__ import annotations
 
 from functools import partial
 from itertools import islice
-from typing import Iterator
+from typing import Callable, Iterator
 
 import jax
 import jax.numpy as jnp
@@ -198,17 +198,33 @@ def packed_collate_kernel(
     return out
 
 
-def _chunks_under_span(items: Iterator, k: int) -> Iterator[list]:
-    """Lists of up to ``k`` items of ``items`` (the last may be shorter).
-    Making a chunk, which is where a plan iterator does its work, is one
-    ``es.host/plan`` span of an operator's trace; the span closes before the
-    chunk is handed on."""
-    while True:
-        with host_span("plan"):
-            buf = list(islice(items, k))
-        if not buf:
-            return
-        yield buf
+def plan_kept_lengths(plans: dict, dataset: JaxDataset) -> np.ndarray:
+    """Events each row of a stacked padded plan chunk keeps (a history is
+    cropped at the row; a cyclic fill row keeps none)."""
+    off = np.asarray(dataset.data.subject_event_offsets, np.int64)
+    idx = np.asarray(plans["subject_indices"], np.int64)
+    return np.where(np.asarray(plans["valid_mask"]), np.minimum(off[idx + 1] - off[idx], dataset.max_seq_len), 0)
+
+
+def padded_segment_ids(kept: np.ndarray, dataset: JaxDataset) -> np.ndarray:
+    """``kept.shape + (L,)`` segment ids as the global layers see padded rows
+    that keep ``kept`` events: one segment, padding ``-1`` on the dataset's side."""
+    L = dataset.max_seq_len
+    pos = np.arange(L)
+    real = pos < kept[..., None] if dataset.seq_padding_side == SeqPaddingSide.RIGHT else pos >= L - kept[..., None]
+    return np.where(real, 0, -1)
+
+
+class PlanEvents(int):
+    """A chunk's real events, the planner's own sum, as the plan iterators
+    yield it beside the stacked plans. It carries the chunk's ``es.host/plan``
+    span's ``id`` (the chunk's index in the dataset's life: the dispatch that
+    runs it) and ``counts`` (`DeviceDataset.plan_counts`), so that whoever
+    dispatches the chunk, however many chunks after it was planned, reads that
+    chunk's and no other's."""
+
+    id: int
+    counts: dict
 
 
 def _dense_pre_sliced(src, rows, cols, keep, n_rows: int, M: int, dtype) -> np.ndarray:
@@ -303,6 +319,12 @@ class DeviceDataset:
             else:
                 self.arrays = {k: jnp.asarray(v) for k, v in host.items()}
         self._kernel_cache: dict = {}
+        # How many chunk pairs of how many the step's flash op visits on rows
+        # with given segment ids, per row; `training.make_chunked_train_step`
+        # sets it where the model's global layers run the op (`plan_counts`).
+        self.flash_pairs: Callable[[np.ndarray], tuple[np.ndarray, int] | None] | None = None
+        self._pairs_by_kept: tuple | None = None  # (the counter it was made with, a padded row's pairs by its kept length)
+        self._chunks_planned = 0  # in this dataset's life: the next plan span's id
 
     # Default HBM budget for auto-residency: conservative against a 16 GB
     # v5e chip that also holds params, optimizer state, and activations.
@@ -359,6 +381,13 @@ class DeviceDataset:
         the shard count HERE, at startup — the alternative is a full epoch
         of pod time before the first dealt eval stream raises.
         """
+        with host_span("startup/device_tables", id="startup"):
+            made = cls._create(dataset, mesh, context_parallel, batch_sizes)
+            jax.block_until_ready(made.arrays)  # the span closes when the tables are on the device
+        return made
+
+    @classmethod
+    def _create(cls, dataset, mesh, context_parallel, batch_sizes) -> "DeviceDataset":
         if jax.process_count() == 1:
             return cls(dataset, mesh=mesh, context_parallel=context_parallel)
         if mesh is None or "data" not in mesh.shape:
@@ -420,7 +449,7 @@ class DeviceDataset:
             if est > budget:
                 return decline(f"estimated {est} bytes exceed the {budget}-byte budget")
             try:
-                return cls(dataset, mesh=mesh, context_parallel=context_parallel)
+                return cls.create(dataset, mesh=mesh, context_parallel=context_parallel)
             except ValueError as e:
                 return decline(str(e))
         if mesh is None or "data" not in mesh.shape:
@@ -882,8 +911,7 @@ class DeviceDataset:
             skip_batches=skip_batches,
             n_shards=self.data_shards,
         )
-        for buf in _chunks_under_span(plans, chunk_steps):
-            yield self._stack_plans(buf)
+        return self._chunks_under_span(plans, chunk_steps, self._stack_plans)
 
     @staticmethod
     def _stack_plans(plans: list[BatchPlan]) -> tuple[dict, int]:
@@ -895,6 +923,60 @@ class DeviceDataset:
             },
             sum(p.n_events for p in plans),
         )
+
+    def _chunks_under_span(self, items: Iterator, k: int, stack) -> Iterator[tuple[dict, PlanEvents]]:
+        """``(plans, n_events)`` of up to ``k`` stacked items of ``items`` (the
+        last may be shorter). Making a chunk, which is where a plan iterator
+        does its work, is one ``es.host/plan`` span, whose ``id`` is the
+        chunk's index in this dataset's life and whose counts are
+        `plan_counts` of the stacked chunk; both go on with the chunk
+        (`PlanEvents`). The span closes before the chunk is handed on, and a
+        span that found the iterator exhausted is not recorded."""
+        while True:
+            with host_span("plan", id=self._chunks_planned) as span:
+                buf = list(islice(items, k))
+                if buf:
+                    plans, events = stack(buf)
+                    span.counts = self.plan_counts(plans, events)
+                else:
+                    span.drop()
+            if not buf:
+                return
+            self._chunks_planned += 1
+            n_events = PlanEvents(events)
+            n_events.id, n_events.counts = span.id, span.counts
+            yield plans, n_events
+
+    def plan_counts(self, plans: dict, events: int | None = None) -> dict:
+        """The feed's counts of a stacked plan chunk: real ``events`` (the
+        planner's sum where it is given, else recounted from the plans);
+        the ``slots`` of its rows (rows x row length); and, where the step's
+        global layers run the flash op on rows of this length (`flash_pairs`),
+        ``pairs_visited`` of ``pairs_dense`` chunk pairs. Packed rows are
+        walked by the op's own bounds; a padded row is one segment, so its
+        walk depends on its kept length alone and is looked up."""
+        pairs = None
+        if "event_mask" in plans:  # packed plans carry the mask directly
+            mask = np.asarray(plans["event_mask"])
+            rows, L = mask.shape[:-1], mask.shape[-1]
+            events = int(mask.sum()) if events is None else events
+            if self.flash_pairs is not None:
+                pairs = self.flash_pairs(np.where(mask, np.asarray(plans["segment_ids"]), -1))
+        else:
+            rows, L = plans["valid_mask"].shape, self.dataset.max_seq_len
+            if events is None or self.flash_pairs is not None:
+                kept = plan_kept_lengths(plans, self.dataset)
+                events = int(kept.sum()) if events is None else events
+            if self.flash_pairs is not None:
+                if self._pairs_by_kept is None or self._pairs_by_kept[0] is not self.flash_pairs:
+                    by_kept = self.flash_pairs(padded_segment_ids(np.arange(L + 1), self.dataset))
+                    self._pairs_by_kept = (self.flash_pairs, by_kept)
+                by_kept = self._pairs_by_kept[1]
+                pairs = None if by_kept is None else (by_kept[0][kept], by_kept[1])
+        counts = {"events": int(events), "slots": int(np.prod(rows)) * L}
+        if pairs is not None:
+            counts.update(pairs_visited=int(pairs[0].sum()), pairs_dense=pairs[0].size * pairs[1])
+        return counts
 
     def packed_plan_chunks(
         self,
@@ -931,13 +1013,15 @@ class DeviceDataset:
                 event_ids, seg, mask, n_events = ds.packed_row_plan(chunk, L)
                 yield event_ids.astype(np.int32), seg.astype(np.int32), mask, n_events
 
-        for buf in _chunks_under_span(batch_plans(), chunk_steps):
-            yield self._stack_packed([b[:3] for b in buf]), sum(b[3] for b in buf)
+        return self._chunks_under_span(batch_plans(), chunk_steps, self._stack_packed)
 
     @staticmethod
-    def _stack_packed(buf: list[tuple]) -> dict:
-        return {
-            "event_ids": np.stack([e for e, _, _ in buf]),
-            "segment_ids": np.stack([s for _, s, _ in buf]),
-            "event_mask": np.stack([m for _, _, m in buf]),
-        }
+    def _stack_packed(buf: list[tuple]) -> tuple[dict, int]:
+        return (
+            {
+                "event_ids": np.stack([e for e, _, _, _ in buf]),
+                "segment_ids": np.stack([s for _, s, _, _ in buf]),
+                "event_mask": np.stack([m for _, _, m, _ in buf]),
+            },
+            sum(n for _, _, _, n in buf),
+        )

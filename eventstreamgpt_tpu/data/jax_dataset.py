@@ -29,7 +29,8 @@ from pathlib import Path
 import numpy as np
 import pandas as pd
 
-from ..utils import SeedableMixin, TimeableMixin
+from ..utils import SeedableMixin
+from ..utils.scopes import host_spanned
 from .config import (
     MeasurementConfig,
     PytorchDatasetConfig,
@@ -102,7 +103,7 @@ class _CSRData:
         return int(self.subject_event_offsets[i + 1] - self.subject_event_offsets[i])
 
 
-class JaxDataset(SeedableMixin, TimeableMixin):
+class JaxDataset(SeedableMixin):
     """A dataset over the cached DL representation, yielding numpy batches.
 
     API mirrors the reference ``PytorchDataset`` (``pytorch_dataset.py:58``):
@@ -127,6 +128,7 @@ class JaxDataset(SeedableMixin, TimeableMixin):
             return "multi_class_classification", normalized, vocab
         raise TypeError(f"Can't process label of {dtype} type!")
 
+    @host_spanned("startup/dataset_read", id="startup")
     def __init__(self, config: PytorchDatasetConfig, split: str):
         super().__init__()
         self.config = config

@@ -30,8 +30,8 @@ this system sends them:
   the call.
 
 `flash_block_sizes` chooses rows and heads a step and the two chunk widths
-from static shapes; `visited_share` is the share of dense chunk pairs the
-forward walks.
+from static shapes; `visited_pairs` counts, row by row, the chunk pairs the
+forward walks beside the dense ones (`visited_share`, their ratio).
 """
 
 from __future__ import annotations
@@ -47,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.scopes import scope
 
-__all__ = ["FlashSizes", "chunk_bounds", "flash_attention", "flash_block_sizes", "lane_tile_groups", "visited_share"]
+__all__ = ["FlashSizes", "chunk_bounds", "flash_attention", "flash_block_sizes", "lane_tile_groups", "visited_pairs", "visited_share"]
 
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 LANES = 128
@@ -140,13 +140,20 @@ def chunk_bounds(segment_ids, chunk_q: int, chunk_k: int):
     return tuple(b.astype(np.int32) for b in (k_lo, k_hi, q_lo, q_hi))
 
 
-def visited_share(segment_ids, chunk_q: int, chunk_k: int | None = None) -> float:
-    """The share of dense ``chunk_q x chunk_k`` pairs the forward (and the dq
-    kernel) walks on rows with these segment ids (padding as ``-1``)."""
+def visited_pairs(segment_ids, chunk_q: int, chunk_k: int | None = None) -> tuple[np.ndarray, int]:
+    """``(visited, dense)``: per row (``segment_ids.shape[:-1]``) the
+    ``chunk_q x chunk_k`` pairs the forward (and the dq kernel) walks on a row
+    with these segment ids (padding as ``-1``), and the pairs a dense walk of
+    one row would."""
     chunk_k = chunk_k or chunk_q
     k_lo, k_hi, _, _ = chunk_bounds(segment_ids, chunk_q, chunk_k)
-    dense = k_lo.size * (segment_ids.shape[-1] // chunk_k)
-    return float((k_hi - k_lo + 1).sum()) / dense
+    return (k_hi - k_lo + 1).sum(-1), k_lo.shape[-1] * (segment_ids.shape[-1] // chunk_k)
+
+
+def visited_share(segment_ids, chunk_q: int, chunk_k: int | None = None) -> float:
+    """`visited_pairs` of all the rows as a share."""
+    visited, dense = visited_pairs(segment_ids, chunk_q, chunk_k)
+    return float(visited.sum()) / (visited.size * dense)
 
 
 # ---------------------------------------------------------------- the kernels

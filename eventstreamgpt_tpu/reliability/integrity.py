@@ -34,6 +34,7 @@ import orbax.checkpoint as ocp
 
 from ..training.checkpoint import TrainCheckpointManager
 from ..utils.misc import atomic_write_json
+from ..utils.scopes import host_spanned
 from . import faults
 
 __all__ = [
@@ -57,6 +58,7 @@ def decode_resume_metadata(meta: dict | None) -> tuple[int, int]:
     return int(meta.get("epoch", 0)), int(meta.get("step_in_epoch", 0))
 
 
+@host_spanned("startup/restore", id="startup")
 def resume_training_state(
     ckpt_mgr: "ReliableCheckpointManager", state: Any, place_state: Callable[[Any], Any]
 ) -> tuple[Any, int, int, int]:

@@ -4,6 +4,10 @@ TPU-native replacement for the reference's Lightning modules
 (``/root/reference/EventStream/transformer/lightning_modules/``).
 """
 
+from ..utils.misc import ImportClock as _ImportClock
+
+_import = _ImportClock()  # `startup/import` of the host record, from here to the last line
+
 from .checkpoint import TrainCheckpointManager, load_pretrained, save_pretrained
 from .fine_tuning import (
     FinetuneConfig,
@@ -68,3 +72,5 @@ __all__ = [
     "train",
     "train_state_bytes",
 ]
+
+_import.done()

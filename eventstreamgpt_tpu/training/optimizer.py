@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import optax
 
 from ..models.config import OptimizationConfig
+from ..utils.scopes import host_spanned
 
 
 def polynomial_decay_with_warmup(
@@ -45,6 +46,7 @@ def polynomial_decay_with_warmup(
     return schedule
 
 
+@host_spanned("startup/build_step", id="startup")
 def build_optimizer(
     optimization_config: OptimizationConfig,
 ) -> tuple[optax.GradientTransformation, optax.Schedule]:

@@ -40,7 +40,7 @@ import optax
 from flax import serialization, struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..data.config import PytorchDatasetConfig, SeqPaddingSide
+from ..data.config import PytorchDatasetConfig
 from ..data.device_dataset import DeviceDataset
 from ..data.jax_dataset import JaxDataset
 from ..data.prefetch import prefetch_to_device
@@ -56,7 +56,8 @@ from ..models.config import (
 from ..models.moe import ROUTING_COLLECTION, routing_counters
 from ..models.na_model import NAPPTForGenerativeSequenceModeling
 from ..utils import config_dataclass
-from ..utils.scopes import host_span, scope
+from ..utils import scopes
+from ..utils.scopes import host_span, host_spanned, scope
 from .checkpoint import TrainCheckpointManager, save_pretrained
 from .generative_metrics import GenerativeMetrics
 from .optimizer import build_optimizer
@@ -74,6 +75,7 @@ class TrainState:
     opt_state: Any
 
 
+@host_spanned("startup/build_model", id="startup")
 def build_model(config: StructuredTransformerConfig):
     """CI vs NA model choice (reference ``generative_modeling.py:98-106``)."""
     mode = config.structured_event_processing_mode
@@ -236,6 +238,7 @@ def shard_batch_cp(batch: EventStreamBatch, mesh: Mesh) -> EventStreamBatch:
     return batch.replace(**updates)
 
 
+@host_spanned("startup/state", id="startup")
 def replicate(tree: Any, mesh: Mesh) -> Any:
     return jax.device_put(tree, NamedSharding(mesh, P()))
 
@@ -291,6 +294,7 @@ def _train_step_body(model, tx, with_health: bool = False, with_routing: bool = 
     return train_step
 
 
+@host_spanned("startup/build_step", id="startup")
 def make_train_step(
     model, tx, with_health: bool = False, out_state_shardings=None
 ) -> Callable:
@@ -329,6 +333,30 @@ def make_train_step(
     )
 
 
+def _flash_pair_counter(config: StructuredTransformerConfig) -> Callable | None:
+    """``segment ids [..., L] -> (pairs visited per row, dense pairs a row)`` at
+    the chunk widths the flash op takes for this model's heads
+    (`ops.pallas_flash.flash_block_sizes`, `visited_pairs`), nothing on rows
+    that are not whole chunks; ``None`` where the global layers run no flash op."""
+    if config.attention_implementation != "pallas_flash":
+        return None
+    from ..ops.pallas_flash import flash_block_sizes, visited_pairs
+
+    latent = "latent" in config.mixer_layers
+    heads = config.num_attention_heads
+    width = config.qk_nope_head_dim + config.qk_rope_head_dim if latent else config.head_dim
+    value_width = config.v_head_dim if latent else config.head_dim
+
+    def pairs(segment_ids: np.ndarray):
+        L = segment_ids.shape[-1]
+        if L % 128:
+            return None
+        return visited_pairs(segment_ids, *flash_block_sizes(1, L, heads, width, value_dim=value_width)[2:])
+
+    return pairs
+
+
+@host_spanned("startup/build_step", id="startup")
 def make_chunked_train_step(
     model,
     tx,
@@ -357,8 +385,14 @@ def make_chunked_train_step(
     the losses: the output becomes ``(state, (losses, healths))``, and with
     ``with_routing`` ``(state, (losses, healths, routings))``, the routed
     layers' counters of every step (`_train_step_body`).
+
+    The feed is told how to count the chunk pairs the model's flash op visits
+    (`DeviceDataset.flash_pairs`), so that the ``es.host/plan`` span of every
+    chunk of plans carries them beside its events and slots
+    (`DeviceDataset.plan_counts`).
     """
     body = _train_step_body(model, tx, with_health=with_health, with_routing=with_routing)
+    device_data.flash_pairs = _flash_pair_counter(model.config)
 
     if packed:
         kern = device_data.packed_kernel()
@@ -401,37 +435,6 @@ def make_chunked_train_step(
         donate_argnums=(0,),
         out_shardings=(out_state_shardings, replicated),
     )
-
-
-def _plan_kept_lengths(plans: dict, dataset: JaxDataset) -> np.ndarray:
-    """Events each row of a stacked padded plan chunk keeps (a history is cropped at the row)."""
-    off = np.asarray(dataset.data.subject_event_offsets, np.int64)
-    idx = np.asarray(plans["subject_indices"], np.int64)
-    return np.minimum(off[idx + 1] - off[idx], dataset.max_seq_len)
-
-
-def _plan_event_count(plans: dict, dataset: JaxDataset) -> int:
-    """Exact real-event count of a (possibly sliced) stacked plan chunk.
-
-    Used when ``max_training_steps`` truncates a chunk: the chunk-level count
-    from ``plan_chunks`` includes the dropped plans' events, which would
-    inflate the final logging window's events_per_sec.
-    """
-    if "event_mask" in plans:  # packed plans carry the mask directly
-        return int(np.asarray(plans["event_mask"]).sum())
-    return int(_plan_kept_lengths(plans, dataset)[np.asarray(plans["valid_mask"])].sum())
-
-
-def _plan_segment_ids(plans: dict, dataset: JaxDataset) -> np.ndarray:
-    """``(k, B, L)`` segment ids as the global layers see them (padding
-    ``-1``), from a stacked plan chunk on the host."""
-    if "event_mask" in plans:  # packed plans carry ids and mask directly
-        return np.where(np.asarray(plans["event_mask"]), np.asarray(plans["segment_ids"]), -1)
-    L = dataset.max_seq_len
-    kept = np.where(np.asarray(plans["valid_mask"]), _plan_kept_lengths(plans, dataset), 0)[..., None]
-    pos = np.arange(L)
-    real = pos < kept if dataset.seq_padding_side == SeqPaddingSide.RIGHT else pos >= L - kept
-    return np.where(real, 0, -1)
 
 
 def make_eval_step(model) -> Callable:
@@ -573,6 +576,13 @@ def train(
     divergence sentinel exhausts its rollback budget (diagnostic dump in
     ``save_dir/divergence_diagnostics.json``).
     """
+    # What the ``startup`` line below accounts for: what the host record holds
+    # since the last span of an earlier run's loop in this process (a sweep's
+    # earlier trial), which on a process's first run is all of it: its imports
+    # and its config lie before this call.
+    hot = (s.end for s in scopes.recorded() if s.name.partition("/")[0] not in ("startup", "compile"))
+    run_began = max(hot, default=0.0)
+
     np.random.seed(cfg.seed)
     rng = jax.random.PRNGKey(cfg.seed)
 
@@ -701,6 +711,7 @@ def train(
 
         strict_sharding = bool(tc.get("strict_sharding", False))
 
+        @host_spanned("startup/state", id="startup")
         def place_state(s):
             nonlocal state_shardings
             state_shardings = make_state_shardings(s, mesh, strict=strict_sharding)
@@ -886,17 +897,6 @@ def train(
         else None
     )
 
-    # The share of dense chunk pairs the global layers' flash op visits
-    # (`ops/pallas_flash.py`), from the host's own plans at the log flush.
-    flash_chunks = None
-    flash_len = packed_L if use_packed else train_pyd.max_seq_len
-    if chunked_step is not None and config.attention_implementation == "pallas_flash" and flash_len % 128 == 0:
-        from ..ops.pallas_flash import flash_block_sizes, visited_share
-
-        latent = "latent" in config.mixer_layers
-        width = config.qk_nope_head_dim + config.qk_rope_head_dim if latent else config.head_dim
-        flash_chunks = flash_block_sizes(oc.batch_size, flash_len, config.num_attention_heads, width)[2:]
-
     # Recompilation sentinel (analysis/compile_guard.py): every steady-state
     # shape is seen during the first in-process epoch, so from the second
     # epoch on the active step function must dispatch cached executables
@@ -932,6 +932,33 @@ def train(
         if log_fp is not None:
             with open(log_fp, "a") as f:
                 f.write(json.dumps(rec) + "\n")
+
+    # The program's own host record (utils/scopes.py) written out: one
+    # ``startup`` line once the first full dispatch has returned (the phases'
+    # self seconds, the compile spans by program, the persistent cache's hits
+    # and misses), and from then on a ``compile`` line for every program a
+    # dispatch compiled: which step recompiled. Both wait for the next flush.
+    started = False
+
+    def note_dispatch(dispatch: host_span, full: bool, pending: list) -> None:
+        nonlocal started
+        if started:
+            compiled = scopes.since(dispatch.start)  # nothing, dispatch after dispatch
+            if compiled:
+                for program, row in scopes.summary(compiled, small=0.0)["compile"].items():
+                    pending.append({"compile": program, "step": global_step, **row})
+        elif full:
+            started = True
+            spans = [s for s in scopes.recorded() if s.start >= run_began]
+            pending.append(
+                {
+                    "startup": scopes.summary(spans),
+                    "step": global_step,
+                    "wall_s": time.perf_counter() - spans[0].start,
+                    "since_process_start_s": scopes.seconds_since_process_start(),
+                    **scopes.compile_totals(),
+                }
+            )
 
     best_tuning_loss = float("inf")
     epochs_since_best = 0
@@ -1021,6 +1048,8 @@ def train(
             def finalize_record(rec: dict) -> None:
                 """Epoch-end flush: the only place window losses (and the lr
                 schedule, a tiny eager jnp computation) touch the host."""
+                if "_losses" not in rec:  # a startup or compile line: host numbers as they are
+                    return log_record(rec)
                 rec["train_loss"] = float(jnp.mean(jnp.concatenate(rec.pop("_losses"))))  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
                 rec["lr"] = float(lr_schedule(rec["step"] // accum))  # graftcheck: allow GC001 -- epoch-end flush, dispatch loop already drained
                 routing = rec.pop("_routing")
@@ -1118,14 +1147,16 @@ def train(
                     step_in_epoch = epoch_skip
                     for plans, n_events in train_plan_chunks(epoch, epoch_skip):
                         k = int(next(iter(plans.values())).shape[0])
+                        chunk, counts = n_events.id, n_events.counts  # the chunk's plan span: events, slots, the flash op's pairs
                         if oc.max_training_steps is not None:
                             remaining = oc.max_training_steps * accum - global_step
                             if remaining < k:
                                 plans = {key_: v[:remaining] for key_, v in plans.items()}
                                 k = remaining
                                 # Recount from the kept plans only — the chunk's
-                                # n_events includes the dropped plans' events.
-                                n_events = _plan_event_count(plans, train_pyd) if k > 0 else 0
+                                # counts include the dropped plans' events.
+                                counts = device_train.plan_counts(plans) if k > 0 else {"events": 0}
+                                n_events = counts["events"]
                         if k <= 0:
                             break
                         # Profile the dispatch(es) overlapping steps [10, 20),
@@ -1136,17 +1167,17 @@ def train(
                         ):
                             jax.profiler.start_trace(str(profile_dir))
                             profiling = True
-                        with host_span("dispatch"):
+                        with host_span("dispatch", id=chunk) as dispatch:
                             if with_health:
                                 state, (losses, healths, *routings) = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                                 health_mon.record(healths)
                                 window_routing.extend(routings)
                             else:
                                 state, losses = chunked_step(state, device_train.arrays, plans, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
-                        if flash_chunks is not None:
-                            # host arithmetic on the plan: what the flash op's walk visits
-                            window_visited.append(visited_share(_plan_segment_ids(plans, train_pyd), *flash_chunks))
+                        if "pairs_dense" in counts:  # what the flash op's walk visits, counted at the plan
+                            window_visited.append(counts["pairs_visited"] / counts["pairs_dense"])
                         global_step += k
+                        note_dispatch(dispatch, k == chunk_steps, pending_logs)
                         step_in_epoch += k
                         epoch_progress = step_in_epoch
                         faults.maybe_sigterm(global_step, shutdown)
@@ -1185,13 +1216,14 @@ def train(
                             if profile_dir and not profiling and 10 <= global_step < 20:
                                 jax.profiler.start_trace(str(profile_dir))
                                 profiling = True
-                            with host_span("dispatch"):
+                            with host_span("dispatch", id=global_step) as dispatch:
                                 if with_health:
                                     state, (loss, health) = train_step(state, batch, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                                     health_mon.record(health)
                                 else:
                                     state, loss = train_step(state, batch, rng)  # graftcheck: allow GC003 -- step body folds rng with state.step; constant base key is the dropout-stream contract
                             global_step += 1
+                            note_dispatch(dispatch, True, pending_logs)
                             epoch_progress = step_in_epoch + 1
                             faults.maybe_sigterm(global_step, shutdown)
                             window_events += n_events

@@ -299,6 +299,13 @@ def load_config(
         overrides: Either pre-parsed nested dict or ``key=value`` strings.
         defaults: Optional extra base-layer values below the YAML file.
     """
+    from .scopes import host_span  # brings JAX in: imported here, where a job reads its config, and not with the module
+
+    with host_span("startup/config", id="startup"):
+        return _load_config(config_cls, yaml_file, overrides, defaults)
+
+
+def _load_config(config_cls, yaml_file, overrides, defaults):
     if isinstance(config_cls, str):
         config_cls = CONFIG_STORE[config_cls]
 

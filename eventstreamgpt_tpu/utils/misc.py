@@ -195,6 +195,21 @@ class TimeableMixin:
         return "\n".join(lines)
 
 
+class ImportClock:
+    """``startup/import`` of the host record (``utils/scopes.py``) for a package
+    whose ``__init__`` makes one as its first statement and calls `done` as its
+    last: the start is taken here, where JAX need not be in yet, so that JAX's
+    own import lies inside the span, and what the package pulls in nests in it."""
+
+    def __init__(self):
+        self.began = time.perf_counter()
+
+    def done(self) -> None:
+        from .scopes import record  # brings JAX in where the package has not
+
+        record("startup/import", self.began, id="startup")
+
+
 def to_dict_flat(obj: Any, prefix: str = "") -> dict[str, Any]:
     """Flattens a (possibly nested dataclass/dict) object into dotted keys.
 

@@ -49,7 +49,7 @@ def cohort(tmp_path_factory, interpret_kernels):
 def trained_ci(cohort):
     out, data_dir, train_ds = cohort
     return chip_smoke.phase_train_ci(
-        data_dir, train_ds, out, TINY, expect_kernels=False, meter=chip_smoke.CompileMeter()
+        data_dir, train_ds, out, TINY, expect_kernels=False
     )
 
 

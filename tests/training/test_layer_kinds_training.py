@@ -128,7 +128,10 @@ def test_padded_plans_give_one_segment_a_row_with_its_padding_tail():
 
     from eventstreamgpt_tpu.data.config import SeqPaddingSide
     from eventstreamgpt_tpu.ops.pallas_flash import visited_share
-    from eventstreamgpt_tpu.training.pretrain import _plan_segment_ids
+    from eventstreamgpt_tpu.data.device_dataset import padded_segment_ids, plan_kept_lengths
+
+    def _plan_segment_ids(plans, dataset):
+        return padded_segment_ids(plan_kept_lengths(plans, dataset), dataset)
 
     offsets = np.asarray([0, 100, 400, 420])  # histories of 100, 300 and 20 events
     plans = {"subject_indices": np.asarray([[0, 1], [2, 0]]), "valid_mask": np.asarray([[True, True], [True, False]])}

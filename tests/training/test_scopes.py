@@ -151,8 +151,11 @@ def test_one_contract_and_no_knob():
         if path.name != "scopes.py" or path.parent.name != "utils":
             assert "named_scope(" not in text and "TraceAnnotation(" not in text, path
             names |= set(re.findall(r"""["']es\.([A-Za-z0-9_/]+)""", text))
-        for call, known in (("scope", program_scopes.SCOPES), ("scoped", program_scopes.SCOPES), ("host_span", program_scopes.HOST_SPANS)):
-            for used in re.findall(rf"\b{call}\(\"([a-z_]+)\"", text):
+        for call, known in (
+            ("scope", program_scopes.SCOPES), ("scoped", program_scopes.SCOPES),
+            ("host_span", program_scopes.HOST_SPANS), ("host_spanned", program_scopes.HOST_SPANS), ("record", program_scopes.HOST_SPANS),
+        ):
+            for used in re.findall(rf"\b{call}\(\"([a-z_/]+)\"", text):
                 assert used in known, (path, call, used)
     assert names <= set(program_scopes.SCOPES) | {"host/" + n for n in program_scopes.HOST_SPANS}
     for path in [*_program_files(), *(REPO / "benchmark").rglob("*.py"), REPO / "chip_smoke.py"]:
@@ -180,9 +183,9 @@ def test_an_operators_trace_holds_the_loop(tmp_path, monkeypatch):
     entered = []
     real = pretrain_module.host_span
 
-    def spy(name):
+    def spy(name, **given):
         entered.append(name)
-        return real(name)
+        return real(name, **given)
 
     monkeypatch.setattr(pretrain_module, "host_span", spy)
     cfg = PretrainConfig(
