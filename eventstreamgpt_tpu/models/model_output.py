@@ -29,6 +29,8 @@ from flax import struct
 from ..data.types import DataModality, EventStreamBatch
 from ..distributions import Bernoulli, Categorical
 from ..ops import safe_weighted_avg, weighted_loss
+from ..ops.impl_select import LANE
+from ..ops.pallas_multihot import multihot_any
 from ..utils.scopes import scoped
 from .config import (
     StructuredTransformerConfig,
@@ -375,23 +377,20 @@ class GenerativeOutputLayerBase(nn.Module):
                 measurement_dists = Categorical(logits=scores)
 
             elif classification_mode == DataModality.MULTI_LABEL_CLASSIFICATION:
-                data_labels_or_zero = jnp.where(
-                    tensor_idx, dynamic_indices - vocab_start + 1, 0
-                ).astype(jnp.int32)
-
-                # Dense multi-hot labels via compare-any rather than a
-                # scatter: `.at[...].set(1.0)` writes the same constant at
-                # every (possibly duplicated) index, so "any slot names this
-                # label" is exactly equivalent — and it fuses into one VPU
-                # pass where the scatter serialized (device profile:
-                # ~1 ms/measurement at bench shape). Value 0 (padding /
-                # other-measurement slots) maps to no label since the
-                # comparison range starts at 1.
-                V = scores.shape[-1]
-                labels = (
-                    (data_labels_or_zero[..., :, None] == jnp.arange(1, V + 1))
-                    .any(axis=-2)
-                    .astype(scores.dtype)
+                # The dense 0/1 label plane in one pass of a kernel
+                # (`ops.pallas_multihot.multihot_any`; the broadcast
+                # compare-any off the TPU). Padding and other measurements'
+                # slots go to -1, which names no column. The plane is written
+                # with the axis on the lanes that XLA gives the scores: the
+                # head's [hidden, vocab] kernel is laid vocabulary-major, and
+                # its product events-minor, where the unified vocabulary is
+                # no multiple of 128 (sandbox compiles for the v5e at five
+                # cells' shapes, PR 37; `tests/test_chip_compile.py` holds two).
+                labels = multihot_any(
+                    jnp.where(tensor_idx, dynamic_indices - vocab_start, -1),
+                    scores.shape[-1],
+                    scores.dtype,
+                    events_minor=self.config.vocab_size % LANE != 0,
                 )
 
                 loss_per_label = -Bernoulli(logits=scores).log_prob(labels)

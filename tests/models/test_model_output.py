@@ -10,6 +10,7 @@ torch following the reference implementation's exact formulas
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 import torch
 import torch.nn.functional as F
 
@@ -286,3 +287,132 @@ class TestGenerationMode:
         # sampling the next event.
         cat = out.preds.classification["event_type"][1]
         assert cat.logits.shape == (B, L, 3)
+
+
+# ------------------------------------------------- the multi-label plane
+# The multi-label heads take their dense 0/1 label plane from
+# `ops.pallas_multihot.multihot_any`: on a TPU a kernel in one of two
+# orientations, elsewhere (and under one lane tile of columns) the broadcast
+# compare-any the parent wrote in place. Each case plants, in measurement
+# ``multi_lab`` of ``V`` columns at slots of ``M``: an index twice in one
+# event, padding slots (index 0), slots of ``other`` whose index lies below
+# and above the span (clipped into it they would name its edge columns), an
+# out-of-range index under ``multi_lab``'s own name, and an event with no
+# label at all. (name, V, M, S, columns in all, compute precision); S and the
+# columns in all decide the kernel's orientation: events on the lanes where S
+# is whole lane tiles and the columns in all are not.
+LABEL_CASES = [
+    ("events_minor_v300_m24", 300, 24, 128, 1000, "fp32"),
+    ("events_minor_v129_m1", 129, 1, 128, 1000, "fp32"),
+    ("events_minor_v300_m24_bf16", 300, 24, 128, 1000, "bf16"),
+    ("events_minor_columns_not_whole_sublane_tiles_v200_m5", 200, 5, 256, 777, "fp32"),
+    ("vocab_minor_v300_m24", 300, 24, 128, 1024, "fp32"),
+    ("vocab_minor_v300_m24_bf16", 300, 24, 128, 1024, "bf16"),
+    ("vocab_minor_rows_not_whole_lane_tiles_v2300_m3", 2300, 3, 5, 3000, "fp32"),
+    ("vocab_minor_v128_m1", 128, 1, 7, 512, "fp32"),
+    ("under_one_lane_tile_v40_m24", 40, 24, 128, 1000, "fp32"),
+    ("under_one_lane_tile_v17_m1", 17, 1, 9, 256, "bf16"),
+]
+
+
+def label_case(V, M, S, columns, precision, B=2):
+    n_et = 3
+    start = 1 + n_et  # ``multi_lab``'s first column in the unified vocabulary
+    config = make_config(
+        vocab_sizes_by_measurement={"event_type": n_et, "multi_lab": V, "other": columns - start - V},
+        vocab_offsets_by_measurement={"event_type": 1, "multi_lab": start, "other": start + V},
+        measurements_idxmap={"event_type": 1, "multi_lab": 2, "other": 3},
+        measurements_per_generative_mode={
+            "single_label_classification": ["event_type"],
+            "multi_label_classification": ["multi_lab"],
+        },
+        max_seq_len=S,
+        hidden_size=16,
+        head_dim=4,
+        precision=precision,
+    )
+    assert config.vocab_size == columns
+    rng = np.random.default_rng(V * 31 + M)
+    meas = rng.choice([0, 2, 3], size=(B, S, M), p=[0.3, 0.5, 0.2])
+    idx = np.where(meas == 2, rng.integers(start, start + V, (B, S, M)), 0)
+    idx = np.where(meas == 3, rng.integers(start + V, columns, (B, S, M)), idx)
+    idx[0, 0], meas[0, 0] = 0, 0  # an event of padding alone
+    idx[0, 1], meas[0, 1] = start + V + 1, 3  # an event of the other measurement alone
+    idx[1, 0], meas[1, 0] = start + V - 1, 2  # every slot names the last column
+    if M >= 3:
+        idx[1, 1, :3], meas[1, 1, :3] = [start + 5, start + 5, start], 2  # a pair and the first column
+        idx[1, 2, :3], meas[1, 2, :3] = [1, start + V + 2, 0], [3, 3, 0]  # other's, below and above the span
+        idx[1, 3, :2], meas[1, 3, :2] = [start + V, start - 1], 2  # out of range under its own name
+    event_mask = np.ones((B, S), bool)
+    event_mask[0, S - 2 :] = False
+    batch = EventStreamBatch(
+        event_mask=jnp.asarray(event_mask),
+        time_delta=jnp.ones((B, S), jnp.float32),
+        dynamic_indices=jnp.asarray(idx),
+        dynamic_measurement_indices=jnp.asarray(meas),
+        dynamic_values=jnp.zeros((B, S, M), jnp.float32),
+        dynamic_values_mask=jnp.zeros((B, S, M), bool),
+    )
+    encoded = jnp.asarray(rng.normal(size=(B, S, config.hidden_size)).astype(np.float32) * 0.5)
+    return config, batch, encoded.astype(config.compute_dtype), (start, idx, meas)
+
+
+@pytest.mark.parametrize("name, V, M, S, columns, precision", LABEL_CASES, ids=[c[0] for c in LABEL_CASES])
+def test_multi_label_plane_from_the_kernel_is_the_compare_any_bit_for_bit(monkeypatch, name, V, M, S, columns, precision):
+    """Labels, loss and gradient of the multi-label head with the plane's
+    kernel (interpreted) against the compare-any formulation (``xla``). Run
+    op by op and not under ``jit``, so that what is compared is the plane and
+    what the head does with it, and not how XLA's CPU backend orders a fused
+    reduction's sums around a different producer."""
+    from eventstreamgpt_tpu.ops import pallas_multihot
+
+    config, batch, encoded, (start, idx, meas) = label_case(V, M, S, columns, precision)
+    layer = ConditionallyIndependentGenerativeOutputLayer(config)
+    params = layer.init(jax.random.PRNGKey(0), batch, encoded)
+
+    def head(p, e):
+        losses, _, labels = layer.apply(p, batch, e, {"multi_lab"}, method=layer.get_classification_outputs)
+        return losses["multi_lab"], labels["multi_lab"]
+
+    launched, kernels = [], (pallas_multihot._anyhot_2d, pallas_multihot._anyhot_events_minor)
+    for kernel in kernels:
+        monkeypatch.setattr(
+            pallas_multihot, kernel.__name__, lambda *a, _k=kernel, **kw: launched.append(_k.__name__) or _k(*a, **kw)
+        )
+    got = {}
+    for impl in ("xla", "pallas_interpret"):
+        monkeypatch.setenv("ESGPT_PALLAS_IMPL", impl)
+        (loss, labels), grads = jax.value_and_grad(head, argnums=(0, 1), has_aux=True)(params, encoded)
+        got[impl] = jax.tree_util.tree_map(np.asarray, (labels, loss, grads))
+    # the orientation the static shapes choose, or none under one lane tile
+    events_minor = S % 128 == 0 and columns % 128 != 0
+    want = [] if V < 128 else ["_anyhot_events_minor" if events_minor else "_anyhot_2d"]
+    assert sorted(set(launched)) == want
+    labels = got["xla"][0]
+    assert labels.dtype == np.float32 and labels.shape == (2, S, V)
+    by_hand = np.zeros((2, S, V + 2), np.float32)  # a column of spill on either side
+    b, s, m = np.nonzero(meas == 2)
+    np.add.at(by_hand, (b, s, np.clip(idx[b, s, m] - start + 1, 0, V + 1)), 1.0)
+    np.testing.assert_array_equal(labels, np.minimum(by_hand[..., 1:-1], 1.0))
+    assert labels[0, 0].sum() == 0 and labels[0, 1].sum() == 0 and labels[1, 0].sum() == 1
+    for a, b_ in zip(jax.tree_util.tree_leaves(got["xla"]), jax.tree_util.tree_leaves(got["pallas_interpret"])):
+        assert a.dtype == b_.dtype
+        np.testing.assert_array_equal(a, b_)
+
+
+@pytest.mark.parametrize("events_minor", [False, True], ids=["vocab_minor", "events_minor"])
+@pytest.mark.parametrize("name, V, M, S, columns, precision", LABEL_CASES, ids=[c[0] for c in LABEL_CASES])
+def test_multihot_any_kernels_at_every_case_and_orientation(name, V, M, S, columns, precision, events_minor):
+    """The op alone, asked for by name (the least width is the head's rule on
+    ``auto``): both kernels on every case's indices, the cases under one lane
+    tile too, in the case's dtype, against the compare-any."""
+    from eventstreamgpt_tpu.ops.pallas_multihot import multihot_any
+
+    _, _, _, (start, idx, meas) = label_case(V, M, S, columns, precision)
+    dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
+    sent = jnp.where(meas == 2, idx - start, -1)
+    want = multihot_any(sent, V, dtype, impl="xla")
+    got = multihot_any(sent, V, dtype, events_minor=events_minor, impl="pallas_interpret")
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape == (2, S, V)
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32)))
+    assert set(np.unique(np.asarray(got.astype(jnp.float32)))) <= {0.0, 1.0}

@@ -204,6 +204,9 @@ def test_embedding_bag_plane_over_four_chips(topo, monkeypatch, program):
     with kernel_mesh(mesh):
         text = build().lower(*args).compile().as_text()
     assert "_multihot_2d" in text
+    # The heads' label plane (255 columns of `lab`: over the least width) is
+    # traced and dead: nothing of a sampling program reads labels.
+    assert "_anyhot" not in text
 
 
 def _flash_compile(one_chip, B, H, S, D, scale, direction="gradient"):
@@ -461,7 +464,7 @@ HYBRID_BLOCKS = {
     "E": (1, 100_125_440, 6, 1.4, 420),
     "*": (5, 23_399_040, 3, 1.4, 420),
 }
-_ITEMSIZE = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1}
+_ITEMSIZE = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1, "s8": 1}
 
 
 def _hybrid_block_gradient(one_chip, monkeypatch, layer, name="nemotron_twotower_ep16", B=16):
@@ -608,3 +611,84 @@ def test_streamed_blocks_at_the_cells_shapes(one_chip, monkeypatch, kind):
     assert max(size for size, *_ in written) < 160e6
     assert [t[1:3] for t in written if t[3].startswith("copy") and t[0] > 100e6] == [("f32", "1024,8,32,128")]
     assert not [t for t in written if t[1] == "f32" and t[2].endswith("1024,3584") and t[0] > 120e6], "a float32 plane of the streams"
+
+
+HEAD_SHAPES = {  # rows, events, hidden; event types, labs, medications, static codes (`benchmark/workloads/*.json`)
+    "ci_w1024.pretrain_packed": (16, 1024, 1024, 40, 3500, 500, 16),
+    "nemotron_twotower_ep16.pretrain_packed": (16, 1024, 2688, 40, 12827, 3500, 16),
+}
+
+
+@pytest.mark.parametrize("cell", list(HEAD_SHAPES))
+def test_head_label_planes_reach_the_loss_in_the_layout_it_reads(one_chip, monkeypatch, cell):
+    """The CI head's classification loss and gradient at a cell's shape, as
+    the chip's backend traces it: both multi-label planes come from
+    `ops.pallas_multihot.multihot_any`'s Mosaic call, events on the lanes
+    where the unified vocabulary is no multiple of 128 (4,057: XLA lays the
+    head's kernel vocabulary-major and the scores events-minor) and the
+    vocabulary on them where it is (16,384), so that nothing of a plane's size
+    is written but the plane: no ``pred[...]`` plane of the compare-any and no
+    ``copy`` over 50 MB under ``es.heads_cls`` (the parent re-laid 210 + 57 MB
+    of ``pred`` in the hybrid cell; a vocabulary-minor plane would be re-laid
+    in the CI cells)."""
+    import re
+
+    import numpy as np
+
+    from eventstreamgpt_tpu.data.types import EventStreamBatch
+    from eventstreamgpt_tpu.models.ci_model import ConditionallyIndependentGenerativeOutputLayer
+    from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ESGPT_PALLAS_IMPL", raising=False)
+    B, S, hidden, *sizes = HEAD_SHAPES[cell]
+    sizes = dict(zip(("event_type", "lab", "med", "demo"), sizes))
+    offsets, at = {}, 1
+    for name, size in sizes.items():
+        offsets[name], at = at, at + size
+    config = StructuredTransformerConfig(
+        vocab_sizes_by_measurement=sizes,
+        vocab_offsets_by_measurement=offsets,
+        measurements_idxmap={"event_type": 1, "lab": 2, "med": 3, "demo": 4},
+        measurements_per_generative_mode={
+            "single_label_classification": ["event_type"],
+            "multi_label_classification": ["lab", "med"],
+            "multivariate_regression": ["lab"],
+        },
+        max_seq_len=S, hidden_size=hidden, head_dim=128, num_attention_heads=hidden // 128,
+        num_hidden_layers=1, intermediate_size=hidden, precision="bf16",
+    )  # fmt: skip
+    layer = ConditionallyIndependentGenerativeOutputLayer(config)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    batch = EventStreamBatch(
+        event_mask=sds((B, S), jnp.bool_),
+        time_delta=sds((B, S), jnp.float32),
+        dynamic_indices=sds((B, S, 24), jnp.int32),
+        dynamic_measurement_indices=sds((B, S, 24), jnp.int32),
+        dynamic_values=sds((B, S, 24), jnp.float32),
+        dynamic_values_mask=sds((B, S, 24), jnp.bool_),
+        segment_ids=sds((B, S), jnp.int32),
+    )
+    encoded = sds((B, S, hidden), jnp.bfloat16)
+    zeros = lambda tree: jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype), tree)  # noqa: E731
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), zeros(batch), zeros(encoded)))
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params)
+
+    def loss(p, e, b):
+        losses, _, _ = layer.apply(p, b, e, set(sizes) - {"demo"}, method=layer.get_classification_outputs)
+        return sum(losses.values())
+
+    text = _compile(lambda p, e, b: jax.value_and_grad(loss, argnums=(0, 1))(p, e, b), params, encoded, batch)
+    kernel = "_anyhot_2d" if config.vocab_size % 128 == 0 else "_anyhot_events_minor"
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    planes = dict(re.findall(rf"%({kernel}[\w.]*) = s8\[([\d,]+)\]", text))
+    written = _temporaries(text)
+    assert not [t for t in written if t[1] == "pred" and t[0] > 1e6], "a compare-any plane"
+    entry = text[text.index("ENTRY") :]
+    copies = re.findall(r'%(copy[\w.\-]*) = (\w+)\[([\d,]+)\][^\n]*?op_name="([^"]*es\.heads_cls[^"]*)"', entry)
+    sized = [(op, dtype, dims) for op, dtype, dims, _ in copies if np.prod([int(n) for n in dims.split(",")]) * _ITEMSIZE.get(dtype, 4) > 50e6]
+    assert sized == []
+    from eventstreamgpt_tpu.ops.pallas_multihot import _any_lane_tiles
+
+    padded = (lambda v: _any_lane_tiles(v)[1]) if kernel == "_anyhot_2d" else (lambda v: -(-v // 256) * 256)
+    assert sorted(np.prod([int(n) for n in dims.split(",")]) for dims in planes.values()) == sorted(B * S * padded(sizes[m]) for m in ("lab", "med"))
