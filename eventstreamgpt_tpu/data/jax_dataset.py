@@ -13,11 +13,12 @@ task windows (``:311-459``), samples subsequences per the configured strategy
 
 The *representation* diverges deliberately (SURVEY.md §7.3): instead of
 per-subject Python lists padded in a per-item loop (the reference's known CPU
-bottleneck), events are flattened at load time into contiguous CSR-style
-numpy arrays (values + offsets). Collation is then a handful of vectorized
-gathers into **static-shape** ``(B, max_seq_len, max_n_dynamic)`` buffers, so
-XLA compiles the training step exactly once and the host never bottlenecks
-the chip.
+bottleneck), events are held as contiguous CSR-style numpy arrays (values +
+offsets), taken at load time straight from the parquet file's Arrow list
+offsets and flat child arrays: no Python object is made per event. Collation
+is then a handful of vectorized gathers into **static-shape**
+``(B, max_seq_len, max_n_dynamic)`` buffers, so XLA compiles the training step
+exactly once and the host never bottlenecks the chip.
 """
 
 from __future__ import annotations
@@ -28,9 +29,12 @@ from pathlib import Path
 
 import numpy as np
 import pandas as pd
+import pyarrow as pa
+import pyarrow.compute as pc
+import pyarrow.parquet as pq
 
 from ..utils import SeedableMixin
-from ..utils.scopes import host_spanned
+from ..utils.scopes import host_span
 from .config import (
     MeasurementConfig,
     PytorchDatasetConfig,
@@ -103,6 +107,75 @@ class _CSRData:
         return int(self.subject_event_offsets[i + 1] - self.subject_event_offsets[i])
 
 
+# ------------------------------------------------ Arrow lists -> flat arrays
+def _unnest(col: pa.ChunkedArray) -> tuple[np.ndarray, pa.ChunkedArray]:
+    """A list column's lengths (0 for a null list) and its values one level
+    down. ``list`` and ``large_list`` alike; a column of Arrow's null type (an
+    empty frame written by pandas) holds no list."""
+    if pa.types.is_null(col.type):
+        return np.zeros(len(col), np.int64), pa.chunked_array([], pa.null())
+    lengths = pc.list_value_length(col).fill_null(0).to_numpy().astype(np.int64)
+    return lengths, pc.list_flatten(col)
+
+
+def _ints(values: pa.ChunkedArray, name: str) -> np.ndarray:
+    """The values as stored (possibly a read-only view: `_shrink` copies)."""
+    if values.null_count:
+        raise ValueError(f"{name} holds {values.null_count} null elements")
+    return values.to_numpy()
+
+
+def _floats(values: pa.ChunkedArray, dtype) -> np.ndarray:
+    """The values in ``dtype``, NaN where an element is null (possibly a
+    read-only view)."""
+    if pa.types.is_null(values.type):
+        return np.full(len(values), np.nan, dtype)
+    return values.to_numpy().astype(dtype, copy=False)
+
+
+def _offsets(lengths: np.ndarray) -> np.ndarray:
+    out = np.zeros(len(lengths) + 1, np.int64)
+    np.cumsum(lengths, out=out[1:])
+    return out
+
+
+def _shrink(x: np.ndarray) -> np.ndarray:
+    """An owned int32 copy when the values fit, else int64 (collation index
+    arithmetic is memory-bound; half-width indices halve the traffic)."""
+    if x.size == 0 or (x.min() >= np.iinfo(np.int32).min and x.max() <= np.iinfo(np.int32).max):
+        return x.astype(np.int32)
+    return x.astype(np.int64)
+
+
+def _segments(starts: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """Positions of the runs ``starts[i] : starts[i] + lengths[i]``, one
+    after another."""
+    ends = np.cumsum(lengths)
+    return np.arange(ends[-1] if len(ends) else 0) + np.repeat(starts - (ends - lengths), lengths)
+
+
+def _aligned(values: np.ndarray, own: np.ndarray, target: np.ndarray, name: str, fill=None) -> np.ndarray:
+    """``values``, one run of ``own[e]`` elements an event, laid out as runs
+    of ``target[e]``: an event's own run where the two agree, nothing where
+    the target is empty and, with a ``fill``, runs of it where the event holds
+    no elements (a null list beside non-empty indices)."""
+    if np.array_equal(own, target):
+        return values
+    ok = (own == target) | (target == 0)
+    if fill is not None:
+        ok |= own == 0
+    if not ok.all():
+        e = int(np.flatnonzero(~ok)[0])
+        raise ValueError(f"event {e} holds {own[e]} {name} for {target[e]} indices")
+    take = (own == target) & (target > 0)
+    src = _segments((np.cumsum(own) - own)[take], target[take])
+    if fill is None:  # every event with elements is taken
+        return values[src]
+    out = np.full(int(target.sum()), fill, values.dtype)
+    out[_segments((np.cumsum(target) - target)[take], target[take])] = values[src]
+    return out
+
+
 class JaxDataset(SeedableMixin):
     """A dataset over the cached DL representation, yielding numpy batches.
 
@@ -128,9 +201,14 @@ class JaxDataset(SeedableMixin):
             return "multi_class_classification", normalized, vocab
         raise TypeError(f"Can't process label of {dtype} type!")
 
-    @host_spanned("startup/dataset_read", id="startup")
     def __init__(self, config: PytorchDatasetConfig, split: str):
         super().__init__()
+        with host_span("startup/dataset_read", id="startup") as span:
+            self._read(config, split)
+            d = self.data
+            span.counts.update(subjects=d.n_subjects, events=len(d.time_delta), data=len(d.dynamic_indices))
+
+    def _read(self, config: PytorchDatasetConfig, split: str) -> None:
         self.config = config
         self.split = split
         self.task_types: dict[str, str] = {}
@@ -148,76 +226,153 @@ class JaxDataset(SeedableMixin):
 
         if config.task_df_name is not None:
             self.has_task = True
-            df, self.tasks = self._load_task_data(save_dir, config.task_df_name, split)
+            table, self.tasks = self._load_task_data(save_dir, config.task_df_name, split)
         else:
             self.has_task = False
             self.tasks = None
             self.task_vocabs = None
-            df = self._read_dl_reps(save_dir / "DL_reps", split)
+            table = self._read_dl_reps(save_dir / "DL_reps", split)
 
-        self.do_produce_static_data = "static_indices" in df.columns
+        self.do_produce_static_data = "static_indices" in table.column_names
         self.seq_padding_side = config.seq_padding_side
         self.max_seq_len = config.max_seq_len
 
-        df = self._to_time_deltas(df)
+        # Every subject of the files, in file order, as flat arrays.
+        lists = [c for c in table.column_names if pa.types.is_list(t := table.schema.field(c).type) or pa.types.is_large_list(t)]
+        subjects = table.drop_columns(lists).to_pandas().reset_index(drop=True)
+        n_subjects = len(subjects)
+        counts, events = _unnest(table["dynamic_indices"])
+        idx_len, idx = _unnest(events)
+        meas_counts, events = _unnest(table["dynamic_measurement_indices"])
+        meas_len, meas = _unnest(events)
+        val_counts, events = _unnest(table["dynamic_values"])
+        val_len, vals = _unnest(events)
+        time_col = "time_delta" if "time_delta" in table.column_names else "time"
+        time_counts, times = _unnest(table[time_col])
+        for name, other in (("dynamic_measurement_indices", meas_counts), ("dynamic_values", val_counts), (time_col, time_counts)):
+            if not np.array_equal(other, counts):
+                raise ValueError(f"{name} holds other event counts than dynamic_indices")
+        # A null index or measurement list is an empty event; values follow
+        # the indices, NaN where an event's value list is null.
+        per_event = np.where((idx_len == 0) | (meas_len == 0), 0, idx_len)
+        dyn_idx = _aligned(_ints(idx, "dynamic_indices"), idx_len, per_event, "dynamic_indices")
+        dyn_meas = _aligned(_ints(meas, "dynamic_measurement_indices"), meas_len, per_event, "measurements")
+        raw_vals = _aligned(_floats(vals, np.float32), val_len, per_event, "values", fill=np.nan)
+
+        ev_offsets = _offsets(counts)
+        ev_subject = np.repeat(np.arange(n_subjects), counts)
+        # An event's delta is real (not the 1.0 filler) when its subject has a next event.
+        real = np.ones(len(ev_subject), bool)
+        real[ev_offsets[1:][counts > 0] - 1] = False
+        if time_col == "time_delta":
+            deltas = times.to_numpy()  # as stored: the statistics read them so
+            time_delta = deltas.astype(np.float32)
+        else:
+            # ``time`` (absolute minutes) -> minutes to the next event, 1 at a
+            # subject's last (``pytorch_dataset.py:245-256``).
+            t = _floats(times, np.float64)  # graftcheck: allow GC002 -- host-side: absolute minutes, differenced in float64 as the reference does
+            time_delta = np.empty(len(t), np.float32)
+            time_delta[:-1] = (t[1:] - t[:-1]).astype(np.float32)
+            time_delta[~real] = 1.0
+            deltas = time_delta
+            if "start_time" in subjects.columns:
+                # start_time advances to the first event's absolute time.
+                first = np.zeros(n_subjects)
+                first[counts > 0] = t[ev_offsets[:-1][counts > 0]]
+                subjects["start_time"] = pd.to_datetime(subjects["start_time"]) + pd.to_timedelta(
+                    pd.Series(first, index=subjects.index), unit="m"
+                )
 
         # Filter short sequences.
-        lens = df["time_delta"].map(len)
-        df = df[lens >= config.min_seq_len].reset_index(drop=True)
+        keep = counts >= config.min_seq_len
 
         # Inter-event-time stats + malformed-subject quarantine
-        # (reference ``pytorch_dataset.py:258-287``). The last delta of each
-        # subject is a filler (1.0) and excluded from stats.
-        def _real_deltas(row):
-            return row[:-1] if len(row) > 1 else row[:0]
-
-        all_deltas = (
-            np.concatenate([_real_deltas(np.asarray(r)) for r in df["time_delta"]])
-            if len(df)
-            else np.asarray([1.0])
-        )
+        # (reference ``pytorch_dataset.py:258-287``), over the real deltas of
+        # the kept subjects, in their order.
+        all_deltas = deltas[real & keep[ev_subject]]
         if len(all_deltas) == 0:
             all_deltas = np.asarray([1.0])
-        min_delta = float(all_deltas.min()) if len(all_deltas) else 1.0
+        min_delta = float(all_deltas.min())
         if min_delta <= 0:
-            bad_mask = df["time_delta"].map(lambda r: float(np.min(_real_deltas(np.asarray(r)))) <= 0 if len(r) > 1 else False)
-            bad = df[bad_mask]
+            with np.errstate(invalid="ignore"):
+                bad = keep & (np.bincount(ev_subject[real & (deltas <= 0)], minlength=n_subjects) > 0)
             print(
-                f"WARNING: Observed inter-event times <= 0 for {len(bad)} subjects!\n"
-                f"ESD Subject IDs: {', '.join(str(x) for x in bad['subject_id'].tolist())}\n"
+                f"WARNING: Observed inter-event times <= 0 for {int(bad.sum())} subjects!\n"
+                f"ESD Subject IDs: {', '.join(str(x) for x in subjects['subject_id'][bad].tolist())}\n"
                 f"Global min: {min_delta}"
             )
             if config.save_dir is not None:
                 fp = Path(config.save_dir) / f"malformed_data_{split}.parquet"
-                bad.to_parquet(fp)
+                self._malformed_rows(table, subjects, np.flatnonzero(bad), keep, time_col, time_delta, ev_offsets).to_parquet(fp)
                 print(f"Wrote malformed data records to {fp}")
             print("Removing malformed subjects")
-            df = df[~bad_mask].reset_index(drop=True)
-            all_deltas = np.concatenate([_real_deltas(np.asarray(r)) for r in df["time_delta"]])
+            keep &= ~bad
+            all_deltas = deltas[real & keep[ev_subject]]
 
         with np.errstate(divide="ignore", invalid="ignore"):
             logs = np.log(all_deltas[all_deltas > 0])
         self.mean_log_inter_event_time_min = float(logs.mean()) if len(logs) else 0.0
         self.std_log_inter_event_time_min = float(logs.std(ddof=1)) if len(logs) > 1 else 1.0
 
-        # Train-subset subsampling (``pytorch_dataset.py:291-303``).
+        sel = np.flatnonzero(keep)
+        # Train-subset subsampling (``pytorch_dataset.py:291-303``): the rows
+        # ``DataFrame.sample`` would pick from the kept subjects.
         if config.train_subset_size not in (None, "FULL") and split == "train":
             if isinstance(config.train_subset_size, int) and config.train_subset_size > 0:
-                n = min(config.train_subset_size, len(df))
+                n = min(config.train_subset_size, len(sel))
             elif isinstance(config.train_subset_size, float) and 0 < config.train_subset_size < 1:
-                n = int(round(config.train_subset_size * len(df)))
+                n = int(round(config.train_subset_size * len(sel)))
             else:
                 raise TypeError(
                     f"Can't process subset size of {type(config.train_subset_size)}, "
                     f"{config.train_subset_size}"
                 )
-            df = df.sample(n=n, random_state=config.train_subset_seed).reset_index(drop=True)
+            sel = sel[pd.Series(np.arange(len(sel))).sample(n=n, random_state=config.train_subset_seed).to_numpy()]
 
-        self.subject_ids = df["subject_id"].tolist()
+        subjects = subjects.iloc[sel].reset_index(drop=True)
+        self.subject_ids = subjects["subject_id"].tolist()
         self.stream_labels = (
-            {t: np.asarray(df[t].to_numpy()) for t in self.tasks} if self.has_task else None
+            {t: np.asarray(subjects[t].to_numpy()) for t in self.tasks} if self.has_task else None
         )
-        self.data = self._flatten(df)
+
+        if self.do_produce_static_data:
+            st_counts, st = _unnest(table["static_indices"])
+            st_meas_counts, st_meas = _unnest(table["static_measurement_indices"])
+            if not np.array_equal(st_meas_counts, st_counts):
+                raise ValueError("static_measurement_indices holds other counts than static_indices")
+            st_idx, st_meas = _ints(st, "static_indices"), _ints(st_meas, "static_measurement_indices")
+        else:
+            st_counts, st_idx, st_meas = np.zeros(n_subjects, np.int64), np.zeros(0, np.int64), np.zeros(0, np.int64)
+
+        if not np.array_equal(sel, np.arange(n_subjects)):
+            ev = _segments(ev_offsets[:-1][sel], counts[sel])
+            data = _segments(_offsets(per_event)[:-1][ev], per_event[ev])
+            counts, time_delta, per_event = counts[sel], time_delta[ev], per_event[ev]
+            dyn_idx, dyn_meas, raw_vals = dyn_idx[data], dyn_meas[data], raw_vals[data]
+            st = _segments(_offsets(st_counts)[:-1][sel], st_counts[sel])
+            st_counts, st_idx, st_meas = st_counts[sel], st_idx[st], st_meas[st]
+
+        if "start_time" in subjects.columns:
+            start_time_min = (
+                pd.to_datetime(subjects["start_time"]).map(lambda t: t.timestamp() / 60.0).to_numpy(copy=True)
+            )
+        else:
+            start_time_min = np.zeros(len(subjects), dtype=np.float64)  # graftcheck: allow GC002 -- host-side minutes since the epoch
+
+        observed = ~np.isnan(raw_vals)
+        self.data = _CSRData(
+            subject_event_offsets=_shrink(_offsets(counts)),
+            time_delta=time_delta,
+            event_data_offsets=_shrink(_offsets(per_event)),
+            dynamic_indices=_shrink(dyn_idx),
+            dynamic_measurement_indices=_shrink(dyn_meas),
+            dynamic_values=np.where(observed, raw_vals, np.float32(0.0)),
+            dynamic_values_observed=observed,
+            static_offsets=_shrink(_offsets(st_counts)),
+            static_indices=_shrink(st_idx),
+            static_measurement_indices=_shrink(st_meas),
+            start_time_min=start_time_min,
+        )
 
         # Static data-element axis sizes for shape-stable collation.
         data_lens = np.diff(self.data.event_data_offsets)
@@ -228,7 +383,9 @@ class JaxDataset(SeedableMixin):
 
     # ------------------------------------------------------------------ I/O
     @staticmethod
-    def _read_dl_reps(dl_dir: Path, split: str) -> pd.DataFrame:
+    def _read_dl_reps(dl_dir: Path, split: str) -> pa.Table:
+        """The split's chunk files as one Arrow table, lists kept as Arrow
+        lists (``list`` or ``large_list``, as written)."""
         # Chunk order is load-bearing (subject order feeds the deterministic
         # batch stream); `append_subjects` grows chunk counts past 9, where
         # lexicographic sorting would interleave ("x_10" < "x_2") and shuffle
@@ -240,7 +397,24 @@ class JaxDataset(SeedableMixin):
         files = sorted(Path(dl_dir).glob(f"{split}*.parquet"), key=chunk_key)
         if not files:
             raise FileNotFoundError(f"No DL_reps parquet files for split {split} in {dl_dir}")
-        return pd.concat([pd.read_parquet(fp) for fp in files], ignore_index=True)
+        return pa.concat_tables([pq.read_table(fp) for fp in files], promote_options="permissive")
+
+    @staticmethod
+    def _malformed_rows(table, subjects, rows, kept, time_col, time_delta, ev_offsets) -> pd.DataFrame:
+        """The quarantined subjects' rows as the reference writes them: the
+        file's columns with ``time`` turned into ``time_delta`` (appended last)
+        and ``start_time`` advanced, indexed by position among the subjects
+        that passed ``min_seq_len``."""
+        frame = table.take(rows).to_pandas()
+        frame.index = pd.RangeIndex(int(kept.sum())).take((np.cumsum(kept) - 1)[rows])
+        if time_col == "time":
+            frame["time_delta"] = pd.Series(
+                [time_delta[ev_offsets[s] : ev_offsets[s + 1]] for s in rows], index=frame.index, dtype=object
+            )
+            if "start_time" in frame.columns:
+                frame["start_time"] = subjects["start_time"].iloc[rows].set_axis(frame.index)
+            frame = frame.drop(columns=["time"])
+        return frame
 
     def _load_task_data(self, save_dir: Path, task_df_name: str, split: str):
         """Task-restricted data loading (``pytorch_dataset.py:149-236``)."""
@@ -248,15 +422,14 @@ class JaxDataset(SeedableMixin):
         raw_task_df_fp = save_dir / "task_dfs" / f"{task_df_name}.parquet"
         task_info_fp = task_dir / "task_info.json"
 
-        cached_files = sorted(task_dir.glob(f"{split}*.parquet"))
-        if cached_files:
-            df = pd.concat([pd.read_parquet(fp) for fp in cached_files], ignore_index=True)
+        if any(task_dir.glob(f"{split}*.parquet")):
+            table = self._read_dl_reps(task_dir, split)
             with open(task_info_fp) as f:
                 task_info = json.load(f)
             tasks = sorted(task_info["tasks"])
             self.task_vocabs = task_info["vocabs"]
             self.task_types = task_info["types"]
-            return df, tasks
+            return table, tasks
 
         if not raw_task_df_fp.is_file():
             raise FileNotFoundError(
@@ -295,11 +468,7 @@ class JaxDataset(SeedableMixin):
             out_fp.parent.mkdir(exist_ok=True, parents=True)
             restricted.to_parquet(out_fp)
 
-        df = pd.concat(
-            [pd.read_parquet(fp) for fp in sorted(task_dir.glob(f"{split}*.parquet"))],
-            ignore_index=True,
-        )
-        return df, tasks
+        return self._read_dl_reps(task_dir, split), tasks
 
     @staticmethod
     def _build_task_cached_df(task_df: pd.DataFrame, cached_data: pd.DataFrame) -> pd.DataFrame:
@@ -369,117 +538,6 @@ class JaxDataset(SeedableMixin):
             rows.append(new_row)
         # All-windows-empty must still return the full column schema.
         return pd.DataFrame(rows) if rows else empty
-
-    # ------------------------------------------------------ representation
-    @staticmethod
-    def _to_time_deltas(df: pd.DataFrame) -> pd.DataFrame:
-        """``time`` (absolute minutes) → ``time_delta`` (minutes to next event).
-
-        The final event's delta is filled with 1; it is ignored downstream via
-        the event mask (``pytorch_dataset.py:245-256``).
-        """
-        if "time_delta" in df.columns:
-            return df
-
-        def convert(times):
-            times = np.asarray(times, dtype=np.float64)
-            if len(times) == 0:
-                return times.astype(np.float32)
-            deltas = np.empty_like(times, dtype=np.float32)
-            deltas[:-1] = (times[1:] - times[:-1]).astype(np.float32)
-            deltas[-1] = 1.0
-            return deltas
-
-        df = df.copy()
-        df["time_delta"] = df["time"].map(convert)
-        # start_time advances to the first event's absolute time.
-        if "start_time" in df.columns:
-            first_offset = df["time"].map(lambda t: float(t[0]) if len(t) else 0.0)
-            df["start_time"] = pd.to_datetime(df["start_time"]) + pd.to_timedelta(
-                first_offset, unit="m"
-            )
-        return df.drop(columns=["time"])
-
-    def _flatten(self, df: pd.DataFrame) -> _CSRData:
-        n_subjects = len(df)
-        event_counts = np.asarray([len(r) for r in df["time_delta"]], dtype=np.int64)
-        subject_event_offsets = np.zeros(n_subjects + 1, dtype=np.int64)
-        np.cumsum(event_counts, out=subject_event_offsets[1:])
-
-        time_delta = (
-            np.concatenate([np.asarray(r, dtype=np.float32) for r in df["time_delta"]])
-            if n_subjects
-            else np.zeros(0, np.float32)
-        )
-
-        data_counts, dyn_idx, dyn_meas, dyn_vals = [], [], [], []
-        for _, row in df.iterrows():
-            for ev_i, ev_m, ev_v in zip(
-                row["dynamic_indices"], row["dynamic_measurement_indices"], row["dynamic_values"]
-            ):
-                ev_i = np.asarray(ev_i if ev_i is not None else [], dtype=np.int64)
-                ev_m = np.asarray(ev_m if ev_m is not None else [], dtype=np.int64)
-                if ev_v is None:
-                    ev_v = np.full(len(ev_i), np.nan, dtype=np.float32)
-                else:
-                    ev_v = np.asarray(
-                        [np.nan if v is None else v for v in ev_v], dtype=np.float32
-                    )
-                data_counts.append(len(ev_i))
-                dyn_idx.append(ev_i)
-                dyn_meas.append(ev_m)
-                dyn_vals.append(ev_v)
-
-        n_events = len(data_counts)
-        event_data_offsets = np.zeros(n_events + 1, dtype=np.int64)
-        np.cumsum(np.asarray(data_counts, dtype=np.int64), out=event_data_offsets[1:])
-
-        static_counts, st_idx, st_meas = [], [], []
-        if self.do_produce_static_data:
-            for _, row in df.iterrows():
-                si = np.asarray(row["static_indices"], dtype=np.int64)
-                sm = np.asarray(row["static_measurement_indices"], dtype=np.int64)
-                static_counts.append(len(si))
-                st_idx.append(si)
-                st_meas.append(sm)
-        else:
-            static_counts = [0] * n_subjects
-        static_offsets = np.zeros(n_subjects + 1, dtype=np.int64)
-        np.cumsum(np.asarray(static_counts, dtype=np.int64), out=static_offsets[1:])
-
-        if "start_time" in df.columns:
-            start_time_min = (
-                pd.to_datetime(df["start_time"]).map(lambda t: t.timestamp() / 60.0).to_numpy()
-            )
-        else:
-            start_time_min = np.zeros(n_subjects, dtype=np.float64)
-
-        def cat(parts, dtype):
-            return np.concatenate(parts).astype(dtype) if parts else np.zeros(0, dtype)
-
-        def shrink(x):
-            """int64 → int32 when values fit (collation index arithmetic is
-            memory-bound; half-width indices halve the traffic)."""
-            if x.size == 0 or (x.min() >= np.iinfo(np.int32).min and x.max() <= np.iinfo(np.int32).max):
-                return x.astype(np.int32)
-            return x
-
-        raw_vals = cat(dyn_vals, np.float32)
-        observed = ~np.isnan(raw_vals)
-
-        return _CSRData(
-            subject_event_offsets=shrink(subject_event_offsets),
-            time_delta=time_delta,
-            event_data_offsets=shrink(event_data_offsets),
-            dynamic_indices=shrink(cat(dyn_idx, np.int64)),
-            dynamic_measurement_indices=shrink(cat(dyn_meas, np.int64)),
-            dynamic_values=np.where(observed, raw_vals, 0.0).astype(np.float32),
-            dynamic_values_observed=observed,
-            static_offsets=shrink(static_offsets),
-            static_indices=shrink(cat(st_idx, np.int64)),
-            static_measurement_indices=shrink(cat(st_meas, np.int64)),
-            start_time_min=start_time_min,
-        )
 
     # ----------------------------------------------------------- item access
     def __len__(self) -> int:

@@ -330,16 +330,22 @@ def summary(spans: list[Span], small: float = 0.1) -> dict:
     by compiled program the seconds of its trace (with what it traces inside
     itself), lowering, backend compile (less the retrieval) and cache
     retrieval, with its backend compiles and how many of them the persistent
-    cache served. Programs of under ``small`` seconds in all are one row,
-    ``(other)``."""
+    cache served; and the counts a start-up phase's spans carry, summed by
+    phase (``startup/dataset_read``: subjects, events, data elements).
+    Programs of under ``small`` seconds in all are one row, ``(other)``."""
     own = self_seconds(spans)
     by_seq = {s.seq: s for s in spans}
     phases: dict[str, float] = {}
+    counts: dict[str, dict] = {}
     programs: dict[str, dict] = {}
     for s in spans:
         kind, _, what = s.name.partition("/")
         if kind == "startup":
             phases[s.name] = phases.get(s.name, 0.0) + own[s.seq]
+            if s.counts:
+                summed = counts.setdefault(s.name, {})
+                for k, n in s.counts.items():
+                    summed[k] = summed.get(k, 0) + n
         elif kind == "compile":
             top = s  # the cache's retrieval counts under its backend event's program
             while (up := by_seq.get(top.parent)) is not None and up.name.startswith("compile/"):
@@ -357,7 +363,7 @@ def summary(spans: list[Span], small: float = 0.1) -> dict:
         other = {k: other[k] + row.get(k, 1) for k in other}  # "programs" counts the rows folded in
     if other["programs"]:
         programs["(other)"] = other
-    return {"phases": phases, "compile": dict(sorted(programs.items(), key=lambda kv: -seconds(kv[1])))}
+    return {"phases": phases, "counts": counts, "compile": dict(sorted(programs.items(), key=lambda kv: -seconds(kv[1])))}
 
 
 def seconds_since_process_start() -> float | None:

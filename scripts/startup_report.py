@@ -22,9 +22,17 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 ROW = "{:<44}{:>9}{:>9}{:>9}{:>11}{:>9}{:>6}"
 
 
+def per_event(phase: str, seconds: float, counts: dict) -> str:
+    """What a phase that counts events cost for its size: the dataset read's
+    events, and microseconds an event."""
+    n = counts.get(phase, {}).get("events")
+    return f"  {n:,} events, {1e6 * seconds / n:.2f} us an event" if n else ""
+
+
 def table(summary: dict) -> str:
+    counts = summary.get("counts", {})  # a train_log.jsonl written before phases carried counts has none
     lines = [ROW.format("phase", "self_s", *[""] * 5)]
-    lines += [ROW.format(name, f"{s:.2f}", *[""] * 5) for name, s in summary["phases"].items()]
+    lines += [ROW.format(name, f"{s:.2f}", *[""] * 5).rstrip() + per_event(name, s, counts) for name, s in summary["phases"].items()]
     lines.append(ROW.format("compiled program", "trace", "lower", "backend", "cache_load", "compiles", "hits"))
     for program, r in summary["compile"].items():
         times = [f"{r[k]:.2f}" for k in ("trace", "lower", "backend", "cache_load")]

@@ -9,11 +9,14 @@ batch values, padding sides, subsequence sampling, and the vectorized
 collation fast path.
 """
 
+import dataclasses
 import shutil
 from pathlib import Path
 
 import numpy as np
 import pandas as pd
+import pyarrow as pa
+import pyarrow.parquet as pq
 import pytest
 
 from eventstreamgpt_tpu.data import JaxDataset, PytorchDatasetConfig
@@ -259,3 +262,395 @@ class TestTaskRestriction:
         out = JaxDataset._build_task_cached_df(pd.DataFrame(task_rows), raw)
         assert len(out) == 0
         assert "subject_id" in out.columns and "time" in out.columns and "label" in out.columns
+
+
+# --------------------------------------------- the Arrow reader's parity
+# The oracle: how the reader built its arrays before it read Arrow lists
+# directly (pandas rows, a Python loop over every event, then concatenation),
+# kept here verbatim but for its inputs and the quarantine file's directory.
+def _oracle_frame(config, split):
+    save_dir = Path(config.save_dir)
+    if config.task_df_name is not None:
+        task_dir = save_dir / "DL_reps" / "for_task" / config.task_df_name
+        files = sorted(task_dir.glob(f"{split}*.parquet"))
+    else:
+
+        def chunk_key(fp: Path):
+            stem, _, suffix = fp.stem.rpartition("_")
+            return (stem, int(suffix)) if suffix.isdigit() else (fp.stem, -1)
+
+        files = sorted((save_dir / "DL_reps").glob(f"{split}*.parquet"), key=chunk_key)
+    return pd.concat([pd.read_parquet(fp) for fp in files], ignore_index=True)
+
+
+def _oracle_to_time_deltas(df):
+    if "time_delta" in df.columns:
+        return df
+
+    def convert(times):
+        times = np.asarray(times, dtype=np.float64)
+        if len(times) == 0:
+            return times.astype(np.float32)
+        deltas = np.empty_like(times, dtype=np.float32)
+        deltas[:-1] = (times[1:] - times[:-1]).astype(np.float32)
+        deltas[-1] = 1.0
+        return deltas
+
+    df = df.copy()
+    df["time_delta"] = df["time"].map(convert)
+    if "start_time" in df.columns:
+        first_offset = df["time"].map(lambda t: float(t[0]) if len(t) else 0.0)
+        df["start_time"] = pd.to_datetime(df["start_time"]) + pd.to_timedelta(first_offset, unit="m")
+    return df.drop(columns=["time"])
+
+
+def _oracle_flatten(df, do_produce_static_data):
+    n_subjects = len(df)
+    event_counts = np.asarray([len(r) for r in df["time_delta"]], dtype=np.int64)
+    subject_event_offsets = np.zeros(n_subjects + 1, dtype=np.int64)
+    np.cumsum(event_counts, out=subject_event_offsets[1:])
+    time_delta = (
+        np.concatenate([np.asarray(r, dtype=np.float32) for r in df["time_delta"]])
+        if n_subjects
+        else np.zeros(0, np.float32)
+    )
+    data_counts, dyn_idx, dyn_meas, dyn_vals = [], [], [], []
+    for _, row in df.iterrows():
+        for ev_i, ev_m, ev_v in zip(row["dynamic_indices"], row["dynamic_measurement_indices"], row["dynamic_values"]):
+            ev_i = np.asarray(ev_i if ev_i is not None else [], dtype=np.int64)
+            ev_m = np.asarray(ev_m if ev_m is not None else [], dtype=np.int64)
+            if ev_v is None:
+                ev_v = np.full(len(ev_i), np.nan, dtype=np.float32)
+            else:
+                ev_v = np.asarray([np.nan if v is None else v for v in ev_v], dtype=np.float32)
+            data_counts.append(len(ev_i))
+            dyn_idx.append(ev_i)
+            dyn_meas.append(ev_m)
+            dyn_vals.append(ev_v)
+    event_data_offsets = np.zeros(len(data_counts) + 1, dtype=np.int64)
+    np.cumsum(np.asarray(data_counts, dtype=np.int64), out=event_data_offsets[1:])
+
+    static_counts, st_idx, st_meas = [], [], []
+    if do_produce_static_data:
+        for _, row in df.iterrows():
+            si = np.asarray(row["static_indices"], dtype=np.int64)
+            sm = np.asarray(row["static_measurement_indices"], dtype=np.int64)
+            static_counts.append(len(si))
+            st_idx.append(si)
+            st_meas.append(sm)
+    else:
+        static_counts = [0] * n_subjects
+    static_offsets = np.zeros(n_subjects + 1, dtype=np.int64)
+    np.cumsum(np.asarray(static_counts, dtype=np.int64), out=static_offsets[1:])
+    if "start_time" in df.columns:
+        start_time_min = pd.to_datetime(df["start_time"]).map(lambda t: t.timestamp() / 60.0).to_numpy()
+    else:
+        start_time_min = np.zeros(n_subjects, dtype=np.float64)
+
+    def cat(parts, dtype):
+        return np.concatenate(parts).astype(dtype) if parts else np.zeros(0, dtype)
+
+    def shrink(x):
+        if x.size == 0 or (x.min() >= np.iinfo(np.int32).min and x.max() <= np.iinfo(np.int32).max):
+            return x.astype(np.int32)
+        return x
+
+    raw_vals = cat(dyn_vals, np.float32)
+    observed = ~np.isnan(raw_vals)
+    return dict(
+        subject_event_offsets=shrink(subject_event_offsets),
+        time_delta=time_delta,
+        event_data_offsets=shrink(event_data_offsets),
+        dynamic_indices=shrink(cat(dyn_idx, np.int64)),
+        dynamic_measurement_indices=shrink(cat(dyn_meas, np.int64)),
+        dynamic_values=np.where(observed, raw_vals, 0.0).astype(np.float32),
+        dynamic_values_observed=observed,
+        static_offsets=shrink(static_offsets),
+        static_indices=shrink(cat(st_idx, np.int64)),
+        static_measurement_indices=shrink(cat(st_meas, np.int64)),
+        start_time_min=start_time_min,
+    )
+
+
+def oracle_read(config, split, tasks, malformed_dir):
+    df = _oracle_frame(config, split)
+    do_produce_static_data = "static_indices" in df.columns
+    df = _oracle_to_time_deltas(df)
+    lens = df["time_delta"].map(len)
+    df = df[lens >= config.min_seq_len].reset_index(drop=True)
+
+    def _real_deltas(row):
+        return row[:-1] if len(row) > 1 else row[:0]
+
+    all_deltas = (
+        np.concatenate([_real_deltas(np.asarray(r)) for r in df["time_delta"]]) if len(df) else np.asarray([1.0])
+    )
+    if len(all_deltas) == 0:
+        all_deltas = np.asarray([1.0])
+    min_delta = float(all_deltas.min()) if len(all_deltas) else 1.0
+    if min_delta <= 0:
+        bad_mask = df["time_delta"].map(
+            lambda r: float(np.min(_real_deltas(np.asarray(r)))) <= 0 if len(r) > 1 else False
+        )
+        bad = df[bad_mask]
+        print(
+            f"WARNING: Observed inter-event times <= 0 for {len(bad)} subjects!\n"
+            f"ESD Subject IDs: {', '.join(str(x) for x in bad['subject_id'].tolist())}\n"
+            f"Global min: {min_delta}"
+        )
+        fp = Path(malformed_dir) / f"malformed_data_{split}.parquet"
+        bad.to_parquet(fp)
+        print(f"Wrote malformed data records to {fp}")
+        print("Removing malformed subjects")
+        df = df[~bad_mask].reset_index(drop=True)
+        all_deltas = np.concatenate([_real_deltas(np.asarray(r)) for r in df["time_delta"]])
+    with np.errstate(divide="ignore", invalid="ignore"):
+        logs = np.log(all_deltas[all_deltas > 0])
+    mean = float(logs.mean()) if len(logs) else 0.0
+    std = float(logs.std(ddof=1)) if len(logs) > 1 else 1.0
+    if config.train_subset_size not in (None, "FULL") and split == "train":
+        if isinstance(config.train_subset_size, int) and config.train_subset_size > 0:
+            n = min(config.train_subset_size, len(df))
+        else:
+            n = int(round(config.train_subset_size * len(df)))
+        df = df.sample(n=n, random_state=config.train_subset_seed).reset_index(drop=True)
+    data = _oracle_flatten(df, do_produce_static_data)
+    data_lens = np.diff(data["event_data_offsets"])
+    static_lens = np.diff(data["static_offsets"])
+    return dict(
+        data=data,
+        subject_ids=df["subject_id"].tolist(),
+        stream_labels={t: np.asarray(df[t].to_numpy()) for t in tasks} if tasks else None,
+        max_n_dynamic=config.max_n_dynamic or max(int(data_lens.max()) if len(data_lens) else 1, 1),
+        max_n_static=config.max_n_static or max(int(static_lens.max()) if len(static_lens) else 1, 1),
+        mean_log_inter_event_time_min=mean,
+        std_log_inter_event_time_min=std,
+    )
+
+
+# --------------------------------------------------------- parity fixtures
+_T0 = pd.Timestamp("2021-03-04 05:06:07.123456")
+
+
+def _synthetic(root: Path) -> Path:
+    from eventstreamgpt_tpu.data.synthetic import write_synthetic_dataset
+
+    return write_synthetic_dataset(
+        root, {"train": 24, "tuning": 6}, n_labs=40, n_meds=10, mean_seq_len=10, max_seq_len=40,
+        mean_obs_per_event=4, max_obs_per_event=8, seed=5,
+    )
+
+
+def _write_rows(root: Path, rows: list[dict], split: str = "train") -> None:
+    for fp in (root / "DL_reps").glob(f"{split}*.parquet"):
+        fp.unlink()
+    pd.DataFrame(rows).to_parquet(root / "DL_reps" / f"{split}_0.parquet")
+
+
+def _row(sid, times, idx, meas, vals, start=_T0):
+    return {
+        "subject_id": sid, "start_time": start, "time": times, "dynamic_indices": idx,
+        "dynamic_measurement_indices": meas, "dynamic_values": vals,
+        "static_indices": [60 + sid % 3], "static_measurement_indices": [4],
+    }
+
+
+def fx_synthetic(root):
+    """data/synthetic.py's cache: start times, NaN values, static codes."""
+    _synthetic(root)
+    return {}
+
+
+def fx_none_values(root):
+    """pandas-written lists with None values and a null value list beside
+    non-empty indices."""
+    _synthetic(root)
+    _write_rows(root, [
+        _row(1, [0.0, 5.0, 7.5, 9.0], [[1, 5], [2], [3, 4, 6], [7]], [[1, 2], [1], [1, 2, 2], [1]],
+             [[None, 1.5], None, [None, 0.25, None], [None]]),
+        _row(2, [0.0, 2.0, 3.0], [[2, 8], [3], [4, 9]], [[1, 2], [1], [1, 2]],
+             [[None, -2.0], [None], None], start=_T0 + pd.Timedelta(days=3)),
+        _row(3, [1.0, 4.0, 8.0], [[1], [2, 7], [3]], [[1], [1, 2], [1]], [[None], [None, 3.0], [None]]),
+    ])
+    return {}
+
+
+def fx_empty_and_short(root):
+    """An empty event, an event whose lists are null, and a subject shorter
+    than ``min_seq_len``."""
+    _synthetic(root)
+    _write_rows(root, [
+        _row(1, [0.0, 1.0, 3.0, 6.0], [[1], [], [2, 5], None], [[1], [], [1, 2], None],
+             [[None], [], [None, 0.5], None]),
+        _row(2, [0.0, 1.0], [[1], [2]], [[1], [1]], [[None], [None]]),
+        _row(3, [0.0, 2.0, 3.0], [[3, 6], [4], []], [[1, 2], [1], []], [[None, 1.0], [None], []]),
+    ])
+    return {"min_seq_len": 3}
+
+
+def fx_malformed(root):
+    """Subjects with a zero and a negative inter-event time: quarantined,
+    reported and written out."""
+    _synthetic(root)
+    rows = [_row(s, list(np.cumsum(np.arange(1.0, 5.0))), [[1], [2], [3], [4]], [[1]] * 4, [[None]] * 4) for s in range(6)]
+    rows[1]["time"] = [0.0, 5.0, 5.0, 9.0]
+    rows[3]["time"] = [0.0, 5.0, 4.0, 9.0]
+    rows[4]["time"] = [0.0, -1.0, 4.0, 9.0]
+    rows.insert(0, _row(9, [0.0], [[1]], [[1]], [[None]]))  # shorter than min_seq_len: filtered before the quarantine
+    _write_rows(root, rows)
+    return {"min_seq_len": 2}
+
+
+def fx_subset_int(root):
+    _synthetic(root)
+    return {"train_subset_size": 7, "train_subset_seed": 3, "do_include_start_time_min": True}
+
+
+def fx_subset_float(root):
+    _synthetic(root)
+    return {"train_subset_size": 0.4, "train_subset_seed": 11}
+
+
+def _to_large(t: pa.DataType) -> pa.DataType:
+    return pa.large_list(_to_large(t.value_type)) if pa.types.is_list(t) else t
+
+
+def fx_large_list(root):
+    """A ``large_list`` schema (what Polars-written caches can hold)."""
+    _synthetic(root)
+    fp = root / "DL_reps" / "train_0.parquet"
+    table = pq.read_table(fp)
+    pq.write_table(table.cast(pa.schema([f.with_type(_to_large(f.type)) for f in table.schema])), fp)
+    return {}
+
+
+def fx_chunks(root):
+    """Chunk files ``train_0``, ``train_2`` and ``train_10``, read in that
+    order."""
+    _synthetic(root)
+    fp = root / "DL_reps" / "train_0.parquet"
+    df = pd.read_parquet(fp)
+    fp.unlink()
+    for k, part in zip((0, 2, 10), (df.iloc[:8], df.iloc[8:16], df.iloc[16:])):
+        part.to_parquet(root / "DL_reps" / f"train_{k}.parquet")
+    return {}
+
+
+def fx_time_delta_column(root):
+    """A cache that stores ``time_delta`` itself (float64), not ``time``."""
+    _synthetic(root)
+    fp = root / "DL_reps" / "train_0.parquet"
+    df = pd.read_parquet(fp)
+    df["time_delta"] = [np.append(np.diff(t), 1.0) for t in df["time"]]
+    df.drop(columns=["time"]).to_parquet(fp)
+    return {}
+
+
+def fx_values_all_null(root):
+    """A value column with no value at all (Arrow's null type inside the
+    lists), and no static columns."""
+    _synthetic(root)
+    rows = [_row(s, [0.0, 1.0 + s, 4.0 + s], [[1, 2], [3], [4]], [[1, 2], [1], [1]], [[None, None], None, [None]]) for s in range(3)]
+    for r in rows:
+        del r["static_indices"], r["static_measurement_indices"]
+    _write_rows(root, rows)
+    return {}
+
+
+def fx_reference_sample(root):
+    """The reference ETL's own sample cache."""
+    for name in ("vocabulary_config.json", "inferred_measurement_configs.json"):
+        shutil.copy(REF_SAMPLE / name, root / name)
+    shutil.copytree(REF_SAMPLE / "DL_reps", root / "DL_reps", ignore=shutil.ignore_patterns("for_task"))
+    return {"split": "tuning"}
+
+
+def fx_task(root):
+    """The task path: windows cut once into ``for_task/``, then read back."""
+    _synthetic(root)
+    raw = pd.read_parquet(root / "DL_reps" / "train_0.parquet")
+    rows = []
+    for i, (_, r) in enumerate(raw.iterrows()):
+        t = np.asarray(r["time"])
+        rows.append({
+            "subject_id": r["subject_id"], "start_time": r["start_time"] + pd.Timedelta(minutes=float(t[1])),
+            "end_time": r["start_time"] + pd.Timedelta(minutes=float(t[-2])), "flag": bool(i % 3), "grade": i % 4,
+        })
+    (root / "task_dfs").mkdir()
+    pd.DataFrame(rows).to_parquet(root / "task_dfs" / "t.parquet")
+    return {"task_df_name": "t"}
+
+
+PARITY_FIXTURES = {f.__name__[3:]: f for f in (
+    fx_synthetic, fx_none_values, fx_empty_and_short, fx_malformed, fx_subset_int, fx_subset_float,
+    fx_large_list, fx_chunks, fx_time_delta_column, fx_values_all_null, fx_reference_sample, fx_task,
+)}
+
+
+def assert_bit_equal(ds, want):
+    for field in dataclasses.fields(ds.data):
+        got, ref = getattr(ds.data, field.name), want["data"][field.name]
+        assert (got.dtype, got.shape) == (ref.dtype, ref.shape), field.name
+        assert got.tobytes() == ref.tobytes(), field.name
+        assert got.flags.writeable, field.name
+    assert ds.subject_ids == want["subject_ids"]
+    assert [type(s) for s in ds.subject_ids] == [type(s) for s in want["subject_ids"]]
+    if want["stream_labels"] is None:
+        assert ds.stream_labels is None
+    else:
+        assert ds.stream_labels.keys() == want["stream_labels"].keys()
+        for t, ref in want["stream_labels"].items():
+            assert ds.stream_labels[t].dtype == ref.dtype and ds.stream_labels[t].tobytes() == ref.tobytes(), t
+    for name in ("max_n_dynamic", "max_n_static", "mean_log_inter_event_time_min", "std_log_inter_event_time_min"):
+        assert np.float64(getattr(ds, name)).tobytes() == np.float64(want[name]).tobytes(), name
+
+
+class TestArrowReaderParity:
+    @pytest.mark.parametrize("fixture", list(PARITY_FIXTURES))
+    def test_arrays_match_the_per_event_loop(self, fixture, tmp_path, capsys):
+        root = tmp_path / "ds"
+        root.mkdir()
+        kwargs = PARITY_FIXTURES[fixture](root)
+        split = kwargs.pop("split", "train")
+        cfg = make_config(root, max_seq_len=16, **kwargs)
+        ds = JaxDataset(cfg, split)
+        said = capsys.readouterr().out
+        (tmp_path / "oracle").mkdir()
+        want = oracle_read(cfg, split, ds.tasks, tmp_path / "oracle")
+        oracle_said = capsys.readouterr().out
+        assert_bit_equal(ds, want)
+        assert said == oracle_said.replace(str(tmp_path / "oracle"), str(root))
+        malformed = root / f"malformed_data_{split}.parquet"
+        assert malformed.exists() == (fixture == "malformed")
+        if fixture == "malformed":
+            assert "for 3 subjects" in said and "ESD Subject IDs: 1, 3, 4" in said
+            assert pq.read_table(malformed).equals(pq.read_table(tmp_path / "oracle" / malformed.name))
+        if fixture == "task":
+            assert ds.tasks == ["flag", "grade"] and sorted((root / "DL_reps" / "for_task" / "t").glob("train*"))
+            assert_bit_equal(JaxDataset(cfg, split), want)  # the cached for_task/ files, read again
+
+    def test_record_counts_the_read(self, tmp_path):
+        from eventstreamgpt_tpu.utils import scopes
+
+        _synthetic(tmp_path)
+        ds = JaxDataset(make_config(tmp_path), "train")
+        (span,) = [s for s in scopes.recorded() if s.name == "startup/dataset_read"][-1:]
+        d = ds.data
+        assert span.counts == {"subjects": len(ds), "events": len(d.time_delta), "data": len(d.dynamic_indices)}
+
+    def test_an_event_whose_measurement_list_is_null_is_empty(self, tmp_path):
+        """Where the loop would have misaligned the arrays (indices kept,
+        measurements dropped), the event holds no element."""
+        _synthetic(tmp_path)
+        _write_rows(tmp_path, [_row(1, [0.0, 1.0, 2.0], [[1, 2], [3, 4], [5]], [[1, 2], None, [1]], [[None, 0.5], [None, 1.0], [None]])])
+        d = JaxDataset(make_config(tmp_path), "train").data
+        np.testing.assert_array_equal(d.event_data_offsets, [0, 2, 2, 3])
+        np.testing.assert_array_equal(d.dynamic_indices, [1, 2, 5])
+        np.testing.assert_array_equal(d.dynamic_measurement_indices, [1, 2, 1])
+        np.testing.assert_array_equal(d.dynamic_values_observed, [False, True, False])
+
+    def test_mismatched_event_lists_are_refused(self, tmp_path):
+        _synthetic(tmp_path)
+        _write_rows(tmp_path, [_row(1, [0.0, 1.0], [[1, 2], [3]], [[1], [1]], [[None, None], [None]])])
+        with pytest.raises(ValueError, match="measurements"):
+            JaxDataset(make_config(tmp_path), "train")

@@ -327,8 +327,8 @@ class TestChunkOrdering:
 
         for i in (0, 2, 10):
             pd.DataFrame({"subject_id": [i]}).to_parquet(tmp_path / f"train_{i}.parquet")
-        df = JaxDataset._read_dl_reps(tmp_path, "train")
-        assert df["subject_id"].tolist() == [0, 2, 10], "lexicographic order would give [0, 10, 2]"
+        table = JaxDataset._read_dl_reps(tmp_path, "train")
+        assert table.column("subject_id").to_pylist() == [0, 2, 10], "lexicographic order would give [0, 10, 2]"
 
 
 # ----------------------------------------------------- slow: bit-identity

@@ -313,7 +313,9 @@ def test_train_writes_its_start_up_and_its_recompile_and_the_parents_visited_sha
     capsys.readouterr()
     assert startup_report.main(["--log", str(tmp_path / "pretrain" / "train_log.jsonl")]) == 0
     printed = capsys.readouterr().out.splitlines()
-    assert printed[0].split() == ["phase", "self_s"] and any(line.split()[:1] == ["startup/dataset_read"] for line in printed)
+    assert printed[0].split() == ["phase", "self_s"] and any(line.split()[:1] == ["startup/dataset_read"] and line.endswith(" us an event") for line in printed)
+    counts = startup["startup"]["counts"]["startup/dataset_read"]
+    assert counts["events"] > counts["subjects"] > 0 and counts["data"] >= counts["events"]
     assert any(line.split()[0] == "chunk_step" and line.split()[-2:] == ["1", "0"] for line in printed)  # compiled once, no cache
     assert json.loads(printed[-1]) == recompile
 
