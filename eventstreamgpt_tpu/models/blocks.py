@@ -7,7 +7,8 @@ docs/layer_kinds.md). Under ``hc_mult`` > 1 the state is that many residual
 streams, a tuple of ``[B, S, C]`` planes, and each part reads and writes them
 through learned maps of its own (`models/hyper_connections.py`). Each kind is
 a small module: latent attention (`models/latent_attention.py`), the Mamba-2
-mixer (`models/state_space.py`), grouped-query attention (here), the gated and
+mixer (`models/state_space.py`), the gated short convolution
+(`models/short_conv.py`), grouped-query attention (here), the gated and
 the routed feed-forward (`models/moe.py`). The classic block (`InnerBlock`: LayerNorm, multi-head
 attention, GELU MLP, every cache branch) is not migrated here; the encoder
 builds one stack or the other.
@@ -28,8 +29,9 @@ from jax.ad_checkpoint import checkpoint_name
 from ..utils.scopes import scope
 from .config import StructuredTransformerConfig
 from .hyper_connections import hyper_connected
-from .latent_attention import LatentAttention, RMSNorm, bias_free_dense, causal_core
+from .latent_attention import LatentAttention, RMSNorm, bias_free_dense, causal_core, rotate, segment_positions
 from .moe import RoutedFeedForward, SwiGLU
+from .short_conv import ShortConvMixer
 from .state_space import Mamba2Mixer
 from .transformer import ATTENTION_CHECKPOINT_NAME, NO_DECODE_STATE
 
@@ -38,9 +40,14 @@ class GroupedQueryAttention(nn.Module):
     """``softmax(q k^T / sqrt(d)) v`` with ``num_attention_heads`` query heads
     of ``head_dim`` over ``num_key_value_heads`` key/value heads (query head
     ``i`` reads key/value head ``i // (heads / kv heads)``), causal inside the
-    packed segment, no bias and no rotary embedding (``nemotron_h`` applies
-    none). The core is `latent_attention.causal_core`; the key/value heads are
-    repeated to the query heads before it (the flash op takes one head count)."""
+    packed segment, no bias. Two options, both off by default (``nemotron_h``
+    applies neither): ``mha_qk_norm``, an RMSNorm of each query and key head
+    over its ``head_dim`` (``layer_norm_epsilon``, a scale of its own for q and
+    for k), and ``mha_rope``, RoPE over the whole head at ``rope_theta`` by the
+    event's index inside its segment (`latent_attention.rotate`), in that order
+    (LFM2's ``lfm2_moe``). The core is `latent_attention.causal_core`; the
+    key/value heads are repeated to the query heads before it (the flash op
+    takes one head count)."""
 
     config: StructuredTransformerConfig
 
@@ -57,6 +64,12 @@ class GroupedQueryAttention(nn.Module):
             key = dense(kv_heads * d, "k_proj")(x).reshape(batch, seq_len, kv_heads, d)
             value = dense(kv_heads * d, "v_proj")(x).reshape(batch, seq_len, kv_heads, d)
         with scope("attn_global"):
+            if cfg.mha_qk_norm:
+                query = RMSNorm(cfg.layer_norm_epsilon, query.dtype, name="q_layernorm")(query)
+                key = RMSNorm(cfg.layer_norm_epsilon, key.dtype, name="k_layernorm")(key)
+            if cfg.mha_rope:
+                positions = segment_positions(segment_ids, batch, seq_len)
+                query, key = (rotate(a, positions, cfg.rope_theta) for a in (query, key))
             key, value = (jnp.repeat(a, heads // kv_heads, axis=2) for a in (key, value))
             out = causal_core(cfg, query, key, value, attention_mask, segment_ids, "grouped-query attention")
             out = checkpoint_name(out, ATTENTION_CHECKPOINT_NAME)
@@ -65,7 +78,12 @@ class GroupedQueryAttention(nn.Module):
 
 
 # A mixer kind's module and the name of its parameters in the layer's tree.
-MIXERS = {"latent": (LatentAttention, "self_attn"), "mha": (GroupedQueryAttention, "self_attn"), "ssm": (Mamba2Mixer, "mixer")}
+MIXERS = {
+    "latent": (LatentAttention, "self_attn"),
+    "mha": (GroupedQueryAttention, "self_attn"),
+    "ssm": (Mamba2Mixer, "mixer"),
+    "conv": (ShortConvMixer, "conv"),
+}
 
 
 class KindsBlock(nn.Module):

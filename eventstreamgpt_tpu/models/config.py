@@ -252,6 +252,11 @@ class StructuredTransformerConfig(JSONableMixin):
         ssm_state_size: int | None = None,
         mamba_conv_kernel: int = 4,
         mamba_chunk_size: int = 128,
+        conv_L_cache: int = 3,
+        conv_bias: bool = False,
+        mha_qk_norm: bool = False,
+        mha_rope: bool = False,
+        moe_topk_norm_eps: float = 0.0,
         # Model output configuration
         TTE_generation_layer_type: str = TimeToEventGenerationHeadType.EXPONENTIAL,
         TTE_lognormal_generation_num_components: int | None = None,
@@ -502,6 +507,8 @@ class StructuredTransformerConfig(JSONableMixin):
         self.ssm_state_size = ssm_state_size
         self.mamba_conv_kernel = mamba_conv_kernel
         self.mamba_chunk_size = mamba_chunk_size
+        self.conv_L_cache, self.conv_bias = conv_L_cache, conv_bias
+        self.mha_qk_norm, self.mha_rope = mha_qk_norm, mha_rope
         self.num_attention_heads = num_attention_heads
         self._set_layer_kinds(mixer_types, ffn_types, norm_type)
         self.moe_intermediate_size = moe_intermediate_size
@@ -512,6 +519,7 @@ class StructuredTransformerConfig(JSONableMixin):
         self.num_experts_per_tok = num_experts_per_tok
         self.routed_scaling_factor = routed_scaling_factor
         self.norm_topk_prob = norm_topk_prob
+        self.moe_topk_norm_eps = moe_topk_norm_eps
         self.moe_expert_form = moe_expert_form
         self.moe_shared_expert_intermediate_size = moe_shared_expert_intermediate_size
         if moe_expert_form not in self.EXPERT_FORMS:
@@ -722,7 +730,7 @@ class StructuredTransformerConfig(JSONableMixin):
 
         return jnp.bfloat16 if self.precision == "bf16" else jnp.float32
 
-    MIXER_KINDS = ("mha", "latent", "ssm", "none")
+    MIXER_KINDS = ("mha", "latent", "ssm", "conv", "none")
     FFN_KINDS = ("mlp", "swiglu", "routed", "none")
     NORM_KINDS = ("layer_norm", "rms_norm")
     EXPERT_FORMS = ("swiglu", "relu2")
@@ -731,7 +739,7 @@ class StructuredTransformerConfig(JSONableMixin):
         """Each layer's mixer and feed-forward kind, in the attention types'
         mini-language. The classic block (`InnerBlock`) is every default. Under
         ``rms_norm`` the stack is built of the kinds block (`models/blocks.py`):
-        a layer names a mixer (``latent``, ``ssm``, ``mha`` with grouped
+        a layer names a mixer (``latent``, ``ssm``, ``conv``, ``mha`` with grouped
         key/value heads, or ``none``) and a feed-forward (``swiglu``,
         ``routed`` or ``none``), at least one of the two, and each mixer kind
         in use has its sizes (docs/layer_kinds.md)."""
@@ -754,7 +762,7 @@ class StructuredTransformerConfig(JSONableMixin):
         if ("latent" in mixers) != (self.qk_nope_head_dim is not None) or not (classic or kinds):
             raise ValueError(
                 "layer kinds: either every default (mha, mlp, layer_norm) or, under rms_norm, mixers of "
-                "latent (with the latent ranks and head dims) / ssm / mha / none and feed-forwards of "
+                "latent (with the latent ranks and head dims) / ssm / conv / mha / none and feed-forwards of "
                 f"swiglu / routed / none; got {mixer_types}, {ffn_types}, {norm_type}, "
                 f"qk_nope_head_dim {self.qk_nope_head_dim}"
             )
@@ -769,6 +777,8 @@ class StructuredTransformerConfig(JSONableMixin):
         if not kinds:
             if self.num_key_value_heads not in (None, self.num_attention_heads):
                 raise ValueError("the classic block has as many key/value heads as query heads")
+            if self.mha_qk_norm or self.mha_rope:
+                raise ValueError("mha_qk_norm and mha_rope are the kinds block's grouped-query attention's")
             return
         if self.structured_event_processing_mode != StructuredEventProcessingMode.CONDITIONALLY_INDEPENDENT:
             raise ValueError("the kinds block serves the conditionally-independent model only")
@@ -793,6 +803,11 @@ class StructuredTransformerConfig(JSONableMixin):
                 )
             if self.mamba_conv_kernel < 1 or self.mamba_chunk_size < 1:
                 raise ValueError("mamba_conv_kernel and mamba_chunk_size are at least 1")
+        if "conv" in mixers and (type(self.conv_L_cache) is not int or self.conv_L_cache < 1 or self.conv_bias):
+            raise ValueError(
+                f"mixer 'conv' needs conv_L_cache, its taps, of at least 1, and no bias (conv_bias true is not built); "
+                f"got {self.conv_L_cache}, {self.conv_bias}"
+            )
 
     @property
     def uses_layer_kinds(self) -> bool:

@@ -38,6 +38,7 @@ from .impl_select import resolve_impl
 # tests/models/test_hybrid_kinds.py, and a sweep at the new shapes is open
 # (PERF.md section 7, PR 32).
 _TILES = {"m": (512, 256, 128), "k": (1024, 512, 256, 128), "n": (768, 512, 256, 128)}
+ROW_TILE = _TILES["m"][0]  # a grid step's rows wherever a walk's chunk is a multiple of it
 
 
 def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:

@@ -40,6 +40,8 @@ SCOPES = (
     "ssm_conv",  # its causal convolution inside the segment, silu, the splits
     "ssm_scan",  # softplus, the decays, the chunked scan, the D skip
     "ssm_gate",  # the gate and the grouped norm
+    "conv_proj",  # a gated short convolution's input and output projections
+    "conv_gate",  # its two gates and its causal taps inside the segment
     "hc_maps",  # a hyper-connected sublayer's maps: the streams' norm, Phi's product, the sigmoids, Sinkhorn
     "hc_mix",  # its pre-mix of the streams, its post/res mix back into them, the sum before ln_f
     "mlp",  # the feed-forward block (the classic MLP, the dense SwiGLU)

@@ -613,6 +613,40 @@ def test_streamed_blocks_at_the_cells_shapes(one_chip, monkeypatch, kind):
     assert not [t for t in written if t[1] == "f32" and t[2].endswith("1024,3584") and t[0] > 120e6], "a float32 plane of the streams"
 
 
+# `lfm2_8b_a1b_ep4.pretrain_packed`'s rows: 16 of 1,024 events at hidden 2,048
+CONV_BLOCKS = {
+    # kind: (layer, parameters, Mosaic calls, temporaries in GB at most)
+    "conv + swiglu": (0, 60_827_648, 0, 1.3),
+    "mha + routed": (1, 98_635_936, 12, 1.9),
+    "conv + routed": (2, 104_933_408, 9, 1.7),
+}
+
+
+@pytest.mark.parametrize("kind", list(CONV_BLOCKS))
+def test_conv_hybrid_blocks_at_the_cells_shapes(one_chip, monkeypatch, kind):
+    """The dense, the attention and a convolution's routed block of
+    `benchmark/configs/lfm2_8b_a1b_ep4.json` under its ``block`` remat on
+    ``[16, 1024, 2048]`` bf16, forward and gradient, as the chip's backend
+    traces them (sandbox compile: 1.11, 1.71 and 1.52 GB of temporaries). The
+    gated short convolution is XLA's and holds no Mosaic call; its largest
+    temporary is ``W_in``'s bf16 output (201 MB). The attention block holds the
+    three flash kernels at 32 heads of 64 (the 8 key/value heads repeated
+    before them) and megablox' nine calls (three gated products forward, each
+    one's ``gmm`` and ``tgmm`` backward); nothing of ``[B, H, S, S]`` is written."""
+    layer, n_params, n_calls, temp_gb = CONV_BLOCKS[kind]
+    compiled, counted = _hybrid_block_gradient(one_chip, monkeypatch, layer, name="lfm2_8b_a1b_ep4")
+    assert counted == n_params
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    if kind.startswith("mha"):
+        for kernel in ("flash_attention", "flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+            assert kernel in text
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    written = _temporaries(text)
+    assert not [t for t in written if t[2] in ("16,32,1024,1024", "16,1024,32,1024")]
+    assert max(size for size, *_ in written) < 240e6
+
+
 HEAD_SHAPES = {  # rows, events, hidden; event types, labs, medications, static codes (`benchmark/workloads/*.json`)
     "ci_w1024.pretrain_packed": (16, 1024, 1024, 40, 3500, 500, 16),
     "nemotron_twotower_ep16.pretrain_packed": (16, 1024, 2688, 40, 12827, 3500, 16),

@@ -31,21 +31,24 @@ PARENT_LOSSES = {
 KINDS_CELL = "glm47flash_ep8.pretrain_packed"  # added by PR 28: it has no parent to be equal to
 HYBRID_CELL = "nemotron_twotower_ep16.pretrain_packed"  # added by PR 32; compiled here at six layers, MEMEM*
 STREAMED_CELL = "xing40_a4b_ep8.pretrain_packed"  # added by PR 34; compiled here at its dense and one routed layer
+CONV_CELL = "lfm2_8b_a1b_ep4.pretrain_packed"  # compiled here at its first two layers: conv + dense, attention + routed
 CELLS = sorted(PARENT_LOSSES) + [KINDS_CELL]
 # Scopes a model does not have: CI has no dependency graph, the classic block
 # none of the kinds block's (docs/layer_kinds.md), the kinds block no local layer;
 # GLM's stack has no state-space layer, nemotron_h's no latent attention and no dense feed-forward.
-# Only Xing4.0's block carries residual streams.
+# Only Xing4.0's block carries residual streams, only LFM2's gated short convolutions (and no shared expert).
 SSM = {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate"}
 STREAMS = {"hc_maps", "hc_mix"}
-KINDS = {"attn_latent", "moe_router", "moe_dispatch", "moe_experts", "moe_shared"} | SSM | STREAMS
+CONV = {"conv_proj", "conv_gate"}
+KINDS = {"attn_latent", "moe_router", "moe_dispatch", "moe_experts", "moe_shared"} | SSM | STREAMS | CONV
 ABSENT = {
     "ci_w1024.pretrain_packed": {"dep_graph"} | KINDS,
     "ci_w1024.pretrain_padded": {"dep_graph"} | KINDS,
     "na_w1024.pretrain": KINDS,
-    KINDS_CELL: {"dep_graph", "attn_local"} | SSM | STREAMS,
-    HYBRID_CELL: {"dep_graph", "attn_local", "attn_latent", "mlp"} | STREAMS,
-    STREAMED_CELL: {"dep_graph", "attn_local"} | SSM,
+    KINDS_CELL: {"dep_graph", "attn_local"} | SSM | STREAMS | CONV,
+    HYBRID_CELL: {"dep_graph", "attn_local", "attn_latent", "mlp"} | STREAMS | CONV,
+    STREAMED_CELL: {"dep_graph", "attn_local"} | SSM | CONV,
+    CONV_CELL: {"dep_graph", "attn_local", "attn_latent", "moe_shared"} | SSM | STREAMS,
 }
 # Instructions of the scan body with an op_name and no es. scope, at most:
 # constants and broadcasts the compiler hoists, the scan's own slicing, the
@@ -92,7 +95,7 @@ def _scope(op_name: str):
     return scope_of(op_name)[0]
 
 
-@pytest.mark.parametrize("name", CELLS + [HYBRID_CELL, STREAMED_CELL])
+@pytest.mark.parametrize("name", CELLS + [HYBRID_CELL, STREAMED_CELL, CONV_CELL])
 def test_every_scope_the_model_has_occurs(name, compiled):
     op_names, _ = compiled(name)
     seen = {_scope(n) for n in op_names} - {None}
@@ -112,7 +115,7 @@ def test_all_three_phases_occur_under_the_mlp(name, compiled):
     assert bool(recompute) == (name != "na_w1024.pretrain")
 
 
-@pytest.mark.parametrize("name", CELLS + [HYBRID_CELL, STREAMED_CELL])
+@pytest.mark.parametrize("name", CELLS + [HYBRID_CELL, STREAMED_CELL, CONV_CELL])
 def test_most_of_the_scan_body_is_under_a_scope(name, compiled):
     op_names, _ = compiled(name)
     body = [n for n in op_names if "/while/body/" in n]
