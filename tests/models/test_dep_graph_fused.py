@@ -23,6 +23,7 @@ from eventstreamgpt_tpu.models.transformer import (
 )
 from eventstreamgpt_tpu.ops.band_attention import dep_graph_attention
 
+from .. import _programs as programs
 from .test_na_model import G, make_batch, make_config
 
 
@@ -100,9 +101,9 @@ class TestModelParity:
         batch = make_batch(all_real=False)
         enc_f = NestedAttentionPointProcessTransformer(fused_cfg)
         enc_u = NestedAttentionPointProcessTransformer(unfused_cfg)
-        params = enc_f.init(jax.random.PRNGKey(0), batch)
-        out_f = enc_f.apply(params, batch)
-        out_u = enc_u.apply(params, batch)  # identical param tree
+        params = programs.init(enc_f, jax.random.PRNGKey(0), batch)
+        out_f = programs.apply(enc_f, params, batch)
+        out_u = programs.apply(enc_u, params, batch)  # identical param tree
         np.testing.assert_allclose(
             np.asarray(out_f.last_hidden_state),
             np.asarray(out_u.last_hidden_state),
@@ -115,10 +116,10 @@ class TestModelParity:
         batch = make_batch()
         model_f = NAPPTForGenerativeSequenceModeling(fused_cfg)
         model_u = NAPPTForGenerativeSequenceModeling(unfused_cfg)
-        params = model_f.init(jax.random.PRNGKey(0), batch)
+        params = programs.init(model_f, jax.random.PRNGKey(0), batch)
 
-        loss_f, grads_f = jax.value_and_grad(lambda p: model_f.apply(p, batch).loss)(params)
-        loss_u, grads_u = jax.value_and_grad(lambda p: model_u.apply(p, batch).loss)(params)
+        loss_f, grads_f = programs.call(jax.value_and_grad(lambda p: model_f.apply(p, batch).loss), params)
+        loss_u, grads_u = programs.call(jax.value_and_grad(lambda p: model_u.apply(p, batch).loss), params)
         np.testing.assert_allclose(float(loss_f), float(loss_u), rtol=1e-6)
         for gf, gu in zip(jax.tree_util.tree_leaves(grads_f), jax.tree_util.tree_leaves(grads_u)):
             np.testing.assert_allclose(np.asarray(gf), np.asarray(gu), rtol=2e-4, atol=1e-6)
@@ -130,9 +131,9 @@ class TestModelParity:
         batch = batch.replace(segment_ids=seg)
         enc_f = NestedAttentionPointProcessTransformer(fused_cfg)
         enc_u = NestedAttentionPointProcessTransformer(unfused_cfg)
-        params = enc_f.init(jax.random.PRNGKey(0), batch)
-        out_f = enc_f.apply(params, batch)
-        out_u = enc_u.apply(params, batch)
+        params = programs.init(enc_f, jax.random.PRNGKey(0), batch)
+        out_f = programs.apply(enc_f, params, batch)
+        out_u = programs.apply(enc_u, params, batch)
         np.testing.assert_allclose(
             np.asarray(out_f.last_hidden_state),
             np.asarray(out_u.last_hidden_state),
@@ -145,9 +146,9 @@ class TestModelParity:
         batch = make_batch()
         model_f = NAPPTForGenerativeSequenceModeling(fused_cfg)
         model_u = NAPPTForGenerativeSequenceModeling(unfused_cfg)
-        params = model_f.init(jax.random.PRNGKey(0), batch)
-        loss_f = float(model_f.apply(params, batch).loss)
-        loss_u = float(model_u.apply(params, batch).loss)
+        params = programs.init(model_f, jax.random.PRNGKey(0), batch)
+        loss_f = float(programs.loss(model_f, params, batch))
+        loss_u = float(programs.loss(model_u, params, batch))
         assert abs(loss_f - loss_u) < 5e-2 * max(1.0, abs(loss_u))
 
     def test_cached_decode_matches_fused_uncached(self):
@@ -161,11 +162,12 @@ class TestModelParity:
         batch = make_batch()
         B, L = batch.event_mask.shape
         encoder = NestedAttentionPointProcessTransformer(config)
-        params = encoder.init(jax.random.PRNGKey(0), batch)
-        full = encoder.apply(params, batch)  # fused path
+        params = programs.init(encoder, jax.random.PRNGKey(0), batch)
+        full = programs.apply(encoder, params, batch)  # fused path
 
         prefix = batch.slice((slice(None), slice(0, L - 1)))
-        out1 = encoder.apply(
+        out1 = programs.apply(
+            encoder,
             params,
             prefix,
             past=NAPast(seq_past=init_kv_caches(config, B, max_len=L), dep_graph_past=None),
@@ -177,8 +179,8 @@ class TestModelParity:
             time=t_full[:, L - 1 : L]
         )
         for target in range(1, G):
-            out_t = encoder.apply(
-                params, trimmed, past=past, use_cache=True,
+            out_t = programs.apply(
+                encoder, params, trimmed, past=past, use_cache=True,
                 dep_graph_el_generation_target=target,
             )
             past = out_t.past_key_values
@@ -189,8 +191,8 @@ class TestModelParity:
                 atol=1e-5,
                 err_msg=f"target={target}",
             )
-        out_0 = encoder.apply(
-            params, trimmed, past=past, use_cache=True, dep_graph_el_generation_target=0
+        out_0 = programs.apply(
+            encoder, params, trimmed, past=past, use_cache=True, dep_graph_el_generation_target=0
         )
         np.testing.assert_allclose(
             np.asarray(out_0.last_hidden_state[:, 0, 0]),
@@ -232,9 +234,9 @@ class TestNarrowHeadProjections:
         full_cfg = make_config(head_narrow_projections=False)
         model_n = NAPPTForGenerativeSequenceModeling(narrow_cfg)
         model_f = NAPPTForGenerativeSequenceModeling(full_cfg)
-        params = model_n.init(jax.random.PRNGKey(0), batch)
-        out_n = model_n.apply(params, batch)
-        out_f = model_f.apply(params, batch)
+        params = programs.init(model_n, jax.random.PRNGKey(0), batch)
+        out_n = programs.apply(model_n, params, batch)
+        out_f = programs.apply(model_f, params, batch)
         np.testing.assert_allclose(float(out_n.loss), float(out_f.loss), rtol=1e-6)
         for m in out_n.losses.classification:
             np.testing.assert_allclose(

@@ -353,7 +353,7 @@ class TestPlanePath:
                 out = layer.apply(p, batch)
                 return (out * cot).sum(), out
 
-            (_, out), grads = jax.value_and_grad(apply, has_aux=True)(params)
+            (_, out), grads = jax.jit(jax.value_and_grad(apply, has_aux=True))(params)
             return out, grads, len(plane_calls)
 
         out_g, grads_g, calls_g = run(10**9)

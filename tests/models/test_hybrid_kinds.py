@@ -19,6 +19,8 @@ from eventstreamgpt_tpu.models.moe import RoutedFeedForward, held_experts_output
 from eventstreamgpt_tpu.models.state_space import Mamba2Mixer, _gate_norm, causal_conv, segment_ordinal
 from eventstreamgpt_tpu.ops.ssd_scan import ssd_scan
 
+from .. import _programs as programs
+
 PATTERN = "MEMEM*EME"
 KINDS = {"M": ("ssm", "none"), "E": ("none", "routed"), "*": ("mha", "none")}
 HYBRID = dict(
@@ -74,7 +76,7 @@ def reference_mixer(x, p, cfg, mask, seg):
 
 def mamba_params(module, x, mask, seg, seed=1):
     """Seeded parameters with every leaf away from its initial constant, so that each one's gradient is read."""
-    params = module.init(jax.random.PRNGKey(seed), x, mask, seg)["params"]
+    params = programs.init(module, jax.random.PRNGKey(seed), x, mask, seg)["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 8))
     jitter = lambda a, s: a + s * jax.random.normal(next(keys), a.shape)  # noqa: E731
     return {
@@ -91,14 +93,14 @@ def test_mamba_mixer_agrees_with_the_recurrence(layout):
     seg = seg if layout else None
     module = Mamba2Mixer(cfg)
     params = mamba_params(module, x, mask, seg)
-    got = module.apply({"params": params}, x, mask, seg)
-    want = reference_mixer(x, params, cfg, mask, seg)
+    got = programs.apply(module, {"params": params}, x, mask, seg)
+    want = programs.call(lambda x, p: reference_mixer(x, p, cfg, mask, seg), x, params)
     real = np.asarray(mask)
     np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
 
     weigh = jax.random.normal(jax.random.PRNGKey(2), got.shape) * mask[..., None]
-    grads = jax.grad(lambda p, x: jnp.sum(module.apply({"params": p}, x, mask, seg) * weigh), argnums=(0, 1))(params, x)
-    wants = jax.grad(lambda p, x: jnp.sum(reference_mixer(x, p, cfg, mask, seg) * weigh), argnums=(0, 1))(params, x)
+    grads = programs.call(jax.grad(lambda p, x: jnp.sum(module.apply({"params": p}, x, mask, seg) * weigh), argnums=(0, 1)), params, x)
+    wants = programs.call(jax.grad(lambda p, x: jnp.sum(reference_mixer(x, p, cfg, mask, seg) * weigh), argnums=(0, 1)), params, x)
     flat, flat_want = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (grads, wants))
     assert len(flat) == 9  # the eight leaves and x
     for (path, g), (_, w) in zip(flat, flat_want):
@@ -115,13 +117,13 @@ def test_a_packed_segment_gives_what_it_gives_alone(layout):
     x, _, seg = inputs(layout)
     module = Mamba2Mixer(cfg)
     params = {"params": mamba_params(module, x, None, seg)}
-    packed = module.apply(params, x, None, seg)
+    packed = programs.apply(module, params, x, None, seg)
     for row, ids in enumerate(LAYOUTS[layout]):
         for s in sorted(set(ids)):
             lo, hi = ids.index(s), len(ids) - ids[::-1].index(s)
-            alone = module.apply(params, x[row : row + 1, lo:hi], None, None)
+            alone = programs.apply(module, params, x[row : row + 1, lo:hi], None, None)
             np.testing.assert_allclose(packed[row : row + 1, lo:hi], alone, rtol=2e-5, atol=2e-5)
-    unpacked = module.apply(params, x, None, None)
+    unpacked = programs.apply(module, params, x, None, None)
     last = LAYOUTS[layout][0].index(max(LAYOUTS[layout][0]))
     assert float(jnp.abs(unpacked[0, last:] - packed[0, last:]).max()) > 1e-3
 
@@ -148,12 +150,13 @@ def test_the_scan_pads_a_row_to_whole_chunks_and_walks_rows_in_blocks(monkeypatc
     bmat, cmat = jax.random.normal(keys[3], (B, S, G, N)), jax.random.normal(keys[4], (B, S, G, N))
     seg = jnp.asarray(np.repeat([[0] * 4 + [1] * 9 + [2] * 8], B, axis=0))
     first = jnp.concatenate([jnp.ones((B, 1), bool), seg[:, 1:] != seg[:, :-1]], axis=1)
-    want = ref.recurrence(x, dt, a, bmat, cmat, first)
+    want = programs.call(ref.recurrence, x, dt, a, bmat, cmat, first)
     ordinal = jnp.cumsum(first, axis=1, dtype=jnp.int32)
-    np.testing.assert_allclose(ssd_scan(x, dt, a, bmat, cmat, ordinal, chunk=8), want, rtol=2e-5, atol=2e-5)
+    scan = lambda chunk: programs.call(ssd_scan, x, dt, a, bmat, cmat, ordinal, chunk=chunk)  # noqa: E731
+    np.testing.assert_allclose(scan(8), want, rtol=2e-5, atol=2e-5)
     monkeypatch.setattr(module, "_L_BYTES", 1)  # a block is one row
-    np.testing.assert_allclose(ssd_scan(x, dt, a, bmat, cmat, ordinal, chunk=8), want, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(ssd_scan(x, dt, a, bmat, cmat, ordinal, chunk=128), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(scan(8), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(scan(128), want, rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize(
@@ -176,9 +179,11 @@ def test_the_grouped_norm_over_the_plane_as_it_is_tiled_is_the_grouped_norm(rows
         gated = gated / jnp.sqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps)
         return scale * gated.reshape(y.shape)
 
-    np.testing.assert_allclose(_gate_norm(y, z, scale, groups, eps), plain(y, z, scale), rtol=2e-6, atol=2e-6)
-    got = jax.grad(lambda *v: jnp.sum(_gate_norm(*v, groups, eps) * w), argnums=(0, 1, 2))(y, z, scale)
-    want = jax.grad(lambda *v: jnp.sum(plain(*v) * w), argnums=(0, 1, 2))(y, z, scale)
+    np.testing.assert_allclose(
+        programs.call(_gate_norm, y, z, scale, groups, eps), programs.call(plain, y, z, scale), rtol=2e-6, atol=2e-6
+    )
+    got = programs.call(jax.grad(lambda *v: jnp.sum(_gate_norm(*v, groups, eps) * w), argnums=(0, 1, 2)), y, z, scale)
+    want = programs.call(jax.grad(lambda *v: jnp.sum(plain(*v) * w), argnums=(0, 1, 2)), y, z, scale)
     for g, v in zip(got, want):
         np.testing.assert_allclose(g, v, rtol=2e-5, atol=2e-5)
 
@@ -193,14 +198,14 @@ def test_relu2_routed_layer_agrees_with_the_plain_reference(impl, monkeypatch):
     cfg = hybrid_config(n_routed_experts=6, moe_expert_offset=3)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 32))
     module = RoutedFeedForward(cfg)
-    params = module.init(jax.random.PRNGKey(2), x)
+    params = programs.init(module, jax.random.PRNGKey(2), x)
     assert set(params["params"]) == {"router", "e_score_correction_bias", "experts_up_proj", "experts_down_proj", "shared_experts"}
     assert set(params["params"]["shared_experts"]) == {"up_proj", "down_proj"}
     assert params["params"]["shared_experts"]["up_proj"]["kernel"].shape == (32, 40)
-    got = module.apply(params, x)
-    np.testing.assert_allclose(got, routed_reference(x, params["params"], cfg), rtol=2e-5, atol=2e-5)
-    grads = jax.grad(lambda p: jnp.sum(module.apply(p, x) ** 2))(params)["params"]
-    want = jax.grad(lambda p: jnp.sum(routed_reference(x, p, cfg) ** 2))(params["params"])
+    got = programs.apply(module, params, x)
+    np.testing.assert_allclose(got, programs.call(lambda p: routed_reference(x, p, cfg), params["params"]), rtol=2e-5, atol=2e-5)
+    grads = programs.call(jax.grad(lambda p: jnp.sum(module.apply(p, x) ** 2)), params)["params"]
+    want = programs.call(jax.grad(lambda p: jnp.sum(routed_reference(x, p, cfg) ** 2)), params["params"])
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * float(jnp.abs(w).max()), err_msg=str(path))
 
@@ -212,17 +217,21 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
     whole = hybrid_config(n_routed_experts=16)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, 32))
     module = RoutedFeedForward(whole)
-    params = module.init(jax.random.PRNGKey(4), x)
-    uncut = module.apply(params, x)
+    params = programs.init(module, jax.random.PRNGKey(4), x)
+    uncut = programs.apply(module, params, x)
     p = params["params"]
-    shared = ref.relu2(x, p["shared_experts"], None)
-    total = shared
-    for first in range(16):
-        share = hybrid_config(n_routed_experts=1, moe_expert_offset=first)
-        held = {k: (v[first : first + 1] if k.startswith("experts_") else v) for k, v in p.items()}
-        total = total + RoutedFeedForward(share).apply({"params": held}, x) - shared
-    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(uncut, routed_reference(x, p, whole), rtol=2e-5, atol=2e-5)
+
+    def shares(p):
+        shared = ref.relu2(x, p["shared_experts"], None)
+        total = shared
+        for first in range(16):
+            share = hybrid_config(n_routed_experts=1, moe_expert_offset=first)
+            held = {k: (v[first : first + 1] if k.startswith("experts_") else v) for k, v in p.items()}
+            total = total + RoutedFeedForward(share).apply({"params": held}, x) - shared
+        return total
+
+    np.testing.assert_allclose(programs.call(shares, p), uncut, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(uncut, programs.call(lambda p: routed_reference(x, p, whole), p), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
@@ -232,8 +241,8 @@ def test_no_row_is_dropped_when_every_row_chooses_the_same_relu2_experts(impl):
     w = [jax.random.normal(jax.random.PRNGKey(6 + i), s) * 0.3 for i, s in enumerate([(held, h, inner), (held, inner, h)])]
     chosen = jnp.broadcast_to(jnp.arange(k), (n, k))
     weights = jnp.full((n, k), 0.25)
-    got, counters = held_experts_output(rows, chosen, weights, *w, offset=0, impl=impl)
-    want = sum(0.25 * (jnp.square(jax.nn.relu(rows @ w[0][e])) @ w[1][e]) for e in range(k))
+    got, counters = programs.call(held_experts_output, rows, chosen, weights, *w, offset=0, impl=impl)
+    want = programs.call(lambda rows, w: sum(0.25 * (jnp.square(jax.nn.relu(rows @ w[0][e])) @ w[1][e]) for e in range(k)), rows, w)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     assert counters.tolist() == [[n * k, n]]
 
@@ -248,21 +257,20 @@ def test_grouped_query_attention_agrees_through_the_interpreted_flash_op(monkeyp
     x = jax.random.normal(jax.random.PRNGKey(0), seg.shape + (32,))
     module = GroupedQueryAttention(cfg)
     monkeypatch.setenv("ESGPT_PALLAS_IMPL", "pallas_interpret")
-    params = module.init(jax.random.PRNGKey(1), x, mask, seg)
+    params = programs.init(module, jax.random.PRNGKey(1), x, mask, seg)
     assert params["params"]["k_proj"]["kernel"].shape == (32, 2 * 128)
     batch = {"event_mask": mask, "segment_ids": seg}
-    want = ref.grouped_query_attention(x, params["params"], sizes(cfg), ref.allowed_keys(batch), None)
+    reference = lambda p: ref.grouped_query_attention(x, p, sizes(cfg), ref.allowed_keys(batch), None)  # noqa: E731
+    want = programs.call(reference, params["params"])
     real = np.asarray(mask)
-    flash = module.apply(params, x, mask, seg)
+    flash = programs.apply(module, params, x, mask, seg)
     np.testing.assert_allclose(np.asarray(flash)[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
-    g_flash = jax.grad(lambda p: jnp.sum(module.apply(p, x, mask, seg) ** 2 * mask[..., None]))(params)
+    g_flash = programs.call(jax.grad(lambda p: jnp.sum(module.apply(p, x, mask, seg) ** 2 * mask[..., None])), params)
     monkeypatch.setenv("ESGPT_PALLAS_IMPL", "xla")
     with pytest.warns(UserWarning, match="taking the einsum path in grouped-query attention"):
-        einsum = module.apply(params, x, mask, seg)
+        einsum = programs.apply(module, params, x, mask, seg)
     np.testing.assert_allclose(np.asarray(einsum)[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
-    g_want = jax.grad(
-        lambda p: jnp.sum(ref.grouped_query_attention(x, p, sizes(cfg), ref.allowed_keys(batch), None) ** 2 * mask[..., None])
-    )(params["params"])
+    g_want = programs.call(jax.grad(lambda p: jnp.sum(reference(p) ** 2 * mask[..., None])), params["params"])
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(g_flash["params"])[0], jax.tree_util.tree_leaves(g_want)):
         np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * float(jnp.abs(w).max()), err_msg=str(path))
 
@@ -272,18 +280,21 @@ def test_each_single_part_block_agrees_with_the_plain_reference(layer_id):
     cfg = hybrid_config(n_routed_experts=8, moe_expert_offset=8)
     x, mask, seg = inputs("inside")
     block = KindsBlock(cfg, layer_id=layer_id)
-    params = block.init(jax.random.PRNGKey(8), x, mask, None, False, False, False, seg)["params"]
+    params = programs.init(block, jax.random.PRNGKey(8), x, mask, None, False, False, False, seg)["params"]
     part = {"M": "mixer", "E": "mlp", "*": "self_attn"}[PATTERN[layer_id]]
     assert set(params) == {"input_layernorm", part}  # one norm a layer, one part
-    got, _ = block.apply({"params": params}, x, mask, None, False, False, False, seg)
+    got, _ = programs.apply(block, {"params": params}, x, mask, None, False, False, False, seg)
     model, batch = sizes(cfg), {"event_mask": mask, "segment_ids": seg}
-    normed = ref.rms_norm(x, params["input_layernorm"], cfg.layer_norm_epsilon)
-    if part == "mixer":
-        want = x + ref.mamba_mixer(normed, params[part], model, mask, *ref._segments(batch), None)
-    elif part == "mlp":
-        want = x + ref.routed_feed_forward(normed, params[part], model, None)[0]
-    else:
-        want = x + ref.grouped_query_attention(normed, params[part], model, ref.allowed_keys(batch), None)
+
+    def reference(params):
+        normed = ref.rms_norm(x, params["input_layernorm"], cfg.layer_norm_epsilon)
+        if part == "mixer":
+            return x + ref.mamba_mixer(normed, params[part], model, mask, *ref._segments(batch), None)
+        if part == "mlp":
+            return x + ref.routed_feed_forward(normed, params[part], model, None)[0]
+        return x + ref.grouped_query_attention(normed, params[part], model, ref.allowed_keys(batch), None)
+
+    want = programs.call(reference, params)
     real = np.asarray(mask)
     np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real], rtol=3e-5, atol=3e-5)
 

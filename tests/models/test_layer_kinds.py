@@ -13,6 +13,7 @@ from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 from eventstreamgpt_tpu.models.latent_attention import LatentAttention, split_kv_kernel
 from eventstreamgpt_tpu.models.moe import RoutedFeedForward, held_experts_output
 
+from .. import _programs as programs
 from . import layer_kinds_reference as ref
 
 KINDS = dict(
@@ -43,9 +44,9 @@ def test_latent_attention_agrees_with_the_plain_reference(packed):
     x, mask, segment_ids = inputs()
     segment_ids = segment_ids if packed else None
     module = LatentAttention(cfg)
-    params = module.init(jax.random.PRNGKey(1), x, mask, segment_ids)
-    got = module.apply(params, x, mask, segment_ids)
-    want = ref.latent_attention(x, params["params"], cfg, mask, segment_ids)
+    params = programs.init(module, jax.random.PRNGKey(1), x, mask, segment_ids)
+    got = programs.apply(module, params, x, mask, segment_ids)
+    want = programs.call(lambda p: ref.latent_attention(x, p, cfg, mask, segment_ids), params["params"])
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
@@ -174,14 +175,14 @@ def test_routed_feed_forward_agrees_with_the_plain_reference(impl, monkeypatch):
     cfg = kinds_config(n_routed_experts=6, moe_expert_offset=3)
     x, mask, _ = inputs(B=2, L=64)
     module = RoutedFeedForward(cfg)
-    params = module.init(jax.random.PRNGKey(2), x)
+    params = programs.init(module, jax.random.PRNGKey(2), x)
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (16,))  # a selection bias that chooses otherwise
     params = {"params": {**params["params"], "e_score_correction_bias": bias}}
-    got = module.apply(params, x)
-    routed, shared = ref.routed_feed_forward(x, params["params"], cfg)
+    got = programs.apply(module, params, x)
+    routed, shared = programs.call(lambda p: ref.routed_feed_forward(x, p, cfg), params["params"])
     np.testing.assert_allclose(got, routed + shared, rtol=2e-5, atol=2e-5)
-    grads = jax.grad(lambda p: jnp.sum(module.apply(p, x) ** 2))(params)["params"]
-    want = jax.grad(lambda p: jnp.sum(sum(ref.routed_feed_forward(x, p, cfg)) ** 2))(params["params"])
+    grads = programs.call(jax.grad(lambda p: jnp.sum(module.apply(p, x) ** 2)), params)["params"]
+    want = programs.call(jax.grad(lambda p: jnp.sum(sum(ref.routed_feed_forward(x, p, cfg)) ** 2)), params["params"])
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * float(jnp.abs(w).max()), err_msg=str(path))
 
@@ -199,20 +200,24 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(router):
     whole = kinds_config(n_routed_experts=width, **router)
     x, _, _ = inputs(B=2, L=32, seed=3)
     module = RoutedFeedForward(whole)
-    params = module.init(jax.random.PRNGKey(4), x)
-    uncut = module.apply(params, x)
+    params = programs.init(module, jax.random.PRNGKey(4), x)
+    uncut = programs.apply(module, params, x)
     p = params["params"]
-    shared = ref.swiglu(x, p["shared_experts"])
-    total = shared
-    for first in range(0, width, width // 8):
-        share = kinds_config(n_routed_experts=width // 8, moe_expert_offset=first, **router)
-        held = {
-            **{k: v for k, v in p.items() if not k.startswith("experts_")},
-            **{k: v[first : first + width // 8] for k, v in p.items() if k.startswith("experts_")},
-        }
-        total = total + RoutedFeedForward(share).apply({"params": held}, x) - shared
-    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
-    routed, ref_shared = ref.routed_feed_forward(x, p, whole)
+
+    def shares(p):
+        shared = ref.swiglu(x, p["shared_experts"])
+        total = shared
+        for first in range(0, width, width // 8):
+            share = kinds_config(n_routed_experts=width // 8, moe_expert_offset=first, **router)
+            held = {
+                **{k: v for k, v in p.items() if not k.startswith("experts_")},
+                **{k: v[first : first + width // 8] for k, v in p.items() if k.startswith("experts_")},
+            }
+            total = total + RoutedFeedForward(share).apply({"params": held}, x) - shared
+        return total
+
+    np.testing.assert_allclose(programs.call(shares, p), uncut, rtol=2e-5, atol=2e-5)
+    routed, ref_shared = programs.call(lambda p: ref.routed_feed_forward(x, p, whole), p)
     np.testing.assert_allclose(uncut, routed + ref_shared, rtol=2e-5, atol=2e-5)
 
 
@@ -236,12 +241,12 @@ def test_rope_restarts_at_a_packed_segment():
     cfg = kinds_config()
     x, _, segment_ids = inputs(B=1)
     block = KindsBlock(cfg, layer_id=1)
-    params = block.init(jax.random.PRNGKey(7), x, None, None, False, False, False, segment_ids)
-    packed, _ = block.apply(params, x, None, None, False, False, False, segment_ids)
+    params = programs.init(block, jax.random.PRNGKey(7), x, None, None, False, False, False, segment_ids)
+    packed, _ = programs.apply(block, params, x, None, None, False, False, False, segment_ids)
     for lo, hi in ((0, 5), (5, 9), (9, 12)):
-        apart, _ = block.apply(params, x[:, lo:hi], None, None, False, False, False, None)
+        apart, _ = programs.apply(block, params, x[:, lo:hi], None, None, False, False, False, None)
         np.testing.assert_allclose(packed[:, lo:hi], apart, rtol=2e-5, atol=2e-5)
-    unpacked, _ = block.apply(params, x, None, None, False, False, False, None)
+    unpacked, _ = programs.apply(block, params, x, None, None, False, False, False, None)
     assert float(jnp.abs(unpacked[:, 5:] - packed[:, 5:]).max()) > 1e-3
 
 
@@ -250,9 +255,9 @@ def test_block_agrees_with_the_plain_reference(layer_id):
     cfg = kinds_config(n_routed_experts=8, moe_expert_offset=8)
     x, mask, segment_ids = inputs()
     block = KindsBlock(cfg, layer_id=layer_id)
-    params = block.init(jax.random.PRNGKey(8), x, mask, None, False, False, False, segment_ids)
-    got, _ = block.apply(params, x, mask, None, False, False, False, segment_ids)
-    want = ref.block(x, params["params"], cfg, layer_id, mask, segment_ids)
+    params = programs.init(block, jax.random.PRNGKey(8), x, mask, None, False, False, False, segment_ids)
+    got, _ = programs.apply(block, params, x, mask, None, False, False, False, segment_ids)
+    want = programs.call(lambda p: ref.block(x, p, cfg, layer_id, mask, segment_ids), params["params"])
     real = np.asarray(mask)  # a row that holds no event is routed nowhere; the encoder zeroes it
     np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real], rtol=3e-5, atol=3e-5)
 
@@ -261,9 +266,9 @@ def test_counters_equal_a_count_made_on_the_host():
     cfg = kinds_config(n_routed_experts=4, moe_expert_offset=2)
     x, mask, _ = inputs(B=2, L=32, seed=9)
     module = RoutedFeedForward(cfg)
-    params = module.init(jax.random.PRNGKey(10), x)
+    params = programs.init(module, jax.random.PRNGKey(10), x)
     assert set(params) == {"params"}  # `init` gives parameters alone
-    _, sown = module.apply(params, x, mask, mutable=["routing"])
+    _, sown = programs.apply(module, params, x, mask, mutable=["routing"])
     (counters,) = sown["routing"]["counters"]
     scores = jax.nn.sigmoid(x.reshape(-1, 32) @ params["params"]["router"])
     chosen = np.asarray(jax.lax.top_k(scores, 4)[1])[np.asarray(mask).reshape(-1)]
@@ -278,25 +283,27 @@ def test_the_selection_bias_chooses_and_does_not_weigh():
     cfg = kinds_config(n_routed_experts=4, moe_expert_offset=2, init_std=0.1)
     x = jax.random.normal(jax.random.PRNGKey(11), (4, 64, 32))
     module = RoutedFeedForward(cfg)
-    params = module.init(jax.random.PRNGKey(13), x)
+    params = programs.init(module, jax.random.PRNGKey(13), x)
     assert float(jnp.abs(params["params"]["e_score_correction_bias"]).max()) == 0.0
     bias = jnp.zeros(16).at[2:6].set(10.0)  # every row's four choices are the four held experts
     biased = {"params": {**params["params"], "e_score_correction_bias": bias}}
-    out, sown = module.apply(biased, x, mutable=["routing"])
+    out, sown = programs.apply(module, biased, x, mutable=["routing"])
     assert sown["routing"]["counters"][0].tolist() == [4 * 256, 256]
 
-    p = params["params"]
-    rows = x.reshape(-1, 32)
-    scores = jax.nn.sigmoid(rows @ p["router"])[:, 2:6]
-    weights = cfg.routed_scaling_factor * scores / scores.sum(-1, keepdims=True)  # by score alone
-    swiglu = lambda r, g, u, d: (jax.nn.silu(r @ g) * (r @ u)) @ d  # noqa: E731
-    want = swiglu(rows, *(p["shared_experts"][n]["kernel"] for n in ("gate_proj", "up_proj", "down_proj")))
-    for i in range(4):
-        want = want + weights[:, i : i + 1] * swiglu(
-            rows, p["experts_gate_proj"][i], p["experts_up_proj"][i], p["experts_down_proj"][i]
-        )
-    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=1e-5, atol=1e-6)
-    grads = jax.grad(lambda q: jnp.sum(module.apply(q, x) ** 2))(biased)["params"]
+    def by_score(p):
+        rows = x.reshape(-1, 32)
+        scores = jax.nn.sigmoid(rows @ p["router"])[:, 2:6]
+        weights = cfg.routed_scaling_factor * scores / scores.sum(-1, keepdims=True)  # by score alone
+        swiglu = lambda r, g, u, d: (jax.nn.silu(r @ g) * (r @ u)) @ d  # noqa: E731
+        want = swiglu(rows, *(p["shared_experts"][n]["kernel"] for n in ("gate_proj", "up_proj", "down_proj")))
+        for i in range(4):
+            want = want + weights[:, i : i + 1] * swiglu(
+                rows, p["experts_gate_proj"][i], p["experts_up_proj"][i], p["experts_down_proj"][i]
+            )
+        return want
+
+    np.testing.assert_allclose(out.reshape(-1, 32), programs.call(by_score, params["params"]), rtol=1e-5, atol=1e-6)
+    grads = programs.call(jax.grad(lambda q: jnp.sum(module.apply(q, x) ** 2)), biased)["params"]
     assert float(jnp.abs(grads["e_score_correction_bias"]).max()) == 0.0
     assert float(jnp.abs(grads["router"]).max()) > 0.0
 
@@ -405,9 +412,9 @@ def test_latent_attention_at_192_and_128_runs_the_flash_op_and_agrees_with_the_e
         xc = x.astype(cfg.compute_dtype)
         with monkeypatch.context() as patch:
             patch.setenv("ESGPT_PALLAS_IMPL", impl)
-            params = module.init(jax.random.PRNGKey(1), xc, mask, segment_ids)
+            params = programs.init(module, jax.random.PRNGKey(1), xc, mask, segment_ids)
             loss = lambda p, x_: jnp.sum(module.apply(p, x_, mask, segment_ids).astype(jnp.float32) * weigh)  # noqa: E731
-            return module.apply(params, xc, mask, segment_ids), jax.grad(loss, argnums=(0, 1))(params, xc)
+            return programs.apply(module, params, xc, mask, segment_ids), programs.call(jax.grad(loss, argnums=(0, 1)), params, xc)
 
     with warnings.catch_warnings():  # the widths engage the in-place assembly: nothing falls back, nothing warns
         warnings.simplefilter("error")

@@ -18,6 +18,8 @@ from eventstreamgpt_tpu.data.types import EventStreamBatch
 from eventstreamgpt_tpu.models.ci_model import ConditionallyIndependentGenerativeOutputLayer
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 
+from .. import _programs as programs
+
 RTOL, ATOL = 1e-4, 1e-5
 
 
@@ -96,37 +98,39 @@ def torch_weighted_loss(loss_per_event, event_mask):
 
 
 class TestCIOutputLayerParity:
-    def setup_method(self):
-        self.config = make_config()
-        self.batch = make_batch()
-        B, L = self.batch.event_mask.shape
+    @pytest.fixture(autouse=True, scope="class")
+    def built(self, request):
+        cls = request.cls
+        cls.config = make_config()
+        cls.batch = make_batch()
+        B, L = cls.batch.event_mask.shape
         rng = np.random.default_rng(7)
-        self.encoded = rng.normal(size=(B, L, self.config.hidden_size)).astype(np.float32) * 0.5
+        cls.encoded = rng.normal(size=(B, L, cls.config.hidden_size)).astype(np.float32) * 0.5
 
-        self.layer = ConditionallyIndependentGenerativeOutputLayer(self.config)
-        self.params = self.layer.init(jax.random.PRNGKey(0), self.batch, jnp.asarray(self.encoded))
-        self.out = self.layer.apply(self.params, self.batch, jnp.asarray(self.encoded))
+        cls.layer = ConditionallyIndependentGenerativeOutputLayer(cls.config)
+        cls.params = programs.init(cls.layer, jax.random.PRNGKey(0), cls.batch, jnp.asarray(cls.encoded))
+        cls.out = programs.apply(cls.layer, cls.params, cls.batch, jnp.asarray(cls.encoded))
 
-        p = self.params["params"]
+        p = cls.params["params"]
         # Shifted encodings used for event-content prediction.
         shifted = np.concatenate(
-            [np.zeros_like(self.encoded[:, :1]), self.encoded[:, :-1]], axis=1
+            [np.zeros_like(cls.encoded[:, :1]), cls.encoded[:, :-1]], axis=1
         )
-        self.t_shifted = torch.from_numpy(shifted)
-        self.t_encoded = torch.from_numpy(self.encoded)
-        self.cls_scores = self.t_shifted @ torch.from_numpy(
+        cls.t_shifted = torch.from_numpy(shifted)
+        cls.t_encoded = torch.from_numpy(cls.encoded)
+        cls.cls_scores = cls.t_shifted @ torch.from_numpy(
             np.asarray(p["ClassificationLayer"]["kernel"])
         ) + torch.from_numpy(np.asarray(p["ClassificationLayer"]["bias"]))
-        self.obs_scores = self.t_shifted @ torch.from_numpy(
+        cls.obs_scores = cls.t_shifted @ torch.from_numpy(
             np.asarray(p["IsObservedLayer"]["kernel"])
         ) + torch.from_numpy(np.asarray(p["IsObservedLayer"]["bias"]))
-        self.p = p
+        cls.p = p
 
-        self.t_event_mask = torch.from_numpy(np.asarray(self.batch.event_mask))
-        self.t_dyn_idx = torch.from_numpy(np.asarray(self.batch.dynamic_indices))
-        self.t_dyn_meas = torch.from_numpy(np.asarray(self.batch.dynamic_measurement_indices))
-        self.t_dyn_vals = torch.from_numpy(np.asarray(self.batch.dynamic_values))
-        self.t_dyn_vmask = torch.from_numpy(np.asarray(self.batch.dynamic_values_mask))
+        cls.t_event_mask = torch.from_numpy(np.asarray(cls.batch.event_mask))
+        cls.t_dyn_idx = torch.from_numpy(np.asarray(cls.batch.dynamic_indices))
+        cls.t_dyn_meas = torch.from_numpy(np.asarray(cls.batch.dynamic_measurement_indices))
+        cls.t_dyn_vals = torch.from_numpy(np.asarray(cls.batch.dynamic_values))
+        cls.t_dyn_vmask = torch.from_numpy(np.asarray(cls.batch.dynamic_values_mask))
 
     def test_single_label_classification_loss(self):
         scores = self.cls_scores[:, :, 1:4]
@@ -239,8 +243,8 @@ class TestLogNormalTTEParity:
         encoded = rng.normal(size=(B, L, config.hidden_size)).astype(np.float32) * 0.5
 
         layer = ConditionallyIndependentGenerativeOutputLayer(config)
-        params = layer.init(jax.random.PRNGKey(0), batch, jnp.asarray(encoded))
-        out = layer.apply(params, batch, jnp.asarray(encoded))
+        params = programs.init(layer, jax.random.PRNGKey(0), batch, jnp.asarray(encoded))
+        out = programs.apply(layer, params, batch, jnp.asarray(encoded))
 
         p = params["params"]["TTE_layer"]["proj"]
         t_enc = torch.from_numpy(encoded)
@@ -277,8 +281,8 @@ class TestGenerationMode:
             np.random.default_rng(1).normal(size=(B, L, config.hidden_size)).astype(np.float32)
         )
         layer = ConditionallyIndependentGenerativeOutputLayer(config)
-        params = layer.init(jax.random.PRNGKey(0), batch, encoded)
-        out = layer.apply(params, batch, encoded, is_generation=True)
+        params = programs.init(layer, jax.random.PRNGKey(0), batch, encoded)
+        out = programs.apply(layer, params, batch, encoded, is_generation=True)
         assert out.loss is None
         assert out.preds.time_to_event is not None
         assert set(out.preds.classification.keys()) == {"event_type", "multi_lab", "lab_vals"}
@@ -368,7 +372,7 @@ def test_multi_label_plane_from_the_kernel_is_the_compare_any_bit_for_bit(monkey
 
     config, batch, encoded, (start, idx, meas) = label_case(V, M, S, columns, precision)
     layer = ConditionallyIndependentGenerativeOutputLayer(config)
-    params = layer.init(jax.random.PRNGKey(0), batch, encoded)
+    params = programs.init(layer, jax.random.PRNGKey(0), batch, encoded)
 
     def head(p, e):
         losses, _, labels = layer.apply(p, batch, e, {"multi_lab"}, method=layer.get_classification_outputs)

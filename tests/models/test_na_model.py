@@ -23,6 +23,8 @@ from eventstreamgpt_tpu.models.transformer import (
     time_from_deltas,
 )
 
+from .. import _programs as programs
+
 # Vocab layout: event_type [1, 4), multi_lab [4, 8), lab_vals [8, 12).
 G = 3  # dep graph: [time-dependent (empty here), event_type, labs]
 
@@ -92,24 +94,26 @@ def make_batch(B=2, L=4, M=5, seed=0, all_real=True):
 
 
 class TestNAEncoder:
-    def setup_method(self):
-        self.config = make_config()
-        self.batch = make_batch()
-        self.encoder = NestedAttentionPointProcessTransformer(self.config)
-        self.params = self.encoder.init(jax.random.PRNGKey(0), self.batch)
+    @pytest.fixture(autouse=True, scope="class")
+    def built(self, request):
+        cls = request.cls
+        cls.config = make_config()
+        cls.batch = make_batch()
+        cls.encoder = NestedAttentionPointProcessTransformer(cls.config)
+        cls.params = programs.init(cls.encoder, jax.random.PRNGKey(0), cls.batch)
 
     def test_output_shape(self):
-        out = self.encoder.apply(self.params, self.batch)
+        out = programs.apply(self.encoder, self.params, self.batch)
         assert out.last_hidden_state.shape == (2, 4, G, 16)
 
     def test_seq_causality(self):
         """Changing a later event must not change earlier events' outputs."""
-        out1 = self.encoder.apply(self.params, self.batch)
+        out1 = programs.apply(self.encoder, self.params, self.batch)
         modified = self.batch.replace(
             dynamic_indices=self.batch.dynamic_indices.at[:, -1, 0].set(2),
             time_delta=self.batch.time_delta.at[:, -1].set(42.0),
         )
-        out2 = self.encoder.apply(self.params, modified)
+        out2 = programs.apply(self.encoder, self.params, modified)
         np.testing.assert_allclose(
             np.asarray(out1.last_hidden_state[:, :-1]),
             np.asarray(out2.last_hidden_state[:, :-1]),
@@ -123,12 +127,12 @@ class TestNAEncoder:
         Output position p attends [history, levels 0..p], so changing level-2
         data (labs, graph slot 2) may only affect output positions >= 2.
         """
-        out1 = self.encoder.apply(self.params, self.batch)
+        out1 = programs.apply(self.encoder, self.params, self.batch)
         # Labs live at data-element slot 2 (measurement 3, graph level 2).
         modified = self.batch.replace(
             dynamic_values=self.batch.dynamic_values.at[:, :, 2].set(7.7),
         )
-        out2 = self.encoder.apply(self.params, modified)
+        out2 = programs.apply(self.encoder, self.params, modified)
         # Graph output positions 0 and 1 (predicting levels 1 and 2) see only
         # levels 0..1 of the same event — position 1 sees level 1 only.
         np.testing.assert_allclose(
@@ -140,7 +144,7 @@ class TestNAEncoder:
 
     def test_event_mask_zeroing(self):
         batch = make_batch(all_real=False)
-        out = self.encoder.apply(self.params, batch)
+        out = programs.apply(self.encoder, self.params, batch)
         np.testing.assert_allclose(np.asarray(out.last_hidden_state[-1, -1]), 0.0)
 
     def test_cached_dep_graph_decode_matches_uncached(self):
@@ -158,10 +162,11 @@ class TestNAEncoder:
         """
         B, L = self.batch.event_mask.shape
         n_decode = 2  # decode the last two events through the cached machine
-        full = self.encoder.apply(self.params, self.batch)
+        full = programs.apply(self.encoder, self.params, self.batch)
 
         prefix = self.batch.slice((slice(None), slice(0, L - n_decode)))
-        out1 = self.encoder.apply(
+        out1 = programs.apply(
+            self.encoder,
             self.params,
             prefix,
             past=NAPast(
@@ -185,7 +190,8 @@ class TestNAEncoder:
             )
 
             for target in range(1, G):
-                out_t = self.encoder.apply(
+                out_t = programs.apply(
+                    self.encoder,
                     self.params,
                     trimmed,
                     past=past,
@@ -201,7 +207,8 @@ class TestNAEncoder:
                     err_msg=f"event={ev} target={target}",
                 )
 
-            out_0 = self.encoder.apply(
+            out_0 = programs.apply(
+                self.encoder,
                 self.params,
                 trimmed,
                 past=past,
@@ -219,11 +226,13 @@ class TestNAEncoder:
 
 
 class TestNAModel:
-    def setup_method(self):
-        self.config = make_config()
-        self.batch = make_batch()
-        self.model = NAPPTForGenerativeSequenceModeling(self.config)
-        self.params = self.model.init(jax.random.PRNGKey(0), self.batch)
+    @pytest.fixture(autouse=True, scope="class")
+    def built(self, request):
+        cls = request.cls
+        cls.config = make_config()
+        cls.batch = make_batch()
+        cls.model = NAPPTForGenerativeSequenceModeling(cls.config)
+        cls.params = programs.init(cls.model, jax.random.PRNGKey(0), cls.batch)
 
     def test_forward_losses(self):
         out = jax.jit(self.model.apply)(self.params, self.batch)
@@ -250,7 +259,7 @@ class TestNAModel:
         assert losses[-1] < losses[0]
 
     def test_generation_mode(self):
-        out = self.model.apply(self.params, self.batch, is_generation=True)
+        out = programs.apply(self.model, self.params, self.batch, is_generation=True)
         assert out.loss is None
         assert out.preds.time_to_event is not None
 

@@ -23,6 +23,8 @@ from eventstreamgpt_tpu.models.na_model import NAPPTForGenerativeSequenceModelin
 
 from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
+from .. import _programs as programs
+
 # bf16 has ~3 decimal digits; after fp32 softmax/loss math the end-to-end
 # loss disagreement stays comfortably within a relative 2%.
 LOSS_RTOL = 2e-2
@@ -124,8 +126,8 @@ class TestCIMixedPrecision:
         batch = dataset.collate_indices(np.arange(min(2, len(dataset))))
         cfg16 = _ci_config(dataset, "bf16")
         model16 = CIPPTForGenerativeSequenceModeling(cfg16)
-        params = model16.init(jax.random.PRNGKey(0), batch)
-        out = model16.apply(params, batch, is_generation=True)
+        params = programs.init(model16, jax.random.PRNGKey(0), batch)
+        out = programs.apply(model16, params, batch, is_generation=True)
         sample = out.preds.time_to_event.sample(jax.random.PRNGKey(0))
         assert (np.asarray(sample) > 0).all()
 
@@ -134,8 +136,8 @@ class TestCIMixedPrecision:
         batch = dataset.collate_indices(np.arange(min(2, len(dataset))))
         cfg16 = _ci_config(dataset, "bf16")
         model16 = CIPPTForGenerativeSequenceModeling(cfg16)
-        params = model16.init(jax.random.PRNGKey(0), batch)
-        out = model16.apply(params, batch, use_cache=True)
+        params = programs.init(model16, jax.random.PRNGKey(0), batch)
+        out = programs.apply(model16, params, batch, use_cache=True)
         assert out.past_key_values[0].key.dtype == jnp.bfloat16
 
 
@@ -149,9 +151,9 @@ class TestNAMixedPrecision:
 
         model32 = NAPPTForGenerativeSequenceModeling(cfg32)
         model16 = NAPPTForGenerativeSequenceModeling(cfg16)
-        params = model32.init(jax.random.PRNGKey(0), batch)
+        params = programs.init(model32, jax.random.PRNGKey(0), batch)
 
-        l32 = float(model32.apply(params, batch).loss)
-        l16 = float(model16.apply(params, batch).loss)
+        l32 = float(programs.loss(model32, params, batch))
+        l16 = float(programs.loss(model16, params, batch))
         assert np.isfinite(l16)
         assert abs(l16 - l32) <= LOSS_RTOL * abs(l32), (l32, l16)

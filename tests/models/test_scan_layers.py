@@ -37,6 +37,8 @@ from eventstreamgpt_tpu.training import build_model
 
 from __graft_entry__ import _make_model_and_batch
 
+from .. import _programs as programs
+
 
 def _deepen(model, num_hidden_layers, **overrides):
     cfg = StructuredTransformerConfig.from_dict(
@@ -77,12 +79,35 @@ class TestScanPeriod:
         assert scan_period(cfg) == (3, 1)
 
 
+@pytest.fixture(scope="module")
+def built():
+    """``(model, batch, params)`` by ``(na, depth, seq_len)``: the unrolled
+    model, its batch and its parameters, built once for the module."""
+    cache = {}
+
+    def get(na, depth, seq_len):
+        if (na, depth, seq_len) not in cache:
+            model, batch = _make_model_and_batch(batch_size=2, seq_len=seq_len, na=na)
+            model = _deepen(model, depth)
+            cache[na, depth, seq_len] = (model, batch, programs.init(model, jax.random.PRNGKey(0), batch))
+        return cache[na, depth, seq_len]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def scanned(built):
+    """The depth-4 CI model, its batch, its stacked parameters and its scanned
+    twin's loss, which every remat policy is held to."""
+    model, batch, params = built(False, 4, 16)
+    params = stack_layer_params(params, model.config)
+    return model, batch, params, programs.loss(_scan_twin(model), params, batch)
+
+
 class TestMigration:
     @pytest.mark.parametrize("na", [False, True], ids=["ci", "na"])
-    def test_round_trip_and_structure(self, na):
-        model, batch = _make_model_and_batch(batch_size=2, seq_len=8, na=na)
-        model = _deepen(model, 4)
-        params = model.init(jax.random.PRNGKey(0), batch)
+    def test_round_trip_and_structure(self, built, na):
+        model, batch, params = built(na, 4, 8)
         stacked = stack_layer_params(params, model.config)
         # Structure matches a fresh scan_layers init (checkpoint-compatible).
         scan_model = _scan_twin(model)
@@ -104,23 +129,18 @@ class TestMigration:
 class TestScanForwardParity:
     @pytest.mark.parametrize("na", [False, True], ids=["ci", "na"])
     @pytest.mark.parametrize("depth", [2, 4], ids=["1group", "2groups"])
-    def test_loss_bitwise_and_grads_within_envelope(self, na, depth):
-        model, batch = _make_model_and_batch(batch_size=2, seq_len=16, na=na)
-        model = _deepen(model, depth)
+    def test_loss_bitwise_and_grads_within_envelope(self, built, na, depth):
+        model, batch, params = built(na, depth, 16)
         scan_model = _scan_twin(model)
-        params = model.init(jax.random.PRNGKey(0), batch)
         sparams = stack_layer_params(params, model.config)
 
-        loss_u = model.apply(params, batch).loss
-        loss_s = scan_model.apply(sparams, batch).loss
+        loss_u, gu = programs.loss_and_grad(model, params, batch)
+        loss_s, gs = programs.loss_and_grad(scan_model, sparams, batch)
         # Two separately compiled programs: equal within one float32 ulp.
         loss_u, loss_s = np.float32(loss_u), np.float32(loss_s)
         assert abs(loss_u - loss_s) <= np.spacing(abs(loss_u))
 
-        gu = jax.grad(lambda p: model.apply(p, batch).loss)(params)
-        gs = unstack_layer_params(
-            jax.grad(lambda p: scan_model.apply(p, batch).loss)(sparams), model.config
-        )
+        gs = unstack_layer_params(gs, model.config)
         fu, fs = _flat(gu), _flat(gs)
         # The documented envelope: the scan body compiles separately, so
         # reduction reassociation moves cancellation-dominated elements by
@@ -131,16 +151,11 @@ class TestScanForwardParity:
     @pytest.mark.parametrize(
         "policy", ["block", "dots_no_batch", "save_attention"]
     )
-    def test_remat_policies_keep_parity(self, policy):
+    def test_remat_policies_keep_parity(self, scanned, policy):
         """Per-layer remat composes with the scan (nn.remat inside nn.scan)
         without touching numerics: the scanned loss under every policy is
         bitwise the no-remat scanned loss."""
-        model, batch = _make_model_and_batch(batch_size=2, seq_len=16)
-        model = _deepen(model, 4)
-        params = stack_layer_params(
-            model.init(jax.random.PRNGKey(0), batch), model.config
-        )
-        base = _scan_twin(model).apply(params, batch).loss
+        model, batch, params, base = scanned
         rematted = build_model(
             StructuredTransformerConfig.from_dict(
                 {
@@ -150,21 +165,19 @@ class TestScanForwardParity:
                 }
             )
         )
-        loss_p = rematted.apply(params, batch).loss
+        loss_p = programs.loss(rematted, params, batch)
         assert np.asarray(base).tobytes() == np.asarray(loss_p).tobytes()
-        g = jax.grad(lambda p: rematted.apply(p, batch).loss)(params)
+        g = programs.grad(rematted, params, batch)
         assert all(
             np.isfinite(np.asarray(x)).all() for x in jax.tree_util.tree_leaves(g)
         )
 
-    def test_output_hidden_states_parity(self):
-        model, batch = _make_model_and_batch(batch_size=2, seq_len=8)
-        model = _deepen(model, 4)
+    def test_output_hidden_states_parity(self, built):
+        model, batch, params = built(False, 4, 8)
         scan_model = _scan_twin(model)
-        params = model.init(jax.random.PRNGKey(0), batch)
         sparams = stack_layer_params(params, model.config)
-        out_u = model.apply(params, batch, output_hidden_states=True)
-        out_s = scan_model.apply(sparams, batch, output_hidden_states=True)
+        out_u = programs.apply(model, params, batch, output_hidden_states=True)
+        out_s = programs.apply(scan_model, sparams, batch, output_hidden_states=True)
         assert len(out_u.hidden_states) == len(out_s.hidden_states)
         # Collecting per-layer ys changes the compiled program, so the
         # intermediate hiddens carry last-ulp reassociation noise; the
@@ -174,28 +187,25 @@ class TestScanForwardParity:
                 np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6
             )
 
-    def test_output_attentions_raises_under_scan(self):
-        model, batch = _make_model_and_batch(batch_size=2, seq_len=8)
+    def test_output_attentions_raises_under_scan(self, built):
+        model, batch, params = built(False, 2, 8)
         scan_model = _scan_twin(model)
-        params = stack_layer_params(
-            model.init(jax.random.PRNGKey(0), batch), model.config
-        )
+        params = stack_layer_params(params, model.config)
         with pytest.raises(NotImplementedError, match="output_attentions"):
-            scan_model.apply(params, batch, output_attentions=True)
+            programs.apply(scan_model, params, batch, output_attentions=True)
 
-    def test_dropout_runs_under_scan(self):
+    def test_dropout_runs_under_scan(self, built):
         """Training-mode dropout traces and runs (split_rngs per scan step);
         the draws legitimately differ from the unrolled stream — only
         finiteness and determinism per rng are pinned."""
-        model, batch = _make_model_and_batch(batch_size=2, seq_len=8)
+        model, batch, params = built(False, 2, 8)
         scan_model = _scan_twin(model)
-        params = stack_layer_params(
-            model.init(jax.random.PRNGKey(0), batch), model.config
-        )
-        l1 = scan_model.apply(params, batch, rngs={"dropout": jax.random.PRNGKey(3)})
-        l2 = scan_model.apply(params, batch, rngs={"dropout": jax.random.PRNGKey(3)})
-        assert np.asarray(l1.loss).tobytes() == np.asarray(l2.loss).tobytes()
-        assert np.isfinite(float(l1.loss))
+        params = stack_layer_params(params, model.config)
+        loss = jax.jit(lambda r: scan_model.apply(params, batch, rngs={"dropout": r}).loss)
+        l1 = loss(jax.random.PRNGKey(3))
+        l2 = loss(jax.random.PRNGKey(3))
+        assert np.asarray(l1).tobytes() == np.asarray(l2).tobytes()
+        assert np.isfinite(float(l1))
 
 
 @pytest.mark.slow

@@ -19,6 +19,7 @@ from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 from eventstreamgpt_tpu.models.moe import RoutedFeedForward, dispatch_chunk, held_experts_output
 from eventstreamgpt_tpu.models.short_conv import ShortConvMixer
 
+from .. import _programs as programs
 from . import layer_kinds_reference as ref
 
 CONFIGS = Path(__file__).resolve().parents[2] / "benchmark" / "configs"
@@ -70,17 +71,17 @@ def test_short_conv_agrees_with_a_sum_over_its_taps(segments):
     x, mask, seg = packed()
     seg = seg if segments else None
     module = ShortConvMixer(cfg)
-    params = module.init(jax.random.PRNGKey(1), x, mask, seg)["params"]
+    params = programs.init(module, jax.random.PRNGKey(1), x, mask, seg)["params"]
     assert jax.tree_util.tree_map(jnp.shape, params) == {
         "in_proj": {"kernel": (32, 96)}, "conv_kernel": (3, 32), "out_proj": {"kernel": (32, 32)},
     }
     assert float(jnp.abs(params["conv_kernel"]).max()) <= 0.5
-    got = module.apply({"params": params}, x, mask, seg)
-    want = ref.short_conv(x, params, cfg, mask, seg)
+    got = programs.apply(module, {"params": params}, x, mask, seg)
+    want = programs.call(lambda p: ref.short_conv(x, p, cfg, mask, seg), params)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     weigh = jax.random.normal(jax.random.PRNGKey(2), got.shape)
-    grads = jax.grad(lambda p, x: jnp.sum(module.apply({"params": p}, x, mask, seg) * weigh), argnums=(0, 1))(params, x)
-    wants = jax.grad(lambda p, x: jnp.sum(ref.short_conv(x, p, cfg, mask, seg) * weigh), argnums=(0, 1))(params, x)
+    grads = programs.call(jax.grad(lambda p, x: jnp.sum(module.apply({"params": p}, x, mask, seg) * weigh), argnums=(0, 1)), params, x)
+    wants = programs.call(jax.grad(lambda p, x: jnp.sum(ref.short_conv(x, p, cfg, mask, seg) * weigh), argnums=(0, 1)), params, x)
     assert_grads_agree(grads, wants)
 
 
@@ -90,15 +91,15 @@ def test_no_tap_crosses_a_segment():
     cfg = lfm2_config()
     x, _, seg = packed()
     module = ShortConvMixer(cfg)
-    params = module.init(jax.random.PRNGKey(1), x, None, seg)
-    together = module.apply(params, x, None, seg)
+    params = programs.init(module, jax.random.PRNGKey(1), x, None, seg)
+    together = programs.apply(module, params, x, None, seg)
     for row in range(2):
         ids = np.asarray(seg[row]).tolist()
         for s in sorted(set(ids)):
             lo, hi = ids.index(s), len(ids) - ids[::-1].index(s)
-            alone = module.apply(params, x[row : row + 1, lo:hi], None, None)
+            alone = programs.apply(module, params, x[row : row + 1, lo:hi], None, None)
             np.testing.assert_allclose(together[row : row + 1, lo:hi], alone, rtol=2e-5, atol=2e-5)
-    unpacked = module.apply(params, x, None, None)
+    unpacked = programs.apply(module, params, x, None, None)
     for start in (5, 13):  # the first two events of a segment read taps of the one before when unpacked
         assert float(jnp.abs(unpacked[0, start : start + 2] - together[0, start : start + 2]).min()) > 1e-4
         np.testing.assert_allclose(unpacked[0, start + 2 : start + 4], together[0, start + 2 : start + 4], rtol=2e-5, atol=2e-5)
@@ -110,11 +111,12 @@ def test_padding_slots_are_zeroed():
     cfg = lfm2_config()
     x, mask, seg = packed(padded_tail=5)
     module = ShortConvMixer(cfg)
-    params = module.init(jax.random.PRNGKey(1), x, mask, seg)
-    got = module.apply(params, x, mask, seg)
+    params = programs.init(module, jax.random.PRNGKey(1), x, mask, seg)
+    run = jax.jit(lambda x: module.apply(params, x, mask, seg))
+    got = run(x)
     assert float(jnp.abs(got[1, -5:]).max()) == 0.0
     noise = jnp.where(mask[..., None], x, 100.0 * jax.random.normal(jax.random.PRNGKey(9), x.shape))
-    np.testing.assert_array_equal(np.asarray(module.apply(params, noise, mask, seg))[np.asarray(mask)], np.asarray(got)[np.asarray(mask)])
+    np.testing.assert_array_equal(np.asarray(run(noise))[np.asarray(mask)], np.asarray(got)[np.asarray(mask)])
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
@@ -129,7 +131,7 @@ def test_grouped_query_attention_with_qk_norm_and_rope(impl, monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(0), seg.shape + (32,))
     module = GroupedQueryAttention(cfg)
     monkeypatch.setenv("ESGPT_PALLAS_IMPL", impl)
-    params = module.init(jax.random.PRNGKey(1), x, mask, seg)
+    params = programs.init(module, jax.random.PRNGKey(1), x, mask, seg)
     assert jax.tree_util.tree_map(jnp.shape, params["params"]) == {
         "q_proj": {"kernel": (32, 512)}, "k_proj": {"kernel": (32, 128)}, "v_proj": {"kernel": (32, 128)},
         "q_layernorm": {"scale": (64,)}, "k_layernorm": {"scale": (64,)}, "o_proj": {"kernel": (512, 32)},
@@ -144,14 +146,15 @@ def test_grouped_query_attention_with_qk_norm_and_rope(impl, monkeypatch):
         return module.apply(p, x, mask, seg)
 
     want = jax.jit(lambda p: ref.grouped_query_attention(x, p, cfg, mask, seg))(params["params"])
-    np.testing.assert_allclose(np.asarray(run(params))[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
+    out = jax.jit(run)(params)
+    np.testing.assert_allclose(np.asarray(out)[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
     grads = jax.jit(jax.grad(lambda p: jnp.sum(run(p) ** 2 * mask[..., None])))(params)["params"]
     wants = jax.jit(jax.grad(lambda p: jnp.sum(ref.grouped_query_attention(x, p, cfg, mask, seg) ** 2 * mask[..., None])))(params["params"])
     assert_grads_agree(grads, wants, rtol=1e-3)
     # a segment's attention is what it gives alone: its positions restart at 0
-    alone = module.apply(params, x[:1, 100:160], None, None) if impl == "xla" else None
+    alone = programs.apply(module, params, x[:1, 100:160], None, None) if impl == "xla" else None
     if alone is not None:
-        np.testing.assert_allclose(run(params)[:1, 100:160], alone, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(out[:1, 100:160], alone, rtol=2e-5, atol=2e-5)
 
 
 def test_both_options_off_leave_the_attention_as_it_was():
@@ -159,13 +162,14 @@ def test_both_options_off_leave_the_attention_as_it_was():
     cfg = lfm2_config(mha_qk_norm=False, mha_rope=False)
     x, mask, seg = packed()
     module = GroupedQueryAttention(cfg)
-    params = module.init(jax.random.PRNGKey(1), x, mask, seg)
+    params = programs.init(module, jax.random.PRNGKey(1), x, mask, seg)
     assert set(params["params"]) == {"q_proj", "k_proj", "v_proj", "o_proj"}
     with_both = GroupedQueryAttention(lfm2_config())
-    assert set(with_both.init(jax.random.PRNGKey(1), x, mask, seg)["params"]) == set(params["params"]) | {"q_layernorm", "k_layernorm"}
+    assert set(jax.eval_shape(with_both.init, jax.random.PRNGKey(1), x, mask, seg)["params"]) == set(params["params"]) | {"q_layernorm", "k_layernorm"}
     real = np.asarray(mask)
-    got = module.apply(params, x, mask, seg)
-    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(ref.grouped_query_attention(x, params["params"], cfg, mask, seg))[real], rtol=2e-5, atol=2e-5)
+    got = programs.apply(module, params, x, mask, seg)
+    want = programs.call(lambda p: ref.grouped_query_attention(x, p, cfg, mask, seg), params["params"])
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("first", [0, 24])
@@ -176,10 +180,10 @@ def test_a_five_layer_lfm2_stack_agrees_with_the_plain_reference(first):
     cfg = lfm2_config(n_routed_experts=8, moe_expert_offset=first)
     x, mask, seg = packed()
     blocks = [KindsBlock(cfg, layer_id=i) for i in range(5)]
-    params = [
-        jittered(b.init(jax.random.PRNGKey(10 + i), x, mask, None, False, False, False, seg)["params"], seed=20 + i)
-        for i, b in enumerate(blocks)
-    ]
+    inits = programs.call(
+        lambda x: [b.init(jax.random.PRNGKey(10 + i), x, mask, None, False, False, False, seg) for i, b in enumerate(blocks)], x
+    )
+    params = [jittered(p["params"], seed=20 + i) for i, p in enumerate(inits)]
     assert [sorted(p) for p in params] == [
         ["conv", "input_layernorm", "mlp", "post_attention_layernorm"],
         ["input_layernorm", "mlp", "post_attention_layernorm", "self_attn"],
@@ -209,16 +213,20 @@ def test_the_four_shares_of_a_32_wide_top_4_router_add_up_to_the_uncut_layer():
     whole = lfm2_config(n_routed_experts=32)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, 32))
     module = RoutedFeedForward(whole)
-    params = module.init(jax.random.PRNGKey(4), x)
-    uncut = module.apply(params, x)
+    params = programs.init(module, jax.random.PRNGKey(4), x)
+    uncut = programs.apply(module, params, x)
     p = params["params"]
-    total = jnp.zeros_like(uncut)
-    for first in range(0, 32, 8):
-        share = lfm2_config(n_routed_experts=8, moe_expert_offset=first)
-        held = {k: (v[first : first + 8] if k.startswith("experts_") else v) for k, v in p.items()}
-        total = total + RoutedFeedForward(share).apply({"params": held}, x)
-    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(uncut, ref.routed_feed_forward(x, p, whole)[0], rtol=2e-5, atol=2e-5)
+
+    def shares(p):
+        total = jnp.zeros_like(uncut)
+        for first in range(0, 32, 8):
+            share = lfm2_config(n_routed_experts=8, moe_expert_offset=first)
+            held = {k: (v[first : first + 8] if k.startswith("experts_") else v) for k, v in p.items()}
+            total = total + RoutedFeedForward(share).apply({"params": held}, x)
+        return total
+
+    np.testing.assert_allclose(programs.call(shares, p), uncut, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(uncut, programs.call(lambda p: ref.routed_feed_forward(x, p, whole)[0], p), rtol=2e-5, atol=2e-5)
 
 
 def test_the_normalising_epsilon_is_lfm2s_alone():
@@ -273,11 +281,11 @@ def test_the_chunk_with_headroom_gives_the_one_chunk_walks_output(held_share, im
     with_headroom, counters_too = walk[32](rows, weights)
     np.testing.assert_allclose(with_headroom, one_chunk_walk, rtol=2e-5, atol=2e-5)
     assert counters.tolist() == counters_too.tolist() and counters.tolist()[0][0] == pairs
-    want = sum(
+    want = jax.jit(lambda rows, weights: sum(
         jnp.where((chosen == e)[..., None], weights[..., None], 0.0).sum(1)
         * ((jax.nn.silu(rows @ stacks[0][e]) * (rows @ stacks[1][e])) @ stacks[2][e])
         for e in range(8)
-    )
+    ))(rows, weights)
     np.testing.assert_allclose(with_headroom, want, rtol=2e-5, atol=2e-5)
     grads = [jax.jit(jax.grad(lambda r, w: jnp.sum(f(r, w)[0] ** 2), argnums=(0, 1)))(rows, weights) for f in walk.values()]
     for g, w in zip(*grads):
@@ -312,6 +320,6 @@ def test_what_the_configuration_refuses_and_the_missing_decode_state():
     assert "gated short convolution" in NO_DECODE_STATE and "last L - 1" in NO_DECODE_STATE
     x, mask, seg = packed()
     block = KindsBlock(cfg, layer_id=0)
-    params = block.init(jax.random.PRNGKey(0), x, mask, None, False, False, False, seg)
+    params = programs.init(block, jax.random.PRNGKey(0), x, mask, None, False, False, False, seg)
     with pytest.raises(NotImplementedError, match="gated short convolution"):
-        block.apply(params, x, mask, None, True, False, False, seg)
+        programs.apply(block, params, x, mask, None, True, False, False, seg)

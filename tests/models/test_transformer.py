@@ -9,6 +9,7 @@ uncached forward — reference ``test_transformer.py:208``).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from eventstreamgpt_tpu.data.types import EventStreamBatch
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
@@ -19,6 +20,8 @@ from eventstreamgpt_tpu.models.transformer import (
     make_causal_mask,
     time_from_deltas,
 )
+
+from .. import _programs as programs
 
 
 def small_config(**kwargs):
@@ -101,24 +104,26 @@ class TestHelpers:
 
 
 class TestCIEncoder:
-    def setup_method(self):
-        self.config = small_config()
-        self.batch = make_batch()
-        self.model = ConditionallyIndependentPointProcessTransformer(self.config)
-        self.params = self.model.init(jax.random.PRNGKey(0), self.batch)
+    @pytest.fixture(autouse=True, scope="class")
+    def built(self, request):
+        cls = request.cls
+        cls.config = small_config()
+        cls.batch = make_batch()
+        cls.model = ConditionallyIndependentPointProcessTransformer(cls.config)
+        cls.params = programs.init(cls.model, jax.random.PRNGKey(0), cls.batch)
 
     def test_output_shape(self):
-        out = self.model.apply(self.params, self.batch)
+        out = programs.apply(self.model, self.params, self.batch)
         assert out.last_hidden_state.shape == (2, 6, 16)
 
     def test_masked_events_do_not_affect_earlier_outputs(self):
         """Causality: changing a later event must not change earlier outputs."""
-        out1 = self.model.apply(self.params, self.batch)
+        out1 = programs.apply(self.model, self.params, self.batch)
         modified = self.batch.replace(
             dynamic_indices=self.batch.dynamic_indices.at[:, -1].set(3),
             dynamic_values=self.batch.dynamic_values.at[:, -1].set(9.9),
         )
-        out2 = self.model.apply(self.params, modified)
+        out2 = programs.apply(self.model, self.params, modified)
         np.testing.assert_allclose(
             np.asarray(out1.last_hidden_state[:, :-1]),
             np.asarray(out2.last_hidden_state[:, :-1]),
@@ -128,12 +133,12 @@ class TestCIEncoder:
 
     def test_padding_mask_sensitivity(self):
         """Real-event outputs must not depend on padded events' content."""
-        out1 = self.model.apply(self.params, self.batch)
+        out1 = programs.apply(self.model, self.params, self.batch)
         modified = self.batch.replace(
             dynamic_indices=self.batch.dynamic_indices.at[1, -1].set(7),
             time_delta=self.batch.time_delta.at[1, -1].set(99.0),
         )
-        out2 = self.model.apply(self.params, modified)
+        out2 = programs.apply(self.model, self.params, modified)
         np.testing.assert_allclose(
             np.asarray(out1.last_hidden_state[1, :4]),
             np.asarray(out2.last_hidden_state[1, :4]),
@@ -142,8 +147,8 @@ class TestCIEncoder:
         )
 
     def test_hidden_states_and_attentions_outputs(self):
-        out = self.model.apply(
-            self.params, self.batch, output_attentions=True, output_hidden_states=True
+        out = programs.apply(
+            self.model, self.params, self.batch, output_attentions=True, output_hidden_states=True
         )
         assert len(out.hidden_states) == 3  # embeddings + 2 layers (final normed)
         assert len(out.attentions) == 2
@@ -155,17 +160,18 @@ class TestCIEncoder:
         The reference's most important encoder invariant
         (``test_transformer.py:208``).
         """
-        full = self.model.apply(self.params, self.batch)
+        full = programs.apply(self.model, self.params, self.batch)
 
         B, L = self.batch.event_mask.shape
         caches = init_kv_caches(self.config, B, max_len=L)
         t_full = time_from_deltas(self.batch)
+        step = jax.jit(lambda b, c: self.model.apply(self.params, b, past=c, use_cache=True))
         step_outputs = []
         for i in range(L):
             step_batch = self.batch.slice((slice(None), slice(i, i + 1))).replace(
                 time=t_full[:, i : i + 1]
             )
-            out = self.model.apply(self.params, step_batch, past=caches, use_cache=True)
+            out = step(step_batch, caches)
             caches = out.past_key_values
             step_outputs.append(np.asarray(out.last_hidden_state[:, 0]))
 
@@ -188,8 +194,8 @@ class TestCIEncoder:
         model_ckpt = ConditionallyIndependentPointProcessTransformer(
             self.config, use_gradient_checkpointing=True
         )
-        out1 = self.model.apply(self.params, self.batch)
-        out2 = model_ckpt.apply(self.params, self.batch)
+        out1 = programs.apply(self.model, self.params, self.batch)
+        out2 = programs.apply(model_ckpt, self.params, self.batch)
         np.testing.assert_allclose(
             np.asarray(out1.last_hidden_state), np.asarray(out2.last_hidden_state), rtol=1e-5
         )
@@ -208,7 +214,7 @@ class TestRematPolicies:
         for policy in ("none", "block", "dots", "dots_no_batch", "save_attention"):
             config = small_config(gradient_checkpointing=policy)
             model = ConditionallyIndependentPointProcessTransformer(config)
-            params = model.init(jax.random.PRNGKey(0), batch)
+            params = programs.init(model, jax.random.PRNGKey(0), batch)
 
             def loss_fn(p):
                 out = model.apply(p, batch)

@@ -61,8 +61,9 @@ def build_ci():
     config = ci_config()
     prompt = make_prompt(B=4, L=4)
     model = CIPPTForGenerativeSequenceModeling(config)
-    params = model.init(jax.random.PRNGKey(0), prompt)
-    params2 = model.init(jax.random.PRNGKey(99), prompt)
+    init = jax.jit(model.init)
+    params = init(jax.random.PRNGKey(0), prompt)
+    params2 = init(jax.random.PRNGKey(99), prompt)
     return config, model, params, params2, prompt
 
 

@@ -1384,10 +1384,16 @@ class TestCensusCompleteness:
     bucket, service replica, or training layout that ships without a
     registered census entry fails here, not in a post-mortem."""
 
-    def test_every_aot_program_is_gated(self):
+    @pytest.fixture(scope="class")
+    def programs(self):
+        """Every registered provider's programs, built once for the class."""
         from eventstreamgpt_tpu.analysis import program_census as census
 
-        programs = census.census_programs()
+        return census.census_programs()
+
+    def test_every_aot_program_is_gated(self, programs):
+        from eventstreamgpt_tpu.analysis import program_census as census
+
         surface = census.aot_surface()
         surface_labels = set().union(*surface.values())
         census_labels = set(programs)
@@ -1410,13 +1416,11 @@ class TestCensusCompleteness:
             "ladder",
         }
 
-    def test_tier_b_budget_keys_exist_in_collectives(self):
+    def test_tier_b_budget_keys_exist_in_collectives(self, programs):
         import json as _json
 
-        from eventstreamgpt_tpu.analysis import program_census as census
-
         layouts = _json.loads((REPO_ROOT / "COLLECTIVES.json").read_text())["layouts"]
-        for label, prog in census.census_programs().items():
+        for label, prog in programs.items():
             if prog.budget_key is not None:
                 assert prog.budget_key in layouts, (
                     f"{label} names missing COLLECTIVES.json budget {prog.budget_key}"

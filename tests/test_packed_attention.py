@@ -20,6 +20,8 @@ from eventstreamgpt_tpu.models.transformer import (
     time_from_deltas,
 )
 
+from . import _programs as programs
+
 VOCAB = 32
 
 
@@ -130,10 +132,10 @@ class TestPackedEquivalence:
         pack, spans = packed_batch(subjects, L=16)
 
         encoder = ConditionallyIndependentPointProcessTransformer(config)
-        params = encoder.init(jax.random.PRNGKey(0), pad)
+        params = programs.init(encoder, jax.random.PRNGKey(0), pad)
 
-        enc_pad = np.asarray(encoder.apply(params, pad).last_hidden_state)
-        enc_pack = np.asarray(encoder.apply(params, pack).last_hidden_state)
+        enc_pad = np.asarray(programs.apply(encoder, params, pad).last_hidden_state)
+        enc_pack = np.asarray(programs.apply(encoder, params, pack).last_hidden_state)
 
         for i, (lo, hi) in enumerate(spans):
             n = hi - lo
@@ -148,9 +150,9 @@ class TestPackedEquivalence:
         pack, spans = packed_batch(subjects, L=12)
 
         encoder = ConditionallyIndependentPointProcessTransformer(config)
-        params = encoder.init(jax.random.PRNGKey(0), pad)
-        enc_pad = np.asarray(encoder.apply(params, pad).last_hidden_state)
-        enc_pack = np.asarray(encoder.apply(params, pack).last_hidden_state)
+        params = programs.init(encoder, jax.random.PRNGKey(0), pad)
+        enc_pad = np.asarray(programs.apply(encoder, params, pad).last_hidden_state)
+        enc_pack = np.asarray(programs.apply(encoder, params, pack).last_hidden_state)
         for i, (lo, hi) in enumerate(spans):
             np.testing.assert_allclose(
                 enc_pack[0, lo:hi], enc_pad[i, : hi - lo], rtol=2e-4, atol=2e-5,
@@ -161,10 +163,9 @@ class TestPackedEquivalence:
         subjects = [make_subject(5, seed=1), make_subject(7, seed=2)]
         pack, _ = packed_batch(subjects, L=16)
         model = CIPPTForGenerativeSequenceModeling(config)
-        params = model.init(jax.random.PRNGKey(0), pack)
-        out = model.apply(params, pack)
-        assert np.isfinite(float(out.loss))
-        grads = jax.grad(lambda p: model.apply(p, pack).loss)(params)
+        params = programs.init(model, jax.random.PRNGKey(0), pack)
+        assert np.isfinite(float(programs.loss(model, params, pack)))
+        grads = programs.grad(model, params, pack)
         assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(grads))
 
     def test_tte_mask_excludes_cross_segment_gaps(self):
@@ -172,8 +173,8 @@ class TestPackedEquivalence:
         subjects = [make_subject(4, seed=1), make_subject(4, seed=2)]
         pack, _ = packed_batch(subjects, L=8)
         model = CIPPTForGenerativeSequenceModeling(config)
-        params = model.init(jax.random.PRNGKey(0), pack)
-        out = model.apply(params, pack)
+        params = programs.init(model, jax.random.PRNGKey(0), pack)
+        out = programs.apply(model, params, pack)
         # TTE labels: positions 0..6 (L-1); position 3 bridges the segments
         # and must be masked (label forced to the 1.0 filler).
         tte_true = np.asarray(out.labels.time_to_event)
@@ -225,9 +226,9 @@ class TestPackedEquivalence:
         subjects = [make_subject(4, seed=1)]
         pack, _ = packed_batch(subjects, L=8)
         model = NAPPTForGenerativeSequenceModeling(config)
-        params = model.init(jax.random.PRNGKey(0), pack)
+        params = programs.init(model, jax.random.PRNGKey(0), pack)
         with pytest.raises(NotImplementedError, match="KV-cached"):
-            model.apply(params, pack, use_cache=True)
+            programs.apply(model, params, pack, use_cache=True)
 
 
 class TestBatchSlicing:

@@ -18,6 +18,8 @@ from __graft_entry__ import _make_model_and_batch
 from eventstreamgpt_tpu.models.ci_model import CIPPTForGenerativeSequenceModeling
 from eventstreamgpt_tpu.models.config import StructuredTransformerConfig
 
+from . import _programs as programs
+
 @pytest.fixture
 def cpu_backend():
     """The backend question is asked when a test runs, never at import:
@@ -63,10 +65,10 @@ class TestFallback:
                 {**cfg_global.to_dict(), "attention_implementation": "pallas_flash"}
             )
         )
-        params = einsum_model.init(jax.random.PRNGKey(0), batch)
-        out_e = einsum_model.apply(params, batch)
-        out_p = pallas_model.apply(params, batch)
-        np.testing.assert_array_equal(np.asarray(out_p.loss), np.asarray(out_e.loss))
+        params = programs.init(einsum_model, jax.random.PRNGKey(0), batch)
+        loss_e = programs.loss(einsum_model, params, batch)
+        loss_p = programs.loss(pallas_model, params, batch)
+        np.testing.assert_array_equal(np.asarray(loss_p), np.asarray(loss_e))
 
     def test_band_local_matches_einsum_model(self):
         """Default ["local", "global"] stack under pallas_flash: the local
@@ -74,12 +76,12 @@ class TestFallback:
         loss and grads must match the full-mask einsum path to fp32 noise."""
         model, batch = _make_model_and_batch(batch_size=2, seq_len=128, n_data=4, hidden=32, vocab=32)
         pallas_model = make_pallas_twin(model)
-        params = model.init(jax.random.PRNGKey(0), batch)
-        out_e = model.apply(params, batch)
-        out_p = pallas_model.apply(params, batch)
-        np.testing.assert_allclose(float(out_p.loss), float(out_e.loss), rtol=1e-5)
-        ge = jax.grad(lambda p: model.apply(p, batch).loss)(params)
-        gp = jax.grad(lambda p: pallas_model.apply(p, batch).loss)(params)
+        params = programs.init(model, jax.random.PRNGKey(0), batch)
+        loss_e = programs.loss(model, params, batch)
+        loss_p = programs.loss(pallas_model, params, batch)
+        np.testing.assert_allclose(float(loss_p), float(loss_e), rtol=1e-5)
+        ge = programs.grad(model, params, batch)
+        gp = programs.grad(pallas_model, params, batch)
         for a, b in zip(jax.tree_util.tree_leaves(ge), jax.tree_util.tree_leaves(gp)):
             np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-3, atol=1e-5)
 
@@ -108,12 +110,12 @@ class TestFallback:
         batch = batch.replace(
             segment_ids=jax.numpy.asarray(seg), event_mask=jax.numpy.asarray(event_mask)
         )
-        params = einsum_model.init(jax.random.PRNGKey(0), batch)
-        out_e = einsum_model.apply(params, batch)
-        out_p = pallas_model.apply(params, batch)
-        np.testing.assert_allclose(float(out_p.loss), float(out_e.loss), rtol=1e-5)
-        ge = jax.grad(lambda p: einsum_model.apply(p, batch).loss)(params)
-        gp = jax.grad(lambda p: pallas_model.apply(p, batch).loss)(params)
+        params = programs.init(einsum_model, jax.random.PRNGKey(0), batch)
+        loss_e = programs.loss(einsum_model, params, batch)
+        loss_p = programs.loss(pallas_model, params, batch)
+        np.testing.assert_allclose(float(loss_p), float(loss_e), rtol=1e-5)
+        ge = programs.grad(einsum_model, params, batch)
+        gp = programs.grad(pallas_model, params, batch)
         for a, b in zip(jax.tree_util.tree_leaves(ge), jax.tree_util.tree_leaves(gp)):
             np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-3, atol=1e-5)
 
@@ -130,13 +132,13 @@ class TestFallback:
             seg_np[:, L // 3 :] = 1
             seg_np[:, -7:] = -1  # padding convention
             seg = jax.numpy.asarray(seg_np)
-            out = band_local_attention(q, k, v, seg, W)
+            out = programs.call(band_local_attention, q, k, v, seg, W)
 
             # Any chunk size >= W that divides L is result-identical: the
             # chunk is a pure performance knob (fp32 here, so exact).
             for C in {W, 2 * W, L}:
                 if L % C == 0:
-                    out_c = band_local_attention(q, k, v, seg, W, chunk_size=C)
+                    out_c = programs.call(band_local_attention, q, k, v, seg, W, chunk_size=C)
                     np.testing.assert_allclose(
                         np.asarray(out_c), np.asarray(out), rtol=1e-6, atol=1e-6
                     )
@@ -154,6 +156,6 @@ class TestFallback:
     def test_param_tree_identical_across_backends(self):
         model, batch = _make_model_and_batch(batch_size=2, seq_len=128, n_data=4, hidden=32, vocab=32)
         pallas_model = make_pallas_twin(model)
-        p_e = model.init(jax.random.PRNGKey(0), batch)
-        p_p = pallas_model.init(jax.random.PRNGKey(0), batch)
+        p_e = jax.eval_shape(model.init, jax.random.PRNGKey(0), batch)
+        p_p = jax.eval_shape(pallas_model.init, jax.random.PRNGKey(0), batch)
         assert jax.tree_util.tree_structure(p_e) == jax.tree_util.tree_structure(p_p)

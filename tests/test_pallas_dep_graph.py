@@ -172,6 +172,7 @@ class TestModelLevelParity:
     def test_na_loss_and_grads_match_xla_impl(self):
         from eventstreamgpt_tpu.models.na_model import NAPPTForGenerativeSequenceModeling
 
+        from . import _programs as programs
         from .models.test_na_model import make_batch, make_config
 
         batch = make_batch()
@@ -181,9 +182,9 @@ class TestModelLevelParity:
         model_p = NAPPTForGenerativeSequenceModeling(
             make_config(dep_graph_attention_impl=KERNEL)
         )
-        params = model_x.init(jax.random.PRNGKey(0), batch)
-        loss_x, grads_x = jax.value_and_grad(lambda p: model_x.apply(p, batch).loss)(params)
-        loss_p, grads_p = jax.value_and_grad(lambda p: model_p.apply(p, batch).loss)(params)
+        params = programs.init(model_x, jax.random.PRNGKey(0), batch)
+        loss_x, grads_x = programs.call(jax.value_and_grad(lambda p: model_x.apply(p, batch).loss), params)
+        loss_p, grads_p = programs.call(jax.value_and_grad(lambda p: model_p.apply(p, batch).loss), params)
         np.testing.assert_allclose(float(loss_x), float(loss_p), rtol=1e-6)
         for gx, gp in zip(
             jax.tree_util.tree_leaves(grads_x), jax.tree_util.tree_leaves(grads_p)

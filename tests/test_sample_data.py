@@ -17,6 +17,8 @@ import pytest
 
 from eventstreamgpt_tpu.data import Dataset, JaxDataset, PytorchDatasetConfig, VocabularyConfig
 
+from . import _programs as programs
+
 SAMPLE = Path(__file__).resolve().parent.parent / "sample_data"
 PROCESSED = SAMPLE / "processed" / "sample"
 
@@ -84,7 +86,7 @@ def test_trains_one_step_to_finite_loss(sample_copy):
     )
     tx, _ = build_optimizer(oc)
     batch = next(ds.batches(8, shuffle=False))
-    params = model.init(jax.random.PRNGKey(0), batch)
+    params = programs.init(model, jax.random.PRNGKey(0), batch)
     state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=tx.init(params))
     mesh = data_parallel_mesh(8)
     state = replicate(state, mesh)

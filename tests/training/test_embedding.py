@@ -16,6 +16,8 @@ from eventstreamgpt_tpu.training.fine_tuning import FinetuneConfig
 
 from tests import SAMPLE_DIR as REF_SAMPLE  # noqa: E402  (the committed artifact)
 
+from .. import _programs as programs
+
 MODEL_KWARGS = dict(
     hidden_size=32,
     head_dim=8,
@@ -40,26 +42,32 @@ def emb_dir(tmp_path_factory):
     config.set_to_dataset(ds)
     model = build_model(config)
     batch = next(ds.batches(4, shuffle=False))
-    params = model.init(jax.random.PRNGKey(0), batch)
+    params = programs.init(model, jax.random.PRNGKey(0), batch)
     model_dir = dst / "model"
     save_pretrained(model_dir, params, config=config)
     data_config.to_json_file(model_dir / "data_config.json", do_overwrite=True)
     return dst, model_dir
 
 
+@pytest.fixture(scope="module")
+def embedder(emb_dir):
+    """The embeddings-only model, a batch and its parameters, built once for the pooling cases."""
+    dst, model_dir = emb_dir
+    cfg = FinetuneConfig(load_from_model_dir=model_dir, task_df_name="t", data_config_overrides={})
+    # No task df on disk — construct the dataset directly without a task.
+    cfg.data_config.task_df_name = None
+    ds = JaxDataset(cfg.data_config, "tuning")
+    config = cfg.config
+    config.set_to_dataset(ds)
+    model = EmbeddingsOnlyModel(config)
+    batch = next(ds.batches(4, shuffle=False))
+    return model, programs.init(model, jax.random.PRNGKey(0), batch), config, batch
+
+
 class TestEmbedBatch:
     @pytest.mark.parametrize("pooling", ["last", "max", "mean", "none"])
-    def test_pooling_shapes(self, emb_dir, pooling):
-        dst, model_dir = emb_dir
-        cfg = FinetuneConfig(load_from_model_dir=model_dir, task_df_name="t", data_config_overrides={})
-        # No task df on disk — construct the dataset directly without a task.
-        cfg.data_config.task_df_name = None
-        ds = JaxDataset(cfg.data_config, "tuning")
-        config = cfg.config
-        config.set_to_dataset(ds)
-        model = EmbeddingsOnlyModel(config)
-        batch = next(ds.batches(4, shuffle=False))
-        params = model.init(jax.random.PRNGKey(0), batch)
+    def test_pooling_shapes(self, embedder, pooling):
+        model, params, config, batch = embedder
         out = np.asarray(embed_batch(model, params, config, batch, pooling))
         H = config.hidden_size
         if pooling == "none":

@@ -15,6 +15,8 @@ import pytest
 from scripts.pretrain import main as pretrain_main
 from tests import SAMPLE_DIR as REF_SAMPLE
 
+from .. import _programs as programs
+
 KINDS = [
     "config.hidden_size=32", "config.num_attention_heads=4", "config.num_hidden_layers=3",
     "config.intermediate_size=48", 'config.seq_attention_types=["global"]', "config.mixer_types=latent",
@@ -165,14 +167,14 @@ def test_generate_and_the_engine_refuse_the_backbone(stack):
     config = StructuredTransformerConfig(measurement_configs=dict(MEASUREMENT_CONFIGS), **classic, **KINDS_KWARGS)
     model = CIPPTForGenerativeSequenceModeling(config)
     prompt = make_prompt()
-    params = model.init(jax.random.PRNGKey(0), prompt)
-    assert jnp.isfinite(model.apply(params, prompt).loss)  # the training forward runs
+    params = programs.init(model, jax.random.PRNGKey(0), prompt)
+    assert jnp.isfinite(programs.loss(model, params, prompt))  # the training forward runs
     with pytest.raises(NotImplementedError, match="no decode cache yet"):
         generate(model, params, prompt, config, jax.random.PRNGKey(1), max_new_events=2)
     with pytest.raises(NotImplementedError, match="no decode cache yet"):
         GenerationEngine(model, params, config, template=prompt, n_slots=2, max_len=8)
     with pytest.raises(NotImplementedError, match="no decode cache yet"):
-        model.apply(params, prompt, use_cache=True, is_generation=True)
+        programs.apply(model, params, prompt, use_cache=True, is_generation=True)
     from eventstreamgpt_tpu.models.transformer import NO_DECODE_STATE
 
     # the message names what is missing, by mechanism
